@@ -851,7 +851,15 @@ static inline void micro_kernel(const T* Ap, const T* Bp, T* C, int64_t ldc,
 
 #if defined(__x86_64__) || defined(__i386__)
 #define PTPU_X86 1
+/* gcc 12's avx512fintrin.h spells "undefined" as `__m512 __Y = __Y;`,
+ * which g++ 12 reports as -Wmaybe-uninitialized wherever an AVX-512
+ * intrinsic is inlined (GCC PR 105593, fixed in 13). The diagnostic's
+ * location is the header, so silencing it around the include covers
+ * exactly the header's own code and keeps -Werror whole for ours. */
+#pragma GCC diagnostic push
+#pragma GCC diagnostic ignored "-Wmaybe-uninitialized"
 #include <immintrin.h>
+#pragma GCC diagnostic pop
 #endif
 
 /* Runtime ISA dispatch (ISSUE r9 tentpole b). The shipped .so builds

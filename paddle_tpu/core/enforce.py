@@ -1,7 +1,7 @@
 """Error enforcement.
 
 TPU-native equivalent of the reference's `paddle/fluid/platform/enforce.h`
-(PADDLE_ENFORCE_* macros) and `platform/errors.cc` error taxonomy. Python
+(PADDLE_ENFORCE_* macros) and `platform/errors.cc` error classes. Python
 exceptions replace the C++ macro machinery; the error categories are kept so
 user-facing messages stay recognisable.
 """

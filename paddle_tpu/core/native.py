@@ -2,9 +2,12 @@
 
 The reference binds C++ via pybind11 (`fluid/pybind/pybind.cc:459`);
 pybind11 isn't in this image, so the native core exposes a flat C ABI and
-this module is the binding layer. The library is compiled on first import
-if the prebuilt `paddle_tpu/_native.so` is missing (the reference's
-analogue: `utils/cpp_extension` JIT builds).
+this module is the binding layer. In a checkout the three shipping
+libraries are built on first use THROUGH `csrc/Makefile` (`_make`, the
+one build recipe — the reference's analogue: `utils/cpp_extension` JIT
+builds): make rebuilds what is older than its sources, so a loaded
+library is never stale, and nothing depends on a prebuilt one lying in
+the tree. An installed package (no `csrc/`) loads the .so it ships.
 
 Everything degrades gracefully: if no C++ toolchain exists, `available()`
 is False and pure-Python fallbacks take over (profiler no-ops, queue →
@@ -12,7 +15,9 @@ is False and pure-Python fallbacks take over (profiler no-ops, queue →
 """
 from __future__ import annotations
 
+import contextlib
 import ctypes
+import fcntl
 import os
 import queue as _pyqueue
 import subprocess
@@ -25,16 +30,40 @@ _LOCK = threading.Lock()
 
 _PKG_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _SO_PATH = os.path.join(_PKG_DIR, "_native.so")
-_SRC = os.path.join(os.path.dirname(_PKG_DIR), "csrc", "ptpu_runtime.cc")
+_CSRC = os.path.join(os.path.dirname(_PKG_DIR), "csrc")
 
 
-def _build() -> bool:
-    if not os.path.exists(_SRC):
+@contextlib.contextmanager
+def build_lock(name: str):
+    """One `make` of `name` in csrc/ at a time across processes.
+
+    Concurrent builds have two guards, each for its own hazard. RENAME
+    (csrc/Makefile): a shipping .so or the demo appears under its name
+    complete or not at all, so a process may dlopen or exec it while
+    another rebuilds it — that protects every READER and needs no lock.
+    This LOCK is about the recipes: it spares N processes that reach a
+    cold tree together (pytest-xdist workers, bench legs) N identical
+    compiles, and it keeps the `make` targets that RUN what they build
+    (selftest, sancheck, schedck: fixed /tmp files, shared binaries;
+    tests/_csrc.py) from running twice at once. A lock dies with its
+    holder, so a killed build never wedges the next."""
+    with open(os.path.join(_CSRC, f".{name}.lock"), "w") as lock:
+        fcntl.flock(lock, fcntl.LOCK_EX)
+        yield
+
+
+def _make(so_path: str) -> bool:
+    """Bring one shipping .so up to date through csrc/Makefile; False when
+    there is no checkout or no toolchain, or the build fails (the caller
+    then loads what exists, or reports the library unavailable)."""
+    if not os.path.exists(os.path.join(_CSRC, "Makefile")):
         return False
-    cmd = ["g++", "-O2", "-std=c++17", "-fPIC", "-shared", "-pthread",
-           "-fvisibility=hidden", "-o", _SO_PATH, _SRC]
+    name = os.path.basename(so_path)
+    target = os.path.join("..", "paddle_tpu", name)
     try:
-        subprocess.run(cmd, check=True, capture_output=True, timeout=120)
+        with build_lock(name):
+            subprocess.run(["make", "-C", _CSRC, target], check=True,
+                           capture_output=True, timeout=900)
         return True
     except (OSError, subprocess.SubprocessError):
         return False
@@ -46,7 +75,7 @@ def _load() -> Optional[ctypes.CDLL]:
         if _TRIED:
             return _LIB
         _TRIED = True
-        if not os.path.exists(_SO_PATH) and not _build():
+        if not _make(_SO_PATH) and not os.path.exists(_SO_PATH):
             return None
         try:
             lib = ctypes.CDLL(_SO_PATH)
@@ -88,30 +117,17 @@ def _load() -> Optional[ctypes.CDLL]:
         lib.ptpu_aes_ctr_xcrypt.argtypes = [
             ctypes.c_char_p, ctypes.c_char_p, ctypes.c_char_p,
             ctypes.c_char_p, ctypes.c_uint64]
-        # newer symbols — a STALE prebuilt .so may predate them; the rest
-        # of the runtime must keep working and the feed path degrade
-        # (an AttributeError must never escape available()). dlopen
-        # caches by path, so a rebuild-and-reload here is unreliable —
-        # delete the stale .so and re-import to pick the new symbols up.
-        try:
-            lib.ptpu_feed_count.restype = ctypes.c_int
-            lib.ptpu_feed_count.argtypes = [
-                ctypes.c_char_p, ctypes.c_int64,
-                ctypes.POINTER(ctypes.c_int64),
-                ctypes.POINTER(ctypes.c_int64)]
-            lib.ptpu_feed_parse.restype = ctypes.c_int
-            lib.ptpu_feed_parse.argtypes = [
-                ctypes.c_void_p, ctypes.c_int64, ctypes.c_void_p,
-                ctypes.c_int64, ctypes.c_void_p, ctypes.c_int64,
-                ctypes.POINTER(ctypes.c_int64)]
-            lib._ptpu_has_feed = True
-        except AttributeError:
-            lib._ptpu_has_feed = False
-        try:
-            lib.ptpu_profiler_enabled.restype = ctypes.c_int
-            lib._ptpu_has_prof_enabled = True
-        except AttributeError:  # stale prebuilt .so
-            lib._ptpu_has_prof_enabled = False
+        lib.ptpu_feed_count.restype = ctypes.c_int
+        lib.ptpu_feed_count.argtypes = [
+            ctypes.c_char_p, ctypes.c_int64,
+            ctypes.POINTER(ctypes.c_int64),
+            ctypes.POINTER(ctypes.c_int64)]
+        lib.ptpu_feed_parse.restype = ctypes.c_int
+        lib.ptpu_feed_parse.argtypes = [
+            ctypes.c_void_p, ctypes.c_int64, ctypes.c_void_p,
+            ctypes.c_int64, ctypes.c_void_p, ctypes.c_int64,
+            ctypes.POINTER(ctypes.c_int64)]
+        lib.ptpu_profiler_enabled.restype = ctypes.c_int
         _LIB = lib
         return _LIB
 
@@ -303,9 +319,6 @@ def aes_ctr_xcrypt(key: bytes, iv: bytes, data: bytes) -> bytes:
 # this set (ISSUE 17 cycles-per-request methodology)
 _PS_SO = os.environ.get("PTPU_PS_SO",
                         os.path.join(_PKG_DIR, "_native_ps.so"))
-_PS_SRCS = [os.path.join(os.path.dirname(_PKG_DIR), "csrc", f)
-            for f in ("ptpu_ps_table.cc", "ptpu_ps_server.cc",
-                      "ptpu_net.cc")]
 _PS_LIB: Optional[ctypes.CDLL] = None
 _PS_TRIED = False
 _PS_LOCK = threading.Lock()
@@ -319,17 +332,11 @@ def _ps_load() -> Optional[ctypes.CDLL]:
         if _PS_TRIED:
             return _PS_LIB
         _PS_TRIED = True
-        if not os.path.exists(_PS_SO):
-            if not all(os.path.exists(s) for s in _PS_SRCS):
-                return None
-            cmd = ["g++", "-O3", "-std=c++17", "-fPIC", "-shared",
-                   "-pthread", "-fvisibility=hidden", "-o", _PS_SO,
-                   *_PS_SRCS]
-            try:
-                subprocess.run(cmd, check=True, capture_output=True,
-                               timeout=120)
-            except (OSError, subprocess.SubprocessError):
-                return None
+        # an override names somebody else's build (an A/B leg's older
+        # .so): load it as it is. The `except AttributeError` arms below
+        # exist for exactly those — this tree's own build has every symbol
+        if "PTPU_PS_SO" not in os.environ:
+            _make(_PS_SO)
         try:
             lib = ctypes.CDLL(_PS_SO)
         except OSError:
@@ -357,8 +364,7 @@ def _ps_load() -> Optional[ctypes.CDLL]:
                 c.c_void_p, c.POINTER(c.c_int64), c.c_int64,
                 c.POINTER(c.c_float)]
         except AttributeError:
-            # stale prebuilt .so missing symbols: treat as unavailable
-            # (delete paddle_tpu/_native_ps.so and re-import to rebuild)
+            # an override .so without the base table ABI: unavailable
             return None
         try:
             lib.ptpu_ps_table_stats_json.restype = c.c_char_p
@@ -367,7 +373,7 @@ def _ps_load() -> Optional[ctypes.CDLL]:
             lib.ptpu_ps_table_note_pull.argtypes = [c.c_void_p,
                                                     c.c_int64]
             lib._ptpu_has_ps_stats = True
-        except AttributeError:   # stale prebuilt .so: stats degrade
+        except AttributeError:   # older override .so: stats degrade
             lib._ptpu_has_ps_stats = False
         try:
             lib.ptpu_ps_server_last_error.restype = c.c_char_p
@@ -402,7 +408,7 @@ def _ps_load() -> Optional[ctypes.CDLL]:
             lib.ptpu_trace_json.restype = c.c_char_p
             lib.ptpu_trace_json.argtypes = [c.c_int64]
             lib._ptpu_has_ps_http = True
-        except AttributeError:   # stale prebuilt .so: telemetry off
+        except AttributeError:   # older override .so: telemetry off
             lib._ptpu_has_ps_http = False
         try:
             # raw-frame capture ring ABI (production drills)
@@ -412,7 +418,7 @@ def _ps_load() -> Optional[ctypes.CDLL]:
             lib.ptpu_capture_save.restype = c.c_int
             lib.ptpu_capture_save.argtypes = [c.c_char_p]
             lib._ptpu_has_capture = True
-        except AttributeError:   # stale prebuilt .so: capture off
+        except AttributeError:   # older override .so: capture off
             lib._ptpu_has_capture = False
         try:
             # counter-conservation invariant gate (ISSUE 20): the C
@@ -423,7 +429,7 @@ def _ps_load() -> Optional[ctypes.CDLL]:
             lib.ptpu_invar_manifest.restype = c.c_char_p
             lib.ptpu_invar_manifest.argtypes = []
             lib._ptpu_has_invar = True
-        except AttributeError:   # stale prebuilt .so: gate off
+        except AttributeError:   # older override .so: gate off
             lib._ptpu_has_invar = False
         _PS_LIB = lib
         return _PS_LIB
@@ -645,6 +651,10 @@ def _predictor_lib() -> ctypes.CDLL:
     with _PRED_LOCK:
         if _PRED_LIB is not None:
             return _PRED_LIB
+        # same rule as _ps_load: an override is loaded as it is (the
+        # `except AttributeError` arms below serve older A/B builds)
+        if "PTPU_PREDICTOR_SO" not in os.environ:
+            _make(_PRED_SO)
         lib = ctypes.CDLL(_PRED_SO)
         c = ctypes
         lib.ptpu_predictor_create.restype = c.c_void_p
@@ -681,7 +691,7 @@ def _predictor_lib() -> ctypes.CDLL:
             lib.ptpu_serving_stats_reset.argtypes = [c.c_void_p]
             lib.ptpu_serving_stop.argtypes = [c.c_void_p]
             lib._ptpu_has_serving = True
-        except AttributeError:   # stale prebuilt .so: serving degrades
+        except AttributeError:   # older override .so: serving degrades
             lib._ptpu_has_serving = False
         lib.ptpu_predictor_destroy.argtypes = [c.c_void_p]
         lib.ptpu_predictor_num_inputs.argtypes = [c.c_void_p]
@@ -725,7 +735,7 @@ def _predictor_lib() -> ctypes.CDLL:
                 c.c_int, c.c_int64, c.c_int, c.c_int, c.c_int, c.c_int,
                 c.c_char_p, c.c_int]
             lib._ptpu_has_decode = True
-        except AttributeError:   # stale prebuilt .so: decode degrades
+        except AttributeError:   # older override .so: decode degrades
             lib._ptpu_has_decode = False
         try:
             # paged KV pool ABI (r12) — absent from stale .so builds
@@ -750,7 +760,7 @@ def _predictor_lib() -> ctypes.CDLL:
             lib.ptpu_kvpool_stats_json.restype = c.c_char_p
             lib.ptpu_kvpool_stats_json.argtypes = [c.c_void_p]
             lib._ptpu_has_kvpool = True
-        except AttributeError:   # stale prebuilt .so: paging degrades
+        except AttributeError:   # older override .so: paging degrades
             lib._ptpu_has_kvpool = False
         try:
             # telemetry HTTP + two-phase drain + tracing ABI (r10)
@@ -768,7 +778,7 @@ def _predictor_lib() -> ctypes.CDLL:
             lib.ptpu_trace_json.restype = c.c_char_p
             lib.ptpu_trace_json.argtypes = [c.c_int64]
             lib._ptpu_has_http = True
-        except AttributeError:   # stale prebuilt .so: telemetry off
+        except AttributeError:   # older override .so: telemetry off
             lib._ptpu_has_http = False
         try:
             # raw-frame capture ring ABI (production drills)
@@ -778,7 +788,7 @@ def _predictor_lib() -> ctypes.CDLL:
             lib.ptpu_capture_save.restype = c.c_int
             lib.ptpu_capture_save.argtypes = [c.c_char_p]
             lib._ptpu_has_capture = True
-        except AttributeError:   # stale prebuilt .so: capture off
+        except AttributeError:   # older override .so: capture off
             lib._ptpu_has_capture = False
         try:
             # speculative decoding ABI (r13) — width-k verify steps,
@@ -795,7 +805,7 @@ def _predictor_lib() -> ctypes.CDLL:
                 c.c_int, c.c_int, c.c_int, c.c_int, c.c_int,
                 c.c_char_p, c.c_int]
             lib._ptpu_has_spec = True
-        except AttributeError:   # stale prebuilt .so: spec degrades
+        except AttributeError:   # older override .so: spec degrades
             lib._ptpu_has_spec = False
         try:
             lib.ptpu_predictor_stats_json.restype = c.c_char_p
@@ -804,7 +814,7 @@ def _predictor_lib() -> ctypes.CDLL:
             lib.ptpu_predictor_set_profiler.argtypes = [c.c_void_p,
                                                         c.c_void_p]
             lib._ptpu_has_pred_stats = True
-        except AttributeError:   # stale prebuilt .so: stats degrade
+        except AttributeError:   # older override .so: stats degrade
             lib._ptpu_has_pred_stats = False
         try:
             # persisted kernel autotuning ABI (r15) — process-global
@@ -816,7 +826,7 @@ def _predictor_lib() -> ctypes.CDLL:
             lib.ptpu_tune_load.argtypes = [c.c_char_p]
             lib.ptpu_tune_clear.argtypes = []
             lib._ptpu_has_tune = True
-        except AttributeError:   # stale prebuilt .so: autotune off
+        except AttributeError:   # older override .so: autotune off
             lib._ptpu_has_tune = False
         try:
             # KV tiering + session hibernation ABI (r19)
@@ -842,7 +852,7 @@ def _predictor_lib() -> ctypes.CDLL:
             lib.ptpu_kvpool_prefix_load.argtypes = [
                 c.c_void_p, c.c_char_p, c.c_char_p, c.c_int]
             lib._ptpu_has_spill = True
-        except AttributeError:   # stale prebuilt .so: tiering off
+        except AttributeError:   # older override .so: tiering off
             lib._ptpu_has_spill = False
         try:
             # counter-conservation invariant gate (ISSUE 20): the C
@@ -853,7 +863,7 @@ def _predictor_lib() -> ctypes.CDLL:
             lib.ptpu_invar_manifest.restype = c.c_char_p
             lib.ptpu_invar_manifest.argtypes = []
             lib._ptpu_has_invar = True
-        except AttributeError:   # stale prebuilt .so: gate off
+        except AttributeError:   # older override .so: gate off
             lib._ptpu_has_invar = False
         # Wire the host profiler (csrc/ptpu_runtime.cc, a separate .so)
         # into the predictor: per-op RecordEvent spans when profiling
@@ -861,10 +871,9 @@ def _predictor_lib() -> ctypes.CDLL:
         # training ranks (profiler/timeline.py merges them).
         if lib._ptpu_has_pred_stats and available():
             rl = _load()
-            if getattr(rl, "_ptpu_has_prof_enabled", False):
-                lib.ptpu_predictor_set_profiler(
-                    c.cast(rl.ptpu_profiler_record, c.c_void_p),
-                    c.cast(rl.ptpu_profiler_enabled, c.c_void_p))
+            lib.ptpu_predictor_set_profiler(
+                c.cast(rl.ptpu_profiler_record, c.c_void_p),
+                c.cast(rl.ptpu_profiler_enabled, c.c_void_p))
         _PRED_LIB = lib
         return lib
 

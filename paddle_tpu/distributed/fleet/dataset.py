@@ -36,8 +36,6 @@ def _native_parse_numeric(path: str):
     if not native.available():
         return None
     lib = native.lib()
-    if not getattr(lib, "_ptpu_has_feed", False):
-        return None          # stale prebuilt .so without the feed symbols
     # single allocation: file bytes + trailing NUL (strtof needs it)
     size = os.path.getsize(path)
     ba = bytearray(size + 1)

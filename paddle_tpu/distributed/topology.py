@@ -12,6 +12,8 @@ Axis order follows the reference: ["data", "pipe", "sharding", "model"]
 from __future__ import annotations
 
 import collections
+import contextlib
+import threading
 from typing import Dict, List, Optional, Sequence
 
 import jax
@@ -20,6 +22,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 _HYBRID_GROUP: Optional["HybridCommunicateGroup"] = None
 _GLOBAL_MESH: Optional[Mesh] = None
+_SCOPED = threading.local()     # .stack: meshes of the steps being traced
 
 
 class CommunicateTopology:
@@ -117,7 +120,29 @@ def get_mesh() -> Mesh:
 
 
 def get_mesh_or_none() -> Optional[Mesh]:
-    return _GLOBAL_MESH
+    """The mesh model code shards for: the one a step is being traced
+    for (`mesh_scope`), else the process-global one."""
+    stack = getattr(_SCOPED, "stack", None)
+    return stack[-1] if stack else _GLOBAL_MESH
+
+
+@contextlib.contextmanager
+def mesh_scope(mesh: Mesh):
+    """Trace model code for `mesh`, whatever the global mesh is by now.
+
+    A step is built for one mesh but traced at its first call; by then
+    another `build_mesh` may have replaced the global one. The step
+    builders enter this scope around their forward/backward so that the
+    sharding hints (`mp_layers._constrain`) and the attention dispatch
+    see the step's own mesh."""
+    stack = getattr(_SCOPED, "stack", None)
+    if stack is None:
+        stack = _SCOPED.stack = []
+    stack.append(mesh)
+    try:
+        yield mesh
+    finally:
+        stack.pop()
 
 
 def set_mesh(mesh: Mesh):

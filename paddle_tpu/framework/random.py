@@ -46,7 +46,12 @@ def next_key():
         key, sub = jax.random.split(scoped[-1])
         scoped[-1] = key
         return sub
-    st.key, sub = jax.random.split(st.key)
+    # the global key stays a concrete value even when a trace draws from
+    # it unscoped (jit.save of a layer with hard-wired training dropout):
+    # left to the trace, the split would store a tracer here and every
+    # later eager draw of the process would fail on it
+    with jax.ensure_compile_time_eval():
+        st.key, sub = jax.random.split(st.key)
     return sub
 
 

@@ -213,7 +213,7 @@ _RNG_PRIMS = frozenset({
 
 def _inner_jaxprs(params: dict):
     for v in params.values():
-        if hasattr(v, "jaxpr"):        # ClosedJaxpr (pjit, custom_* ...)
+        if hasattr(v, "jaxpr"):        # ClosedJaxpr (jit, custom_* ...)
             yield v.jaxpr
         elif hasattr(v, "eqns"):       # raw Jaxpr
             yield v
@@ -256,7 +256,7 @@ def _is_zero(v, producers, depth: int = 0) -> bool:
 def _keep_prob(pred, producers, depth: int = 0):
     """The bernoulli keep probability behind a dropout mask predicate,
     or None when it cannot be established. jax.random.bernoulli traces
-    as `pjit[name=_bernoulli](key, p)` with p a scalar literal; the
+    as `jit[name=_bernoulli](key, p)` with p a scalar literal; the
     mask may pass through broadcasts/converts on its way to the
     select."""
     from jax.extend.core import Literal
@@ -266,7 +266,7 @@ def _keep_prob(pred, producers, depth: int = 0):
     if e is None:
         return None
     name = e.primitive.name
-    if name == "pjit" and e.params.get("name") == "_bernoulli" and \
+    if name == "jit" and e.params.get("name") == "_bernoulli" and \
             len(e.invars) == 2 and isinstance(e.invars[1], Literal):
         try:
             import numpy as np
@@ -286,7 +286,7 @@ def dropout_removal(eqns, jaxpr):
     A dropout site is a select whose PREDICATE is RNG-derived
     (`where(bernoulli(key, keep), x / keep, 0)` in the default
     upscale_in_train mode): taint vars forward from the RNG primitives,
-    find select_n / pjit-`_where` eqns with a tainted predicate and a
+    find select_n / jit-`_where` eqns with a tainted predicate and a
     zero branch, VERIFY the kept branch is `x / keep` with the divisor
     equal to the bernoulli keep probability, and rewire consumers to x
     — exactly the eval-mode (training=False) semantics. Sites that
@@ -321,7 +321,7 @@ def dropout_removal(eqns, jaxpr):
         if name == "select_n" and len(e.invars) == 3:
             pred, on_false, on_true = e.invars
             cases = [on_false, on_true]
-        elif name == "pjit" and e.params.get("name") == "_where" and \
+        elif name == "jit" and e.params.get("name") == "_where" and \
                 len(e.invars) == 3:
             pred, on_true, on_false = e.invars
             cases = [on_false, on_true]

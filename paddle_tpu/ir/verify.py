@@ -16,8 +16,8 @@ invariants every pass must preserve, immediately after the pass:
     (dropout_removal retargets outvars through its substitution map; a
     bug there leaves an output pointing at a deleted eqn);
   * no empty eqns — every eqn defines at least one output;
-  * fused-op arity — call-style eqns carrying a subgraph (`pjit`,
-    `closed_call`, `core_call` — the jaxpr spelling of a fused op, e.g.
+  * fused-op arity — call-style eqns carrying a subgraph (`jit`,
+    `closed_call`, `call` — the jaxpr spelling of a fused op, e.g.
     the `_where`/`_bernoulli` sites dropout_removal rewrites) must bind
     exactly as many invars/outvars as their inner jaxpr declares.
 
@@ -59,7 +59,7 @@ def enabled() -> bool:
 # call-style primitives whose params carry the fused subgraph and whose
 # eqn arity must match it exactly (scan/while/cond pack extra operands
 # around their bodies, so they are checked structurally, not by arity)
-_ARITY_CHECKED = {"pjit", "closed_call", "core_call"}
+_ARITY_CHECKED = {"jit", "closed_call", "call"}
 
 
 def _inner_jaxpr(params: dict):

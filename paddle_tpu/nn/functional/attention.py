@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from ...core.flags import flag
+from ...ops.flash_attention import flash_attention
 
 
 def scaled_dot_product_attention(query, key, value, attn_mask=None,
@@ -31,18 +32,73 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
         kv_mask = _as_kv_mask(attn_mask, query.shape[0], key.shape[1]) \
             if attn_mask is not None else None
         if attn_mask is None or kv_mask is not None:
-            try:
-                from ...ops.flash_attention import flash_attention
-            except ImportError:
-                _log_fallback("pallas flash kernel unavailable")
-            else:
-                return flash_attention(query, key, value, causal=is_causal,
-                                       scale=scale, kv_mask=kv_mask)
-        else:
-            _log_fallback("attn_mask is not a [b,1,1,k] bool/int k-side "
-                          "padding mask")
+            return _flash(query, key, value, is_causal, scale, kv_mask)
+        _log_fallback("attn_mask is not a [b,1,1,k] bool/int k-side "
+                      "padding mask")
     return _xla_attention(query, key, value, attn_mask, dropout_p, is_causal,
                           training, scale)
+
+
+# mesh axes the per-shard kernel was compiled and run for (PR 23: AOT for
+# a described v5e:2x2 and on four chips, dp2 x mp2 and sharding2 x mp2)
+_KERNEL_AXES = ("data", "sharding", "model")
+
+
+def _mesh_shards(query):
+    """(mesh, batch axes, head axis) when the call is part of a program
+    over several devices, else None.
+
+    The mesh is the one the step is being traced for
+    (`topology.mesh_scope`, entered by `build_train_step`), else the
+    process-global mesh — the same rule the models' sharding hints follow
+    (`mp_layers._constrain`). A traced operand does not show where it
+    will live, so under a bare `jax.jit` outside any scope the global
+    mesh decides; a concrete operand does, and one that rests on a single
+    device keeps the call there whatever mesh is left over.
+
+    Attention is independent per (batch row, head), so those are the two
+    dims the kernel may be split on: batch over data x sharding, heads
+    over 'model' (the TP layout of the fused QKV projection,
+    models/gpt.py)."""
+    from ...distributed.topology import get_mesh_or_none
+    mesh = get_mesh_or_none()
+    if mesh is None or mesh.size == 1:
+        return None
+    if not isinstance(query, jax.core.Tracer) and (
+            not isinstance(query, jax.Array)
+            or len(query.sharding.device_set) == 1):
+        return None
+    batch = tuple(a for a in ("data", "sharding") if a in mesh.axis_names)
+    head = "model" if "model" in mesh.axis_names else None
+    return mesh, batch, head
+
+
+def _flash(query, key, value, causal, scale, kv_mask):
+    """The Pallas kernel, per shard under a multi-device mesh: GSPMD
+    cannot partition a Mosaic kernel ("Mosaic kernels cannot be
+    automatically partitioned"), so each device runs it on its own
+    batch rows and heads through shard_map. No collective is needed.
+    Verified for the axes in `_KERNEL_AXES` only; `_pallas_ok` keeps
+    every other mesh off this path."""
+    shards = _mesh_shards(query)
+    if shards is None:
+        return flash_attention(query, key, value, causal=causal,
+                               scale=scale, kv_mask=kv_mask)
+    from jax.sharding import PartitionSpec as P
+    mesh, batch, head = shards
+    qkv = P(batch, None, head, None)
+    args, specs = [query, key, value], [qkv, qkv, qkv]
+    if kv_mask is not None:
+        args.append(kv_mask)
+        specs.append(P(batch, None))
+
+    def local(q, k, v, m=None):
+        return flash_attention(q, k, v, causal=causal, scale=scale,
+                               kv_mask=m)
+
+    # manual over EVERY mesh axis: Mosaic refuses a partly-manual context
+    return jax.shard_map(local, mesh=mesh, in_specs=tuple(specs),
+                         out_specs=qkv, check_vma=False)(*args)
 
 
 def _as_kv_mask(attn_mask, batch: int, k_len: int):
@@ -96,13 +152,30 @@ def _pallas_ok(q, k, causal: bool) -> bool:
     dense score temps exceed HBM. Floor tunable via
     FLAGS_pallas_attention_min_seq (causal; non-causal uses
     min(floor, 512)). Cross-attention (k_len != q_len) stays on the XLA
-    path."""
+    path. So does, under a multi-device mesh, a batch or head count that
+    does not divide over its axes, and any mesh with an axis beyond
+    `_KERNEL_AXES` larger than 1: under 'pipe' the kernel would sit in
+    the vmapped stage dim, which the specs of `_flash` treat as
+    replicated (an all-gather and the same work on every stage), and
+    that was neither compiled nor run; 'sequence' has its own ring
+    attention."""
     if jax.default_backend() not in ("tpu",):
         return False
     b, s, h, d = q.shape
     floor = int(flag("pallas_attention_min_seq"))
     if not causal:
         floor = min(floor, 512)
+    shards = _mesh_shards(q)
+    if shards is not None:
+        # the kernel runs per shard (_flash): batch and heads must split
+        # evenly over their mesh axes
+        mesh, batch, head = shards
+        size = dict(zip(mesh.axis_names, mesh.devices.shape))
+        if any(n > 1 for a, n in size.items() if a not in _KERNEL_AXES):
+            return False
+        if b % math.prod(size[a] for a in batch) or \
+                (head and h % size[head]):
+            return False
     return (k.shape == q.shape and s % 128 == 0 and s >= floor
             and d <= 256)
 

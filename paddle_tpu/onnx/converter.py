@@ -8,7 +8,7 @@ per primitive. Parameters closed over the trace arrive as jaxpr consts and
 become ONNX initializers, so the exported file is self-contained.
 
 Static shapes only (ONNX dims are taken from traced avals). Higher-order
-primitives (pjit/custom_jvp/remat/closed_call) are inlined recursively.
+primitives (jit/custom_jvp/remat/closed_call) are inlined recursively.
 """
 from __future__ import annotations
 
@@ -213,11 +213,9 @@ def _convert_eqn(g: _Graph, eqn):
     prim = eqn.primitive.name
     ins = [g.name_of(v) for v in eqn.invars]
 
-    if prim in ("jit", "pjit", "closed_call", "custom_jvp_call",
-                "custom_vjp_call", "custom_vjp_call_jaxpr", "remat",
-                "remat2", "checkpoint", "custom_jvp_call_jaxpr"):
-        inner = eqn.params.get("jaxpr") or eqn.params.get("call_jaxpr") \
-            or eqn.params.get("fun_jaxpr")
+    if prim in ("jit", "closed_call", "custom_jvp_call",
+                "custom_vjp_call", "remat2"):
+        inner = eqn.params.get("jaxpr") or eqn.params.get("call_jaxpr")
         if inner is None:
             raise UnsupportedPrimitive(f"{prim} without inner jaxpr")
         if hasattr(inner, "jaxpr"):          # ClosedJaxpr

@@ -45,14 +45,17 @@ def _block_for(s: int) -> int:
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """Whether `pallas_call` runs the kernels in the Pallas interpreter.
+
+    Never in the library: these are TPU programs, and a backend that
+    cannot lower them raises. The CPU tests that check the kernels'
+    arithmetic off-chip ask for the interpreter by patching this
+    function (tests/test_flash_attention.py)."""
+    return False
 
 
-def _params():
-    if _interpret():
-        return {}
-    return dict(compiler_params=pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary")))
+_COMPILER_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 
 # ---------------------------------------------------------------- forward
@@ -87,8 +90,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, causal, scale, nk,
                 jnp.int32, (bq, bk), 1)
             s = jnp.where(q_pos >= k_pos, s, NEG_INF)
         if mask_ref is not None:
-            # k-side padding mask (1=keep): [BK] from the stat-lane array
-            s = jnp.where(mask_ref[0][:, 0][None, :] > 0, s, NEG_INF)
+            # k-side padding mask (1=keep), [1, BK]: k runs along lanes
+            # like the score tile's columns, so this is a row broadcast
+            s = jnp.where(mask_ref[0] > 0, s, NEG_INF)
         m_prev = m_ref[:, 0:1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         # fully-masked row guard: m_new == NEG_INF would make the masked
@@ -122,11 +126,11 @@ def _fwd(q3, k3, v3, causal, scale, mask3=None, heads=1):
     in_specs = [qt, kt, kt]
     args = [q3, k3, v3]
     if mask3 is not None:
-        # k-side mask rides the stat-lane layout, tiled by the K index;
-        # it stays [batch, s, LANE] — every head of a batch row reads the
-        # same block via the b // heads index map (heads is static)
-        in_specs.append(pl.BlockSpec((1, blk, LANE),
-                                     lambda b, i, j: (b // heads, j, 0),
+        # k-side mask is [batch, 1, s], tiled by the K index along lanes;
+        # every head of a batch row reads the same block via the
+        # b // heads index map (heads is static)
+        in_specs.append(pl.BlockSpec((1, 1, blk),
+                                     lambda b, i, j: (b // heads, 0, j),
                                      memory_space=pltpu.VMEM))
         args.append(mask3)
     o, lse = pl.pallas_call(
@@ -143,7 +147,7 @@ def _fwd(q3, k3, v3, causal, scale, mask3=None, heads=1):
                         pltpu.VMEM((blk, 128), jnp.float32),
                         pltpu.VMEM((blk, 128), jnp.float32)],
         interpret=_interpret(),
-        **_params(),
+        compiler_params=_COMPILER_PARAMS,
     )(*args)
     return o, lse
 
@@ -181,7 +185,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
                 jnp.int32, (bq, bk), 1)
             s = jnp.where(q_pos >= k_pos, s, NEG_INF)
         if mask_ref is not None:
-            s = jnp.where(mask_ref[0][:, 0][None, :] > 0, s, NEG_INF)
+            s = jnp.where(mask_ref[0] > 0, s, NEG_INF)
         p = jnp.exp(s - lse)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
@@ -227,7 +231,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
                 jnp.int32, (bq, bk), 1)
             s = jnp.where(q_pos >= k_pos, s, NEG_INF)
         if mask_ref is not None:
-            s = jnp.where(mask_ref[0][:, 0][None, :] > 0, s, NEG_INF)
+            s = jnp.where(mask_ref[0] > 0, s, NEG_INF)
         p = jnp.exp(s - lse)                              # [BQ, BK]
         pc = p.astype(do.dtype)
         dv_acc[:] += jax.lax.dot_general(
@@ -270,9 +274,9 @@ def _bwd_impl(causal, scale, res, g, mask3=None, heads=1):
     lse_j = pl.BlockSpec((1, blk, LANE), tile_j, memory_space=pltpu.VMEM)
 
     masked = mask3 is not None
-    mj = pl.BlockSpec((1, blk, LANE), lambda b, i, j: (b // heads, j, 0),
+    mj = pl.BlockSpec((1, 1, blk), lambda b, i, j: (b // heads, 0, j),
                       memory_space=pltpu.VMEM)
-    mi = pl.BlockSpec((1, blk, LANE), lambda b, i, j: (b // heads, i, 0),
+    mi = pl.BlockSpec((1, 1, blk), lambda b, i, j: (b // heads, 0, i),
                       memory_space=pltpu.VMEM)
     # dq grid: (bh, q_tile, k_tile) — the k-side mask follows axis 2
     dq_in = [ti, tj, tj, ti, lse_i, lse_i] + ([mj] if masked else [])
@@ -286,7 +290,7 @@ def _bwd_impl(causal, scale, res, g, mask3=None, heads=1):
         out_shape=[jax.ShapeDtypeStruct((bh, s, d), q3.dtype)],
         scratch_shapes=[pltpu.VMEM((blk, d), jnp.float32)],
         interpret=_interpret(),
-        **_params(),
+        compiler_params=_COMPILER_PARAMS,
     )(*dq_args)[0]
 
     # grid dims: (bh, k_tile, q_tile) — q is the reduce (innermost) dim;
@@ -304,7 +308,7 @@ def _bwd_impl(causal, scale, res, g, mask3=None, heads=1):
         scratch_shapes=[pltpu.VMEM((blk, d), jnp.float32),
                         pltpu.VMEM((blk, d), jnp.float32)],
         interpret=_interpret(),
-        **_params(),
+        compiler_params=_COMPILER_PARAMS,
     )(*dkv_args)
     return dq, dk, dv
 
@@ -372,10 +376,9 @@ def flash_attention(query, key, value, causal: bool = False,
     if kv_mask is None:
         o3 = _flash3(to3(query), to3(key), to3(value), causal, scale)
     else:
-        # [batch, s, LANE] — heads share the batch row via the kernels'
+        # [batch, 1, s] — heads share the batch row via the kernels'
         # b // heads index map (no h-fold HBM duplication)
-        m = jnp.asarray(kv_mask, jnp.float32)             # [b, s]
-        m3 = jnp.broadcast_to(m[:, :, None], (b, s, LANE))
+        m3 = jnp.asarray(kv_mask, jnp.float32).reshape(b, 1, s)
         o3 = _flash3m(to3(query), to3(key), to3(value), m3, causal, scale,
                       h)
     return jnp.swapaxes(o3.reshape(b, h, s, d), 1, 2)

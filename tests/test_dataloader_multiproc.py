@@ -120,7 +120,10 @@ class TestMultiprocessDataLoader:
     def test_processes_beat_threads_on_gil_bound_decode(self):
         if (os.cpu_count() or 1) < 4:
             pytest.skip("needs >=4 cpus for a meaningful comparison")
-        ds = SlowPythonDS()
+        # ~1 s of GIL-bound decode in all: starting four worker
+        # processes costs ~0.2 s here, which a 0.1 s workload (the
+        # default size on this interpreter) cannot win back
+        ds = SlowPythonDS(iters=300000)
 
         def run(mode):
             dl = DataLoader(ds, batch_size=4, num_workers=4,

@@ -30,6 +30,8 @@ import subprocess
 import numpy as np
 import pytest
 
+from _csrc import build_all
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CSRC = os.path.join(REPO, "csrc")
 FUZZ = os.path.join(CSRC, "fuzz")
@@ -96,8 +98,7 @@ class TestCorpusReplay:
 
 
 def _build_shipping():
-    subprocess.run(["make", "all"], cwd=CSRC, check=True,
-                   capture_output=True)
+    build_all()
 
 
 @pytest.fixture(scope="module")

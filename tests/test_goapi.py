@@ -13,6 +13,8 @@ import subprocess
 
 import pytest
 
+from _csrc import build_all
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 GOAPI = os.path.join(REPO, "goapi")
 
@@ -40,8 +42,7 @@ def test_go_round_trip(tmp_path):
 
     # ensure the .so exists (fresh checkout): same build the predictor
     # tests use
-    subprocess.run(["make", "all"], cwd=os.path.join(REPO, "csrc"),
-                   check=True, capture_output=True, timeout=300)
+    build_all()
     td = os.path.join(GOAPI, "testdata")
     os.makedirs(td, exist_ok=True)
     pt.seed(0)

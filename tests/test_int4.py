@@ -34,6 +34,8 @@ import textwrap
 import numpy as np
 import pytest
 
+from _csrc import build_all
+
 import jax.numpy as jnp
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -52,8 +54,7 @@ REL_L2_BOUND = 0.15
 @pytest.fixture(scope="module")
 def built():
     try:
-        subprocess.run(["make", "all"], cwd=os.path.join(REPO, "csrc"),
-                       check=True, capture_output=True)
+        build_all()
     except FileNotFoundError:
         if not os.path.exists(LIB):
             raise

@@ -156,7 +156,7 @@ class TestProgram:
             p.apply_pass(orphan_output)
 
     def test_verifier_rejects_broken_fused_op_arity(self):
-        """pjit eqns are the jaxpr spelling of a fused subgraph; a pass
+        """jit eqns are the jaxpr spelling of a fused subgraph; a pass
         that drops an operand without rewriting the inner jaxpr must be
         caught by the arity check."""
         from paddle_tpu.ir import verify
@@ -165,21 +165,21 @@ class TestProgram:
             return jax.jit(lambda a, b: a * b + 1.0)(x, y)
 
         p = Program.capture(f, jnp.ones((2,)), jnp.ones((2,)))
-        pjit_eqns = [e for e in p.closed.jaxpr.eqns
-                     if e.primitive.name == "pjit"]
-        assert pjit_eqns, "expected a pjit eqn in the traced program"
+        jit_eqns = [e for e in p.closed.jaxpr.eqns
+                     if e.primitive.name == "jit"]
+        assert jit_eqns, "expected a jit eqn in the traced program"
 
-        def drop_pjit_operand(eqns, jaxpr):
+        def drop_jit_operand(eqns, jaxpr):
             out = []
             for e in eqns:
-                if e.primitive.name == "pjit":
+                if e.primitive.name == "jit":
                     e = e.replace(invars=list(e.invars)[:-1])
                 out.append(e)
             return out
 
         with pytest.raises(verify.IRVerificationError,
                            match="arity"):
-            p.apply_pass(drop_pjit_operand)
+            p.apply_pass(drop_jit_operand)
 
     def test_verifier_flag_gates_the_check(self):
         """With verification forced off, the same broken pass goes

@@ -23,6 +23,8 @@ import subprocess
 
 import pytest
 
+from _csrc import make as _make
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CSRC = os.path.join(REPO, "csrc")
 
@@ -36,12 +38,6 @@ SHIPPING_SOS = [
     "paddle_tpu/_native.so", "paddle_tpu/_native_predictor.so",
     "paddle_tpu/_native_ps.so",
 ]
-
-
-def _make(args, timeout=900):
-    return subprocess.run(["make", "-j4", *args], cwd=CSRC,
-                          capture_output=True, text=True,
-                          timeout=timeout)
 
 
 def _selftests_warm() -> bool:

@@ -15,6 +15,8 @@ import subprocess
 import numpy as np
 import pytest
 
+from _csrc import build_all
+
 import jax.numpy as jnp
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -23,8 +25,7 @@ DEMO = os.path.join(REPO, "csrc", "ptpu_predictor_demo")
 
 
 def _build():
-    subprocess.run(["make", "all"], cwd=os.path.join(REPO, "csrc"),
-                   check=True, capture_output=True)
+    build_all()
 
 
 @pytest.fixture(scope="module")

@@ -36,6 +36,8 @@ import tempfile
 
 import pytest
 
+from _csrc import make as _make
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CSRC = os.path.join(REPO, "csrc")
 
@@ -60,12 +62,6 @@ SAN_BINARIES = {
              "ptpu_schedck_fixture_closerace.san-tsan",
              "ptpu_predictor_demo.san-tsan"],
 }
-
-
-def _make(args, timeout=900):
-    return subprocess.run(["make", "-j4", *args], cwd=CSRC,
-                          capture_output=True, text=True,
-                          timeout=timeout)
 
 
 def _san_flag_available(kind: str) -> bool:

@@ -21,12 +21,13 @@ import subprocess
 import numpy as np
 import pytest
 
+from _csrc import build_all
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _build():
-    subprocess.run(["make", "all"], cwd=os.path.join(REPO, "csrc"),
-                   check=True, capture_output=True)
+    build_all()
 
 
 @pytest.fixture(scope="module")

@@ -180,7 +180,7 @@ class TestAMP:
                 if eqn.primitive.name == "dot_general":
                     acc.append(eqn)
                 for v in eqn.params.values():
-                    if hasattr(v, "jaxpr"):  # pjit/closed sub-jaxprs
+                    if hasattr(v, "jaxpr"):  # jit/closed sub-jaxprs
                         dots(v.jaxpr, acc)
             return acc
 

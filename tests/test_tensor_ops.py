@@ -190,6 +190,19 @@ class TestRandom:
         p = paddle.randperm(10)
         assert sorted(np.asarray(p).tolist()) == list(range(10))
 
+    def test_unscoped_draw_under_jit_leaves_no_tracer_behind(self):
+        """A trace that draws from the global key (no rng_guard — e.g.
+        jit.save of a layer with hard-wired training dropout) must not
+        store a tracer there: every later eager draw of the process
+        would fail with UnexpectedTracerError."""
+        import jax
+        from paddle_tpu.framework import random as R
+        paddle.seed(3)
+        jax.jit(lambda x: paddle.nn.functional.dropout(
+            x, p=0.5, training=True))(paddle.ones([8]))
+        assert not isinstance(R.get_rng_state(), jax.core.Tracer)
+        paddle.randn([2])       # the eager state still works
+
 
 class TestTensorArray:
     """TensorArray ops (reference tensor/array.py): eager list mode and

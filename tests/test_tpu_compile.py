@@ -1,0 +1,196 @@
+"""The main path's kernels compile for a TPU v5e — without the chip.
+
+The TPU compiler is installed here and compiles for a chip that is
+described and not attached (`jax.experimental.topologies`). Interpret
+mode cannot show what these show: the k-side-masked flash kernel passed
+every interpret-mode test while the installed compiler never finished it
+(a sublane->lane relayout of the mask column, ISSUE 23), and GSPMD
+refuses to partition a Mosaic kernel over a mesh. Nothing runs, so
+these say nothing about results or times.
+
+Rules this file keeps (on-chip-measurement guide, section 2): the
+topology is described inside a module-scoped fixture, never at import
+and never in conftest.py (one process at a time may load libtpu, and
+every xdist worker imports every test file); compiles happen in the
+test's own process; JAX's persistent compilation cache is off around
+them (an entry written without a chip cannot be read back and warns);
+every compile has a time limit of its own, so a spinning compiler fails
+one test instead of eating the suite's clock.
+"""
+import contextlib
+import faulthandler
+import importlib.util
+import os
+import sys
+import threading
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P, \
+    SingleDeviceSharding
+
+from paddle_tpu.ops import flash_attention as fa
+
+# Each kernel compiles in about a second here; the whole file in ~15 s.
+COMPILE_LIMIT_S = 120
+
+
+@contextlib.contextmanager
+def time_limit(what, seconds=COMPILE_LIMIT_S):
+    """End this process when the block runs past its limit: the compiler
+    spins inside one C++ call that nothing can interrupt (it does release
+    the GIL, so a Python timer thread gets to run). Under xdist that is
+    one failed test ("worker crashed") and a fresh worker for the rest;
+    conftest.py sees to it that the test is not tried again."""
+    def give_up():
+        print(f"{what}: still running after {seconds} s", file=sys.stderr)
+        faulthandler.dump_traceback(file=sys.stderr)
+        os._exit(1)
+
+    timer = threading.Timer(seconds, give_up)
+    timer.daemon = True
+    timer.start()
+    try:
+        yield
+    finally:
+        timer.cancel()
+
+
+@pytest.fixture(scope="module")
+def topo():
+    """The described chip. Skips where no libtpu is installed, and for
+    nothing else: whatever else goes wrong here is an error of every
+    test in the file, because a skip would take the only guard that
+    these kernels compile away without a sound.
+
+    libtpu admits one process per machine and holds /tmp/libtpu_lockfile
+    for that process's life. The rule is about the chip, and nothing
+    here touches one — but every xdist worker that is handed a test of
+    this file loads libtpu, and all but the first used to be turned away
+    ("Internal error when accessing libtpu multi-process lockfile").
+    ALLOW_MULTIPLE_LIBTPU_LOAD is libtpu's own switch for that; it is
+    read once, when the library loads, so it is set for that moment
+    only and the tests' subprocesses do not inherit it."""
+    if importlib.util.find_spec("libtpu") is None:
+        pytest.skip("libtpu is not installed: no v5e can be described")
+    from jax.experimental import topologies
+    env = pytest.MonkeyPatch()
+    env.setenv("ALLOW_MULTIPLE_LIBTPU_LOAD", "1")
+    try:
+        with time_limit("describing v5e:2x2"):
+            return topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+    finally:
+        env.undo()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module", autouse=True)
+def no_persistent_cache():
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def compiled_text(fn, *args):
+    with time_limit("compile"):
+        return jax.jit(fn).lower(*args).compile().as_text()
+
+
+# (shape [b, s, h, d], causal, masked, operand dtype)
+KERNEL_CASES = [
+    pytest.param((8, 1024, 16, 64), True, False, jnp.bfloat16,
+                 id="gpt345m-causal-bf16"),
+    pytest.param((32, 512, 12, 64), False, True, jnp.bfloat16,
+                 id="bert_base-kvmask-bf16"),
+    pytest.param((2, 1024, 4, 64), True, False, jnp.float32,
+                 id="fp32-operands"),
+    pytest.param((2, 1024, 4, 80), True, False, jnp.bfloat16,
+                 id="gpt2p6b-head_dim80"),
+    pytest.param((2, 128, 4, 64), True, False, jnp.bfloat16,
+                 id="tile128-one-block"),
+    pytest.param((2, 256, 4, 64), False, True, jnp.bfloat16,
+                 id="tile256-kvmask"),
+    pytest.param((2, 384, 4, 64), True, True, jnp.bfloat16,
+                 id="tile128-three-blocks-causal-kvmask"),
+]
+
+
+@pytest.mark.parametrize("shape,causal,masked,dtype", KERNEL_CASES)
+def test_flash_kernels_compile(one_chip, shape, causal, masked, dtype):
+    """fwd, dq and dk/dv kernels through jax.grad(flash_attention)."""
+    assert fa._interpret() is False      # the real kernels, not the
+    b, s, _, _ = shape                   # interpreter
+    x = jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    args = [x, x, x]
+    if masked:
+        args.append(jax.ShapeDtypeStruct((b, s), jnp.bool_,
+                                         sharding=one_chip))
+
+    def loss_and_grads(q, k, v, m=None):
+        return jax.value_and_grad(
+            lambda q, k, v: fa.flash_attention(
+                q, k, v, causal=causal, kv_mask=m
+            ).astype(jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
+
+    text = compiled_text(loss_and_grads, *args)
+    assert text.count("tpu_custom_call") == 3, text.count("tpu_custom_call")
+
+
+def test_dispatch_shards_kernel_over_four_chips(topo, monkeypatch):
+    """scaled_dot_product_attention under a dp2 x mp2 mesh at the
+    GPT-345M shape: the kernel must arrive wrapped in shard_map, or the
+    compiler refuses it ("Mosaic kernels cannot be automatically
+    partitioned"). The dispatch asks which backend is the default — the
+    CPU, in this process — so the test answers for it."""
+    from paddle_tpu.distributed import build_mesh, topology
+    from paddle_tpu.nn.functional import attention as A
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(topology, "_GLOBAL_MESH", None)
+    mesh = build_mesh(dp=2, mp=2, devices=list(topo.devices))
+    sh = NamedSharding(mesh, P("data", None, "model", None))
+    x = jax.ShapeDtypeStruct((8, 1024, 16, 64), jnp.bfloat16, sharding=sh)
+
+    def loss_and_grads(q, k, v):
+        return jax.value_and_grad(
+            lambda q, k, v: A.scaled_dot_product_attention(
+                q, k, v, is_causal=True).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2))(q, k, v)
+
+    text = compiled_text(loss_and_grads, x, x, x)
+    assert text.count("tpu_custom_call") == 3
+
+
+def test_dispatch_keeps_one_chip_under_a_stale_mesh(topo, one_chip,
+                                                    monkeypatch):
+    """A step traced for one device (`mesh_scope`) while a four-device
+    mesh from an earlier step is still the global one: the kernel must
+    compile for the one chip, unwrapped."""
+    from paddle_tpu.distributed import build_mesh, topology
+    from paddle_tpu.nn.functional import attention as A
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(topology, "_GLOBAL_MESH", None)
+    one = build_mesh(devices=list(topo.devices)[:1])
+    build_mesh(dp=2, mp=2, devices=list(topo.devices))   # the stale one
+    x = jax.ShapeDtypeStruct((8, 1024, 16, 64), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def loss_and_grads(q, k, v):
+        with topology.mesh_scope(one):
+            return jax.value_and_grad(
+                lambda q, k, v: A.scaled_dot_product_attention(
+                    q, k, v, is_causal=True).astype(jnp.float32).sum(),
+                argnums=(0, 1, 2))(q, k, v)
+
+    assert "shard_map" not in str(jax.make_jaxpr(loss_and_grads)(x, x, x))
+    text = compiled_text(loss_and_grads, x, x, x)
+    assert text.count("tpu_custom_call") == 3
