@@ -10,6 +10,10 @@ Top-level namespace mirrors `python/paddle/__init__.py` of the reference.
 """
 from __future__ import annotations
 
+import time as _time
+
+_import_t0 = _time.perf_counter_ns()
+
 __version__ = "0.1.0"
 
 from . import core  # noqa: F401
@@ -151,3 +155,9 @@ monkey_patch_tensor()   # like the reference, patch at import
 from .static.program import _install_dispatch as _isd  # noqa: E402
 _isd()
 del _isd
+
+# what importing the package took, first line to last, as a span of the
+# program's profiler (`profiler.spans()`)
+profiler.record_span("import.paddle_tpu", _import_t0,
+                     _time.perf_counter_ns())
+del _import_t0

@@ -17,10 +17,19 @@ before their first compile. The rule:
 `tests/conftest.py` applies the same rule through the environment
 variable (it has to, before `jax` is imported, so that the tests'
 subprocesses inherit it).
+
+`enable()` also makes every compile of the process visible: JAX reports
+what tracing, lowering and the backend compile (or the read from this
+cache) of each program took, and each report becomes a span of the
+program's profiler, `compile.trace`, `compile.lower` or
+`compile.backend`, with the function's name as its `detail`; the counters
+`compile.requests` and `compile.cache_hits` of `profiler.stats.REGISTRY`
+count them.
 """
 from __future__ import annotations
 
 import os
+import time
 
 # <checkout>/.jax_cache — the directory that holds the paddle_tpu package
 DEFAULT_DIR = os.path.join(
@@ -28,8 +37,48 @@ DEFAULT_DIR = os.path.join(
         os.path.abspath(__file__)))), ".jax_cache")
 
 
+_SPAN_OF_EVENT = {
+    "/jax/core/compile/jaxpr_trace_duration": "compile.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "compile.lower",
+    "/jax/core/compile/backend_compile_duration": "compile.backend",
+}
+_listening = False
+
+
+def _listen() -> None:
+    """Register the listeners, once a process (JAX has no way to take
+    one back)."""
+    global _listening
+    if _listening:
+        return
+    _listening = True
+    import jax
+
+    from .. import profiler
+
+    counters = profiler.stats.REGISTRY
+
+    def duration(event, seconds, fun_name="", **_kw):
+        name = _SPAN_OF_EVENT.get(event)
+        if name is None:
+            return
+        now = time.perf_counter_ns()
+        profiler.record_span(name, now - int(seconds * 1e9), now,
+                             detail=str(fun_name))
+        if name == "compile.backend":
+            counters.counter("compile.requests").add()
+
+    def event(name, **_kw):
+        if name == "/jax/compilation_cache/cache_hits":
+            counters.counter("compile.cache_hits").add()
+
+    jax.monitoring.register_event_duration_secs_listener(duration)
+    jax.monitoring.register_event_listener(event)
+
+
 def enable() -> str:
     """Turn the persistent cache on and return the directory in use."""
+    _listen()
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env

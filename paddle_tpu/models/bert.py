@@ -23,6 +23,7 @@ from ..nn.layer_conv_norm import LayerNorm
 from ..distributed.meta_parallel.mp_layers import (
     ColumnParallelLinear, RowParallelLinear, VocabParallelEmbedding,
     _constrain)
+from ..profiler import ATTN, MLM_HEAD, MLP, RecordEvent
 
 
 @dataclasses.dataclass
@@ -106,15 +107,17 @@ class BertEncoderLayer(Layer):
     def forward(self, x, attn_mask=None):
         b, s, d = x.shape
         h, hd = self.num_heads, self.head_dim
-        qkv = jnp.reshape(self.qkv(x), (b, s, 3, h, hd))
-        q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-        attn = F.scaled_dot_product_attention(q, k, v, attn_mask=attn_mask,
-                                              training=self.training)
-        attn = jnp.reshape(attn, (b, s, d))
-        x = self.ln1(x + self.dropout(self.out_proj(attn)))
-        y = self.fc2(F.gelu(self.fc1(x.astype(self._dtype_)),
-                            approximate=True))
-        return self.ln2(x + self.dropout(y)).astype(self._dtype_)
+        with jax.named_scope(ATTN):
+            qkv = jnp.reshape(self.qkv(x), (b, s, 3, h, hd))
+            q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+            attn = F.scaled_dot_product_attention(
+                q, k, v, attn_mask=attn_mask, training=self.training)
+            attn = jnp.reshape(attn, (b, s, d))
+            x = self.ln1(x + self.dropout(self.out_proj(attn)))
+        with jax.named_scope(MLP):
+            y = self.fc2(F.gelu(self.fc1(x.astype(self._dtype_)),
+                                approximate=True))
+            return self.ln2(x + self.dropout(y)).astype(self._dtype_)
 
 
 class BertPooler(Layer):
@@ -158,6 +161,7 @@ class BertPretrainingHeads(Layer):
                                                   is_bias=True)
         self.seq_relationship = Linear(cfg.hidden_size, 2)
 
+    @jax.named_scope(MLM_HEAD)
     def forward(self, sequence_output, pooled_output, embedding_weight,
                 masked_positions=None):
         # embedding_weight passed (not stored) so the tied table stays a
@@ -180,6 +184,7 @@ class BertPretrainingHeads(Layer):
 
 
 class BertForPretraining(Layer):
+    @RecordEvent("model.build")
     def __init__(self, cfg_or_model):
         super().__init__()
         self.bert = (cfg_or_model if isinstance(cfg_or_model, BertModel)

@@ -15,7 +15,6 @@ same architecture is built TPU-first:
 from __future__ import annotations
 
 import dataclasses
-import functools
 import warnings
 from typing import Any, Dict, Optional, Tuple
 
@@ -34,6 +33,8 @@ from ..distributed.meta_parallel.mp_layers import (
 from ..distributed.meta_parallel.stacked_pipeline import (
     one_f_one_b, pipelined_apply, stack_stage_params)
 from ..distributed.topology import mesh_scope
+from ..profiler import (ATTN, CLIP, DECODER, EMBED, LM_LOSS, MLP, OPTIMIZER,
+                        RecordEvent)
 
 
 @dataclasses.dataclass
@@ -127,10 +128,15 @@ class GPTDecoderLayer(Layer):
         self._dtype_ = dt
 
     def forward(self, x):
+        with jax.named_scope(ATTN):
+            x = self._attn(x)
+        with jax.named_scope(MLP):
+            y = self.fc2(F.gelu(self.fc1(self.ln2(x)), approximate=True))
+            return x + self.dropout(y).astype(x.dtype)
+
+    def _attn(self, x):
         b, s, d = x.shape
         h, hd = self.num_heads, self.head_dim
-        dt = x.dtype
-        res = x
         qkv = self.qkv(self.ln1(x))   # LN in fp32, matmul in compute dtype
         qkv = jnp.reshape(qkv, (b, s, 3, h, hd))
         # heads sharded over 'model' (column shards = contiguous head groups)
@@ -148,10 +154,7 @@ class GPTDecoderLayer(Layer):
         from jax.ad_checkpoint import checkpoint_name
         attn = checkpoint_name(attn, "attn_out")
         attn = jnp.reshape(attn, (b, s, d))
-        x = res + self.dropout(self.out_proj(attn)).astype(dt)
-        res = x
-        y = self.fc2(F.gelu(self.fc1(self.ln2(x)), approximate=True))
-        return res + self.dropout(y).astype(dt)
+        return x + self.dropout(self.out_proj(attn)).astype(x.dtype)
 
 
 class GPTEmbeddings(Layer):
@@ -211,6 +214,7 @@ class GPTPretrainingCriterion(Layer):
 
 
 class GPTForPretraining(Layer):
+    @RecordEvent("model.build")
     def __init__(self, cfg_or_model):
         super().__init__()
         if isinstance(cfg_or_model, GPTModel):
@@ -282,6 +286,7 @@ def _outer_specs(model: GPTForPretraining):
     return out
 
 
+@RecordEvent("build_train_step")   # one frame more: warnings below say 3
 def build_train_step(model: GPTForPretraining, optimizer, mesh,
                      num_microbatches: int = 1, remat: bool = True,
                      donate: bool = True, pipeline_schedule: str = "gpipe",
@@ -328,7 +333,7 @@ def build_train_step(model: GPTForPretraining, optimizer, mesh,
         warnings.warn(
             f"num_microbatches={num_microbatches} < pipeline stages "
             f"{pp}: the schedule needs at least one microbatch per stage; "
-            f"using {pp}", stacklevel=2)
+            f"using {pp}", stacklevel=3)
     if sp > 1:
         # sequence parallelism composes with dp x tp x zero AND pp: the
         # pipeline schedules split the BATCH dim into microbatches while
@@ -339,11 +344,12 @@ def build_train_step(model: GPTForPretraining, optimizer, mesh,
         if loss_chunks > 1:
             warnings.warn("loss_chunks disabled under sequence "
                           "parallelism (the chunk scan would re-slice the "
-                          "sequence-sharded dim)", stacklevel=2)
+                          "sequence-sharded dim)", stacklevel=3)
             loss_chunks = 0
 
-    outer, block_list = _split_params(model)
-    stacked = stack_stage_params(block_list)  # leaves [L, ...]
+    with RecordEvent("build_train_step.stack"):
+        outer, block_list = _split_params(model)
+        stacked = stack_stage_params(block_list)  # leaves [L, ...]
     master_src = (outer, stacked)  # pre-cast fp32 leaves for master init
     if param_dtype is not None:
         # O2-style residency: params rest in param_dtype (bf16 halves
@@ -360,7 +366,7 @@ def build_train_step(model: GPTForPretraining, optimizer, mesh,
             warnings.warn(
                 "param_dtype set without optimizer multi_precision=True: "
                 "no fp32 master weights — low-precision updates will "
-                "accumulate rounding error", stacklevel=2)
+                "accumulate rounding error", stacklevel=3)
     template = model.gpt.layers[0]
 
     def block_apply(bparams, x):
@@ -404,6 +410,7 @@ def build_train_step(model: GPTForPretraining, optimizer, mesh,
             template._sp_attention = None
         return out
 
+    @jax.named_scope(DECODER)
     def stage_blocks(stage_p, h, key=None):
         """One pipeline stage = scan over its L/pp blocks (shared by the
         gpipe and 1f1b schedules). `key` (when dropout > 0) is split into
@@ -436,6 +443,7 @@ def build_train_step(model: GPTForPretraining, optimizer, mesh,
 
     seq_axis = "sequence" if sp > 1 else None
 
+    @jax.named_scope(EMBED)
     def embed_fwd(input_ids, position_ids=None):
         x = model.gpt.embeddings(input_ids, position_ids)
         return _constrain(x, ("data", "sharding"), seq_axis, None)
@@ -480,6 +488,7 @@ def build_train_step(model: GPTForPretraining, optimizer, mesh,
                                num_microbatches=max(num_microbatches, pp),
                                remat=False, rng_key=key)
 
+    @jax.named_scope(LM_LOSS)
     def lm_loss(hidden, labels):
         """ln_f → tied-head logits → CE. With loss_chunks > 1 the [B,S,V]
         fp32 logits tensor never materializes: a checkpointed scan over
@@ -554,7 +563,8 @@ def build_train_step(model: GPTForPretraining, optimizer, mesh,
         # whole optimizer HBM the offload exists to avoid
         opt_state0 = jax.eval_shape(optimizer.init_state, flatname_params)
     else:
-        opt_state0 = optimizer.init_state(flatname_params)
+        with RecordEvent("build_train_step.opt_init"):
+            opt_state0 = optimizer.init_state(flatname_params)
         if param_dtype is not None:
             # masters must come from the PRE-cast fp32 weights — fp32
             # (bf16(w)) throws away the mantissa bits the masters exist
@@ -649,7 +659,7 @@ def build_train_step(model: GPTForPretraining, optimizer, mesh,
                     return loss_fn(params, batch_)
             return jax.value_and_grad(lf)(params_pair, batch)
 
-    def step(state, batch, rng=None):
+    def gpt_train_step(state, batch, rng=None):
         if cfg.dropout > 0.0 and rng is None:
             # without a key the dropout draws would fall back to the
             # process-global RNG: one constant mask baked into the
@@ -754,22 +764,20 @@ def build_train_step(model: GPTForPretraining, optimizer, mesh,
         {n: ns(s) for n, s in stacked_param_specs.items()},
         opt_state_shardings)
 
-    if cfg.dropout > 0.0:
-        step_jit = jax.jit(
-            step,
-            in_shardings=(state_shardings, batch_sharding, None),
-            out_shardings=(state_shardings, None),
-            donate_argnums=(0,) if donate else ())
-    else:
-        step_jit = jax.jit(
-            functools.partial(step, rng=None),
-            in_shardings=(state_shardings, batch_sharding),
-            out_shardings=(state_shardings, None),
-            donate_argnums=(0,) if donate else ())
+    # the jitted function's name is the compiled module's ("jit_<name>"),
+    # which is how a trace or a compile log tells the step program from
+    # every other; without dropout it is called without a key
+    step_jit = jax.jit(
+        gpt_train_step,
+        in_shardings=(state_shardings, batch_sharding)
+        + ((None,) if cfg.dropout > 0.0 else ()),
+        out_shardings=(state_shardings, None),
+        donate_argnums=(0,) if donate else ())
 
     # place initial state
-    state0 = jax.device_put(
-        (outer, stacked, opt_state0), state_shardings)
+    with RecordEvent("build_train_step.place"):
+        state0 = jax.device_put(
+            (outer, stacked, opt_state0), state_shardings)
     return step_jit, state0
 
 
@@ -929,7 +937,7 @@ def _build_offload_chunked_step(*, cfg, optimizer, outer, stacked,
     g_stacked_shardings = {n: ns(opt_spec(f"blocks.{n}", stacked[n]))
                            for n in stacked}
 
-    def grad_phase(params_pair, opt_step, batch, rng=None):
+    def gpt_offload_grad(params_pair, opt_step, batch, rng=None):
         loss, (g_outer, g_stacked) = loss_and_grads(params_pair, batch,
                                                     rng)
         flat_g = dict(g_outer)
@@ -942,7 +950,8 @@ def _build_offload_chunked_step(*, cfg, optimizer, outer, stacked,
         if optimizer._grad_clip is not None:
             # global-norm clip sees the FULL grad set here; the per-chunk
             # updates below must not clip again
-            flat_g = optimizer._grad_clip(flat_g)
+            with jax.named_scope(OPTIMIZER), jax.named_scope(CLIP):
+                flat_g = optimizer._grad_clip(flat_g)
         g_outer = {n: flat_g[n] for n in g_outer}
         g_stacked = {n: flat_g[f"blocks.{n}"] for n in g_stacked}
         return loss, g_outer, g_stacked, opt_step + 1
@@ -952,14 +961,14 @@ def _build_offload_chunked_step(*, cfg, optimizer, outer, stacked,
                       batch_sharding),
         out_shardings=(None, g_outer_shardings, g_stacked_shardings,
                        ns(P())))
+    # named as `build_train_step`'s step is, one name per program
     if cfg.dropout > 0.0:
         grad_kwargs["in_shardings"] = grad_kwargs["in_shardings"] + (None,)
-        grad_jit = jax.jit(grad_phase, **grad_kwargs)
-    else:
-        grad_jit = jax.jit(functools.partial(grad_phase, rng=None),
-                           **grad_kwargs)
+    grad_jit = jax.jit(gpt_offload_grad, **grad_kwargs)
 
-    def chunk_update(stacked_p, g_stacked, slots_chunk, new_step, start):
+    @jax.named_scope(OPTIMIZER)
+    def gpt_offload_chunk(stacked_p, g_stacked, slots_chunk, new_step,
+                          start):
         p_c = {f"blocks.{n}": jax.lax.dynamic_slice_in_dim(v, start, k, 0)
                for n, v in stacked_p.items()}
         g_c = {f"blocks.{n}":
@@ -980,75 +989,68 @@ def _build_offload_chunked_step(*, cfg, optimizer, outer, stacked,
     # multi-device meshes, and outside-jit copies dispatch async anyway,
     # pipelining chunk i+1's upload behind chunk i's compute
     chunk_jit = jax.jit(
-        chunk_update,
+        gpt_offload_chunk,
         in_shardings=(stacked_shardings, g_stacked_shardings,
                       chunk_slot_dev, ns(P()), None),
         out_shardings=(stacked_shardings, chunk_slot_dev),
         donate_argnums=(0, 2) if donate else ())
 
-    def outer_update(outer_p, g_outer, outer_slots, new_step):
+    @jax.named_scope(OPTIMIZER)
+    def gpt_offload_outer(outer_p, g_outer, outer_slots, new_step):
         return optimizer.apply_named(outer_p, g_outer, outer_slots,
                                      new_step)
 
     outer_jit = jax.jit(
-        outer_update,
+        gpt_offload_outer,
         in_shardings=(outer_shardings, g_outer_shardings,
                       outer_slot_dev, ns(P())),
         out_shardings=(outer_shardings, outer_slot_dev),
         donate_argnums=(0, 2) if donate else ())
-
-    import os as _os
-    _sync = _os.environ.get("PTPU_OFFLOAD_SYNC") == "1"
-
-    def _trace(tag, value):
-        if _sync:
-            jax.block_until_ready(value)
-            print(f"offload-step: {tag} done", flush=True)
 
     def step_fn(state, batch, rng=None):
         if cfg.dropout > 0.0 and rng is None:
             raise ValueError(
                 "cfg.dropout > 0 requires step(state, batch, rng_key) — "
                 "pass a fresh jax.random key every step")
+        # the spans are the host's side of each phase (dispatch, the
+        # transfers it starts and the wait for room); the device's side
+        # is the three named programs in a trace
         outer_p, stacked_p, opt_state = state
-        if cfg.dropout > 0.0:
+        with RecordEvent("offload.grad"):
             loss, g_outer, g_stacked, new_step = grad_jit(
-                (outer_p, stacked_p), opt_state["step"], batch, rng)
-        else:
-            loss, g_outer, g_stacked, new_step = grad_jit(
-                (outer_p, stacked_p), opt_state["step"], batch)
-        _trace("grad", loss)
+                (outer_p, stacked_p), opt_state["step"], batch,
+                *((rng,) if cfg.dropout > 0.0 else ()))
         slots = opt_state["slots"]
         new_stacked = stacked_p
         chunk_results = []
         for ci in range(n_chunks):
-            if ci >= 2:
-                # backpressure: dispatch is async, so without this the
-                # Python loop uploads EVERY chunk's slots before the
-                # first update frees any — the whole optimizer state
-                # lands on device at once and the step OOMs exactly
-                # like the unchunked version. Once chunk ci-2's new
-                # slots are back at rest on the host, its update has
-                # executed and its donated device buffers are free, so
-                # at most ~2 chunks of slots are in flight on device
-                jax.block_until_ready(chunk_results[ci - 2])
-            slots_chunk = jax.device_put(
-                {n: {sname: slots[n][sname][ci] for sname in slots[n]}
-                 for n in stacked_slot_names}, chunk_slot_dev)
-            new_stacked, new_chunk = chunk_jit(
-                new_stacked, g_stacked, slots_chunk, new_step, starts[ci])
-            # back to host residence; dropping the device ref frees the
-            # chunk's HBM before chunk ci+2 uploads
-            chunk_results.append(
-                jax.device_put(new_chunk, chunk_slot_shardings))
-            _trace(f"chunk {ci}/{n_chunks}", chunk_results[-1])
-        outer_slots = jax.device_put(
-            {n: slots[n] for n in outer_slot_names}, outer_slot_dev)
-        new_outer, new_outer_slots = outer_jit(outer_p, g_outer,
-                                               outer_slots, new_step)
-        _trace("outer", new_outer_slots)
-        new_outer_slots = jax.device_put(new_outer_slots,
-                                         outer_slot_shardings)
+            with RecordEvent("offload.chunk"):
+                if ci >= 2:
+                    # backpressure: dispatch is async, so without this the
+                    # Python loop uploads EVERY chunk's slots before the
+                    # first update frees any — the whole optimizer state
+                    # lands on device at once and the step OOMs exactly
+                    # like the unchunked version. Once chunk ci-2's new
+                    # slots are back at rest on the host, its update has
+                    # executed and its donated device buffers are free, so
+                    # at most ~2 chunks of slots are in flight on device
+                    jax.block_until_ready(chunk_results[ci - 2])
+                slots_chunk = jax.device_put(
+                    {n: {sname: slots[n][sname][ci] for sname in slots[n]}
+                     for n in stacked_slot_names}, chunk_slot_dev)
+                new_stacked, new_chunk = chunk_jit(
+                    new_stacked, g_stacked, slots_chunk, new_step, starts[ci])
+                # back to host residence; dropping the device ref frees the
+                # chunk's HBM before chunk ci+2 uploads
+                chunk_results.append(
+                    jax.device_put(new_chunk, chunk_slot_shardings))
+        with RecordEvent("offload.outer"):
+            outer_slots = jax.device_put(
+                {n: slots[n] for n in outer_slot_names}, outer_slot_dev)
+            new_outer, new_outer_slots = outer_jit(outer_p, g_outer,
+                                                   outer_slots, new_step)
+            new_outer_slots = jax.device_put(new_outer_slots,
+                                             outer_slot_shardings)
         new_slots = {n: {sname: tuple(cr[n][sname]
                                       for cr in chunk_results)
                          for sname in slots[n]}

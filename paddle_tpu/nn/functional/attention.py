@@ -17,8 +17,10 @@ import jax.numpy as jnp
 
 from ...core.flags import flag
 from ...ops.flash_attention import flash_attention
+from ...profiler import ATTENTION
 
 
+@jax.named_scope(ATTENTION)   # mask handling, layout changes, the kernel
 def scaled_dot_product_attention(query, key, value, attn_mask=None,
                                  dropout_p=0.0, is_causal=False,
                                  training=True, scale=None):

@@ -22,6 +22,7 @@ import numpy as np
 
 from ..core import enforce
 from ..core.dtypes import convert_dtype, get_default_dtype
+from ..profiler import RecordEvent
 
 
 class Parameter:
@@ -335,6 +336,7 @@ class Layer:
             out[name] = b if keep_vars else b.value
         return out
 
+    @RecordEvent("set_state_dict")
     def set_state_dict(self, state_dict, use_structured_name=True):
         missing, unexpected = [], set(state_dict.keys())
         for name, slot in list(self.named_parameters()) + \

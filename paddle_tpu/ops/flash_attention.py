@@ -29,6 +29,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..profiler import FLASH_BWD_DKV, FLASH_BWD_DQ, FLASH_FWD
+
 # Row statistics (lse/delta) ride an 8-lane broadcast: TPU block layouts
 # need the last two dims (sublane, lane) to divide (8, 128) or equal the
 # array dims — a trailing dim of 8 equals itself, keeping the stat arrays
@@ -148,6 +150,10 @@ def _fwd(q3, k3, v3, causal, scale, mask3=None, heads=1):
                         pltpu.VMEM((blk, 128), jnp.float32)],
         interpret=_interpret(),
         compiler_params=_COMPILER_PARAMS,
+        # the name is the innermost component of the kernel's `op_name`,
+        # and with it the compiled instruction's name ("%flash_fwd.N"):
+        # a trace tells the three kernels apart without knowing shapes
+        name=FLASH_FWD,
     )(*args)
     return o, lse
 
@@ -291,6 +297,7 @@ def _bwd_impl(causal, scale, res, g, mask3=None, heads=1):
         scratch_shapes=[pltpu.VMEM((blk, d), jnp.float32)],
         interpret=_interpret(),
         compiler_params=_COMPILER_PARAMS,
+        name=FLASH_BWD_DQ,
     )(*dq_args)[0]
 
     # grid dims: (bh, k_tile, q_tile) — q is the reduce (innermost) dim;
@@ -309,6 +316,7 @@ def _bwd_impl(causal, scale, res, g, mask3=None, heads=1):
                         pltpu.VMEM((blk, d), jnp.float32)],
         interpret=_interpret(),
         compiler_params=_COMPILER_PARAMS,
+        name=FLASH_BWD_DKV,
     )(*dkv_args)
     return dq, dk, dv
 

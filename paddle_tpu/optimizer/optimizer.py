@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from ..nn.layer import Layer, Parameter
+from ..profiler import CLIP, OPTIMIZER
 from .lr import LRScheduler
 
 
@@ -115,11 +116,13 @@ class Optimizer:
               grads: Dict[str, jax.Array],
               state: Dict[str, Any]):
         """Pure update: returns (new_params, new_state). Call inside jit."""
-        step = state["step"] + 1
-        if self._grad_clip is not None:
-            grads = self._grad_clip(grads)
-        new_params, new_slots = self.apply_named(params, grads,
-                                                 state["slots"], step)
+        with jax.named_scope(OPTIMIZER):
+            step = state["step"] + 1
+            if self._grad_clip is not None:
+                with jax.named_scope(CLIP):
+                    grads = self._grad_clip(grads)
+            new_params, new_slots = self.apply_named(params, grads,
+                                                     state["slots"], step)
         return new_params, {"step": step, "slots": new_slots}
 
     def apply_named(self, params: Dict[str, jax.Array],

@@ -1,49 +1,166 @@
-"""`paddle.profiler` equivalent.
+"""`paddle.profiler` equivalent, and the one place where the program names
+its own work.
 
-Host-side scoped events live in the native runtime
-(csrc/ptpu_runtime.cc Profiler ≈ `platform/profiler.h:127` RecordEvent);
-device-side timing comes from `jax.profiler` (XLA's tracer replaces the
-reference's CUPTI `DeviceTracer`, `platform/device_tracer.h:43`). Both
-export chrome://tracing-compatible traces (`tools/timeline.py` parity).
+Host spans: `RecordEvent` keeps every finished span in memory, on
+`time.perf_counter_ns` (the clock a caller's own timings use), and enters
+a `jax.profiler.TraceAnnotation`, so that while a jax trace runs the same
+span lies on the host plane of the `.xplane.pb`, on the device events'
+clock. While `start_profiler()` is on it also feeds the native ring
+(csrc/ptpu_runtime.cc Profiler ≈ `platform/profiler.h:127` RecordEvent),
+whose chrome://tracing dump the serving tools read (`tools/timeline.py`
+parity). Device-side timing comes from `jax.profiler` (XLA's tracer
+replaces the reference's CUPTI `DeviceTracer`,
+`platform/device_tracer.h:43`).
+
+Device names: the constants below are the names of the step programs, of
+the Pallas kernels and of the `jax.named_scope`s on the training path.
+They end up in every HLO instruction's `op_name`, and a kernel's name is
+its compiled instruction's name too, which is what the benchmark's trace
+readers match (`benchmarks/metrics/*.json`;
+`tests/test_train_path_names.py` holds the two together).
 """
 from __future__ import annotations
 
 import contextlib
 import functools
-import os
-from typing import Optional
+import itertools
+import threading
+import time
+from typing import NamedTuple, Optional
+
+from jax.profiler import TraceAnnotation
 
 from ..core import native
 from . import stats  # noqa: F401  (re-export: profiler.stats registry)
 
+# step programs (`jax.jit` of a function of this name: module `jit_<name>`)
+GPT_TRAIN_STEP = "gpt_train_step"
+GPT_OFFLOAD_GRAD = "gpt_offload_grad"
+GPT_OFFLOAD_CHUNK = "gpt_offload_chunk"
+GPT_OFFLOAD_OUTER = "gpt_offload_outer"
+# Pallas kernels (`pallas_call(name=...)`: the innermost component of the
+# kernel's `op_name`, and the compiled instruction's name, "%flash_fwd.16")
+FLASH_FWD = "flash_fwd"
+FLASH_BWD_DQ = "flash_bwd_dq"
+FLASH_BWD_DKV = "flash_bwd_dkv"
+KERNELS = (FLASH_FWD, FLASH_BWD_DQ, FLASH_BWD_DKV)
+# scopes, outermost first: the step's phases, a block's two halves, the
+# attention dispatch inside `attn`, the clip inside `optimizer`
+EMBED = "embed"
+DECODER = "decoder"
+LM_LOSS = "lm_loss"
+MLM_HEAD = "mlm_head"
+OPTIMIZER = "optimizer"
+CLIP = "clip"
+ATTN = "attn"
+MLP = "mlp"
+ATTENTION = "attention"
+
+
+class Span(NamedTuple):
+    """One finished host span, nanoseconds of `time.perf_counter_ns`.
+    `parent` is the id of the span that was open on the same thread when
+    this one started (0: none); `detail` says which of several (the
+    function a `compile.*` span compiled)."""
+    name: str
+    start_ns: int
+    end_ns: int
+    id: int
+    parent: int
+    detail: str = ""
+
+
+# the spans are few (set-up, compiles, whatever a caller scopes); a
+# caller that scopes every step of a long job keeps its first MAX_SPANS
+# and a count of the rest, until `reset()`
+MAX_SPANS = 1 << 16
+_spans: list = []
+_ids = itertools.count(1)
+_native_on = False   # between start_profiler() and stop_profiler()
+
+
+class _Open(threading.local):
+    """Ids of the spans open on this thread, outermost first."""
+
+    def __init__(self):
+        self.ids = []
+
+
+_open = _Open()
+
+
+def _keep(span: Span) -> None:
+    if len(_spans) < MAX_SPANS:
+        _spans.append(span)
+    else:
+        stats.REGISTRY.counter("profiler.spans_dropped").add()
+
+
+def record_span(name: str, start_ns: int, end_ns: int,
+                detail: str = "") -> None:
+    """Keep a span that was timed by other means (an import, a duration
+    JAX reports); its parent is the span open on this thread now."""
+    stack = _open.ids
+    _keep(Span(name, start_ns, end_ns, next(_ids),
+               stack[-1] if stack else 0, detail))
+
+
+def spans() -> list:
+    """Every span finished since the process started or `reset()`, in
+    the order they ended."""
+    return list(_spans)
+
 
 class RecordEvent:
-    """Scoped host event (reference: platform/profiler.h:127).
+    """Scoped host span (reference: platform/profiler.h:127), as context
+    manager, `begin()` / `end()` pair or decorator.
 
-    Usable as context manager or decorator; no-op when profiling is off or
-    the native lib is unavailable.
+    Always: one `Span` kept in memory (`spans()`), parent taken from the
+    spans open on this thread, and a `TraceAnnotation` of the same name,
+    which costs a fraction of a microsecond and shows in a jax trace when
+    one is running. Between `start_profiler()` and `stop_profiler()` the
+    span also goes to the native ring; outside it no native call is made.
+    Meant for work that runs once per process or per compile, not inside
+    a jitted step (there `jax.named_scope` names the device's work).
     """
 
     def __init__(self, name: str):
         self.name = name
-        self._t0 = None
+        self._native_t0 = None
 
     def __enter__(self):
-        if native.available():
-            self._t0 = native.lib().ptpu_profiler_now_us()
+        stack = _open.ids
+        self._parent = stack[-1] if stack else 0
+        self._id = next(_ids)
+        stack.append(self._id)
+        if _native_on:
+            self._native_t0 = native.lib().ptpu_profiler_now_us()
+        self._annotation = TraceAnnotation(self.name)
+        self._annotation.__enter__()
+        self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc):
-        if self._t0 is not None and native.available():
+        t1 = time.perf_counter_ns()
+        self._annotation.__exit__(*exc)
+        if self._native_t0 is not None:
             l = native.lib()
-            l.ptpu_profiler_record(self.name.encode(), self._t0,
+            l.ptpu_profiler_record(self.name.encode(), self._native_t0,
                                    l.ptpu_profiler_now_us())
+            self._native_t0 = None
+        stack = _open.ids
+        try:
+            # also closes children left open by an exception
+            del stack[stack.index(self._id):]
+        except ValueError:      # ended on another thread than it began
+            pass
+        _keep(Span(self.name, self._t0, t1, self._id, self._parent))
         return False
 
     begin = __enter__
 
     def end(self):
-        self.__exit__()
+        self.__exit__(None, None, None)
 
     def __call__(self, fn):
         """Decorator form: every call of `fn` runs inside a scoped
@@ -60,14 +177,18 @@ class RecordEvent:
 
 def start_profiler(tracer_option: str = "Default"):
     """Reference: fluid/profiler.py start_profiler."""
+    global _native_on
     if native.available():
         native.lib().ptpu_profiler_enable()
+        _native_on = True
 
 
 def stop_profiler(sorted_key: Optional[str] = None,
                   profile_path: str = "/tmp/profile"):
     """Dump host events as a chrome trace (reference writes profiler.proto;
     chrome trace is the rendered form both end up in)."""
+    global _native_on
+    _native_on = False
     if native.available():
         l = native.lib()
         l.ptpu_profiler_disable()
@@ -86,11 +207,14 @@ def profiler(tracer_option: str = "Default",
 
 
 def event_count() -> int:
+    """Events in the native ring (those recorded while profiling was on)."""
     return int(native.lib().ptpu_profiler_count()) if native.available() \
         else 0
 
 
 def reset():
+    """Forget the spans kept in memory and clear the native ring."""
+    del _spans[:]
     if native.available():
         native.lib().ptpu_profiler_clear()
 

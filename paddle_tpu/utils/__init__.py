@@ -138,7 +138,8 @@ class ProfilerOptions:
 
 class Profiler:
     """Reference: utils/profiler.py Profiler — start/stop facade over the
-    native profiler (csrc RecordEvent ring + chrome-trace export)."""
+    native profiler (the ring `RecordEvent` feeds while this runs, and
+    its chrome-trace export)."""
 
     def __init__(self, enabled=True, options=None):
         self.enabled = enabled
@@ -167,7 +168,11 @@ class Profiler:
         return False
 
     def record_step(self, change_profiler_status=True):
-        pass  # steps are delimited by RecordEvent scopes here
+        # nothing to mark: a step is whatever `RecordEvent` scope the
+        # caller puts around it, which keeps the span in memory
+        # (`profiler.spans()`) and, while this profiler runs, in the
+        # native ring's chrome dump
+        pass
 
 
 _profiler_singleton = None
