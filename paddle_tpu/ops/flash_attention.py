@@ -3,26 +3,47 @@
 Replaces the reference's inference-only fused attention CUDA kernels
 (`operators/fused/multihead_matmul_op.cu`,
 `operators/math/bert_encoder_functor.cu`) with a fused kernel that works in
-both directions: the S×S score matrix lives only tile-by-tile in VMEM, so
+both directions: the S×S score matrix lives only chunk-by-chunk in VMEM, so
 long sequences never materialize O(S²) in HBM.
 
 Layout contract: [batch, seq, heads, head_dim] (paddle 2.x attention
-layout); internally [b·h, s, d]. All three kernels (fwd, dq, dk/dv) walk a
-3-D grid (bh, out_tile, reduce_tile) with square seq tiles in VMEM and
-fp32 scratch accumulators — VMEM use is O(BLOCK·(BLOCK+d)) regardless of
-S, so the same kernel serves 1K and 64K tokens (and each ring-attention
-shard, sequence_parallel.py). The tile edge adapts to the sequence
-(512 → 256 → 128): big tiles keep the MXU busy and amortize the per-tile
-softmax bookkeeping (measured on v5e: 512-tiles ≈ 2x over 128-tiles at
-seq 1024). Matmul operands stay bf16 (fp32 operands run the MXU at 1/8
-rate); accumulation and softmax statistics are fp32. Row statistics
-(logsumexp/delta) ride an 8-lane broadcast because TPU block layouts need
-a lane-divisible trailing dim.
+layout); internally [b·h, s, d]. All three kernels (fwd, dq, dk/dv) run on
+a grid (bh, out_block, reduce_block) and share one skeleton (`_walk`). The
+resident block is the whole sequence while an operand of one head fits
+`_RESIDENT_BYTES` (2K tokens in bf16), so at 1K tokens the grid is
+(bh, 1, 1): K and V (dk/dv: Q and dO) are fetched once a head, nothing is
+carried between grid steps, and the running max / sum / accumulator are
+values written once. Longer sequences keep the outer axes with smaller
+blocks and carry that state between grid steps in fp32 scratch, so VMEM
+use is O(block·d) regardless of S and one kernel body serves 1K and 64K
+tokens. Inside a block the plane is walked in chunks of up to 1024 rows,
+one or two a side, as straight-line code, and a chunk's body is cut into
+groups of out-side rows (`_plan`); under a causal mask the walk stops
+(dk/dv: starts) at the chunk the diagonal crosses (`_chunk_spans`), only
+that chunk's body builds a mask, and each of its groups stops at its own
+diagonal (`_groups`): computed / needed scores at 1K tokens is 1.25
+(`score_work`), where 512 x 512 tiles computed 1.50.
+
+What sets the time, measured on v5e (PERF.md, PR 28): not the vector work
+per score element (dropping the mask, the scale or the `exp` from the
+old 512-tile kernels moved nothing) but how much independent work one
+basic block holds. A grid step or a `fori_loop` step drains the pipeline:
+128-row chunks in a loop took twice the time of 512-row chunks although
+they compute a quarter less, and the whole head as straight-line code
+takes half the time of 512-tiles on a (bh, 2, 2) grid. Around that:
+dk/dv computes the scores transposed (k qᵀ) so that pᵀ·dO and dsᵀ·q are
+plain matmuls, with lse and delta delivered along lanes (XLA lays lse out
+so, a small fusion a call outside the kernels). Matmul operands stay bf16
+(fp32 operands run the MXU at 1/8 rate); accumulation and softmax
+statistics are fp32. Row statistics (logsumexp/delta) ride an 8-lane
+broadcast between forward and dq because TPU block layouts need a
+lane-divisible trailing dim.
 """
 from __future__ import annotations
 
 import functools
 import math
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -38,12 +59,117 @@ from ..profiler import FLASH_BWD_DKV, FLASH_BWD_DQ, FLASH_FWD
 LANE = 8
 NEG_INF = -1e30
 
+# The most one resident operand block may take of VMEM, counted as it lies
+# there (rows x the head dim padded to 128 lanes x the operand's bytes).
+# dq holds five such blocks and two columns of statistics, all
+# double-buffered: 5 MiB + 4 MiB of the 16 MiB a kernel may use on a v5e,
+# beside a group's scores.
+_RESIDENT_BYTES = 512 * 1024
 
-def _block_for(s: int) -> int:
-    for b in (512, 256, 128):
-        if s % b == 0 and s >= b:
-            return b
-    raise ValueError(f"flash_attention needs seq % 128 == 0, got {s}")
+
+class Plan(NamedTuple):
+    """How the kernels walk the (q, k) plane of one head."""
+    block: int      # rows of q and of k resident in VMEM per grid step
+    chunk: int      # rows of q and of k per chunk of the walk inside
+    sub: int        # out-side rows per group of a chunk
+
+
+def _plan(s: int, d: int, dtype, causal: bool) -> Plan:
+    """Resident block, chunk and group from what the call can see.
+
+    The block is the whole sequence while an operand of one head fits
+    `_RESIDENT_BYTES`, else the largest 128-multiple divisor of `s` that
+    fits half of it. The chunk is as large as divides the block, up to
+    `_CHUNK`, and a block is one or two chunks a side, walked as
+    straight-line code, which the scheduler overlaps where a loop step
+    drains the pipeline (PERF.md, PR 28: 128-row chunks in a `fori_loop`
+    took twice the time of 512-row chunks over a smaller area); a block
+    that would take more chunks (s = 1152) gives way to blocks of one
+    chunk on the grid. A chunk's body is cut into groups of out-side rows,
+    so that what it holds at a time is a group's scores, [sub, chunk] fp32
+    within `_GROUP_SCORES`. Under a causal mask the groups also win the
+    area, since each stops at its own diagonal (`_groups`), and are
+    `_CAUSAL_SUB` rows at most."""
+    if s % 128 != 0 or s < 128:
+        raise ValueError(f"flash_attention needs seq % 128 == 0, got {s}")
+    row_bytes = -(-d // 128) * 128 * jnp.dtype(dtype).itemsize
+    rows = _RESIDENT_BYTES // row_bytes
+    if s > rows:    # several blocks: their carried state needs room too
+        rows //= 2
+    block = _divisor(s, rows)
+    chunk = _divisor(block, _CHUNK)
+    if block > 2 * chunk:
+        block = chunk
+    sub = _divisor(chunk, _GROUP_SCORES // chunk)
+    return Plan(block, chunk, _divisor(sub, _CAUSAL_SUB) if causal else sub)
+
+
+def _divisor(n: int, most: int) -> int:
+    """The largest multiple of 128 that divides `n` and is at most `most`
+    (128 where there is none)."""
+    return max(b for b in range(128, max(128, min(n, most)) + 1, 128)
+               if n % b == 0)
+
+
+# Measured at [128, 1024, 64] causal and [256, 512, 64] masked on a v5e
+# (PERF.md, PR 28).
+_CHUNK = 1024
+_GROUP_SCORES = 256 * 1024
+_CAUSAL_SUB = 256
+
+
+def _chunk_spans(o0, c, n, causal, before):
+    """The reduce-side chunks (of `c` rows, `n` in all) that the out-side
+    rows [o0, o0 + c) visit: (full_lo, full_hi, diag_lo, diag_hi), two
+    half-open ranges of chunk indices — chunks clear of the diagonal, and
+    the one it crosses. `before`: the reduce side is k and the out side q
+    (forward, dq), so the full chunks lie before the diagonal; else the
+    reduce side is q (dk/dv) and they lie after it. The kernels' walk and
+    `score_work` both come from here."""
+    if not causal:
+        return 0, n, 0, 0
+    d = o0 // c
+    return (0, d, d, d + 1) if before else (d + 1, n, d, d + 1)
+
+
+def _groups(c, sub, before, diag):
+    """A chunk (c x c) cut into groups of `sub` out-side rows: (out_lo,
+    red_lo, red_hi) — out rows [out_lo, out_lo + sub) against the reduce
+    rows [red_lo, red_hi). In the chunk the diagonal crosses (its corner
+    lies on it) a group needs nothing beyond its own diagonal. Static, so
+    a chunk's body is straight-line code."""
+    if not diag:
+        return [(g, 0, c) for g in range(0, c, sub)]
+    return [(g, 0, g + sub) if before else (g, g, c)
+            for g in range(0, c, sub)]
+
+
+class ScoreWork(NamedTuple):
+    chunks: int         # chunk bodies one head runs
+    masked_chunks: int  # of them, the body that applies the causal mask
+    ratio: float        # computed / needed score elements
+
+
+def score_work(s: int, causal: bool, d: int = 64, dtype=jnp.bfloat16,
+               transposed: bool = False) -> ScoreWork:
+    """What one head costs under `_plan`'s schedule, counted from the same
+    spans and groups the kernels are built from. `transposed`: the dk/dv
+    walk (k chunks over q chunks) instead of the forward's and dq's."""
+    _, c, sub = _plan(s, d, dtype, causal)
+    before = not transposed
+    full = diag = 0
+    for o0 in range(0, s, c):
+        f_lo, f_hi, d_lo, d_hi = _chunk_spans(o0, c, s // c, causal, before)
+        full += f_hi - f_lo
+        diag += d_hi - d_lo
+
+    def scores(on_diag):
+        return sum(sub * (hi - lo)
+                   for _, lo, hi in _groups(c, sub, before, on_diag))
+
+    computed = full * scores(False) + diag * scores(True)
+    needed = s * (s + 1) // 2 if causal else s * s
+    return ScoreWork(full + diag, diag, computed / needed)
 
 
 def _interpret() -> bool:
@@ -59,95 +185,197 @@ def _interpret() -> bool:
 _COMPILER_PARAMS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel", "arbitrary"))
 
+_NT = (((1,), (1,)), ((), ()))      # a bᵀ: contract the last dim of both
+_NN = (((1,), (0,)), ((), ()))      # a b
+
+
+def _dot(a, b, dims):
+    return jax.lax.dot_general(a, b, dims,
+                               preferred_element_type=jnp.float32)
+
+
+def _rows(ref, start, size):
+    """Rows [start, start + size) of a [1, rows, d] block."""
+    return ref[0, pl.ds(start, size), :]
+
+
+def _keep_tri(rows, cols, off, before):
+    """The causal mask of a diagonal group: keep where q_pos >= k_pos.
+    `before`: q along rows from `off`, k along columns from 0; else k
+    along rows and q along columns from the same position."""
+    a = jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 0)
+    b = jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 1)
+    return a + off >= b if before else b >= a
+
+
+def _walk(plan, n, causal, before, out_id, red_id, init, scratch, prep,
+          piece, finalize):
+    """The skeleton the three kernels share. The resident out block is cut
+    into chunks; each takes its carry (from `scratch` where the reduce
+    side has more than one block, else `init`: (fill, width) per value),
+    meets the resident reduce chunks `_chunk_spans` names in rising
+    order, group by group (`_groups`), through `piece(ctx, j, g, lo, hi,
+    tri, rows)` -> the group's new rows of the carry (`tri`: apply the
+    causal mask), and hands the carry to `finalize(ctx, r, carry)` after
+    its last reduce block. `ctx = prep(r)` is what a chunk of out rows
+    needs once. Of several reduce blocks under a causal mask only the one
+    on the diagonal is walked causally and the ones clear of it in full,
+    so the spans inside a block are static either way."""
+    block, c, sub = plan
+    if n > 1:
+        @pl.when(red_id == 0)
+        def _init():
+            for ref, (fill, _) in zip(scratch, init):
+                ref[:] = jnp.full_like(ref, fill)
+
+    def meet(ctx, diag, j, carry):
+        parts = [piece(ctx, j, g, lo, hi, diag,
+                       tuple(x[g:g + sub] for x in carry))
+                 for g, lo, hi in _groups(c, sub, before, diag)]
+        return tuple(jnp.concatenate(xs, axis=0) for xs in zip(*parts))
+
+    def out_chunk(r, on_diag, last):
+        ctx = prep(r)
+        f_lo, f_hi, d_lo, d_hi = _chunk_spans(r, c, block // c, on_diag,
+                                              before)
+        if n > 1:
+            carry = tuple(ref[pl.ds(r, c), :] for ref in scratch)
+        else:
+            carry = tuple(jnp.full((c, w), fill, jnp.float32)
+                          for fill, w in init)
+        spans = [(f_lo, f_hi, False), (d_lo, d_hi, True)]
+        for lo, hi, diag in spans if before else spans[::-1]:
+            for j in range(lo, hi):
+                carry = meet(ctx, diag, j, carry)
+        if n > 1:
+            for ref, x in zip(scratch, carry):
+                ref[pl.ds(r, c), :] = x
+        if isinstance(last, bool):
+            if last:
+                finalize(ctx, r, carry)
+        else:
+            pl.when(last)(lambda: finalize(ctx, r, carry))
+
+    def walk(on_diag, last):
+        for r in range(0, block, c):
+            out_chunk(r, on_diag, last)
+
+    if n == 1:
+        walk(causal, True)
+    elif not causal:
+        walk(False, red_id == n - 1)
+    else:
+        # forward and dq end a q block at its diagonal k block; dk/dv
+        # begins a k block there and ends at the last q block
+        pl.when(red_id == out_id)(
+            lambda: walk(True, True if before else red_id == n - 1))
+        pl.when(red_id < out_id if before else red_id > out_id)(
+            lambda: walk(False, False if before else red_id == n - 1))
+
 
 # ---------------------------------------------------------------- forward
 
-def _fwd_kernel(q_ref, k_ref, v_ref, *refs, causal, scale, nk,
-                masked=False):
-    if masked:
-        mask_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref = refs
-    else:
-        o_ref, lse_ref, acc_ref, m_ref, l_ref = refs
-        mask_ref = None
-    iq, jk = pl.program_id(1), pl.program_id(2)
+def _fwd_kernel(q_ref, k_ref, v_ref, *refs, causal, scale, plan, n, masked):
+    refs = list(refs)
+    mask_ref = refs.pop(0) if masked else None
+    o_ref, lse_ref = refs[:2]
+    _, c, sub = plan
+    d = q_ref.shape[-1]
 
-    @pl.when(jk == 0)
-    def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
+    def prep(r):
+        return _rows(q_ref, r, c)
 
-    @pl.when(jnp.logical_or(not causal, jk <= iq))
-    def _compute():
-        q = q_ref[0]                                      # [BQ, d] bf16
-        k = k_ref[0]
-        v = v_ref[0]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        bq, bk = s.shape
-        if causal:
-            q_pos = iq * bq + jax.lax.broadcasted_iota(
-                jnp.int32, (bq, bk), 0)
-            k_pos = jk * bk + jax.lax.broadcasted_iota(
-                jnp.int32, (bq, bk), 1)
-            s = jnp.where(q_pos >= k_pos, s, NEG_INF)
-        if mask_ref is not None:
-            # k-side padding mask (1=keep), [1, BK]: k runs along lanes
-            # like the score tile's columns, so this is a row broadcast
-            s = jnp.where(mask_ref[0] > 0, s, NEG_INF)
-        m_prev = m_ref[:, 0:1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        # fully-masked row guard: m_new == NEG_INF would make the masked
-        # exp(s - m_new) = 1; clamp so p stays 0 and the row sums to 0
-        m_new = jnp.where(m_new > 0.5 * NEG_INF, m_new, 0.0)
+    def piece(q, j, g, lo, hi, tri, carry):
+        """One online-softmax step of a group's q rows over k/v rows."""
+        m, l, acc = carry
+        k, v = _rows(k_ref, j * c, hi), _rows(v_ref, j * c, hi)
+        s = _dot(q[g:g + sub], k, _NT) * scale            # [sub, hi] fp32
+        if tri:
+            s = jnp.where(_keep_tri(sub, hi, g, True), s, NEG_INF)
+        if masked:
+            # k-side padding mask (1=keep), a row [1, k]: k runs along
+            # lanes like the scores' columns
+            s = jnp.where(mask_ref[0, 0, pl.ds(j, 1), :hi] > 0, s, NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+        if masked:
+            # fully-masked row guard: m_new == NEG_INF would make the
+            # masked exp(s - m_new) = 1; clamp so p stays 0 and the row
+            # sums to 0. Without a k-side mask a row's first chunk always
+            # holds its own position, so m_new is finite from there on.
+            m_new = jnp.where(m_new > 0.5 * NEG_INF, m_new, 0.0)
         p = jnp.exp(s - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[:] = l_ref[:] * corr + jnp.sum(p, axis=1, keepdims=True)
-        acc_ref[:] = acc_ref[:] * corr + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[:] = jax.lax.broadcast_in_dim(m_new[:, 0], m_ref.shape, (0,))
+        corr = jnp.exp(m - m_new)
+        l = l * corr + jnp.sum(p, axis=1, keepdims=True)
+        acc = acc * corr + _dot(p.astype(v.dtype), v, _NN)
+        return m_new, l, acc
 
-    @pl.when(jk == nk - 1)
-    def _finalize():
-        l_safe = jnp.maximum(l_ref[:, 0:1], 1e-30)
-        o_ref[0] = (acc_ref[:] / l_safe).astype(o_ref.dtype)
-        lse = m_ref[:, 0:1] + jnp.log(l_safe)
-        lse_ref[0] = jax.lax.broadcast_in_dim(lse[:, 0],
-                                              lse_ref.shape[1:], (0,))
+    def finalize(q, r, carry):
+        m, l, acc = carry
+        l_safe = jnp.maximum(l, 1e-30)
+        o_ref[0, pl.ds(r, c), :] = (acc / l_safe).astype(o_ref.dtype)
+        lse_ref[0, pl.ds(r, c), :] = jnp.broadcast_to(
+            m + jnp.log(l_safe), (c, LANE))
+
+    _walk(plan, n, causal, True, pl.program_id(1), pl.program_id(2),
+          ((NEG_INF, 1), (0.0, 1), (0.0, d)), refs[2:], prep, piece,
+          finalize)
+
+
+def _specs(plan, d, causal, heads, out_is_q):
+    """Block specs of a grid (bh, out_block, reduce_block): operand rows of
+    the out side and of the reduce side, the q side's statistics as
+    columns (`stat_out`) or one row a group (`stat_rows`), and the k-side
+    mask one row a chunk (`mask_rows`) or as a column (`mask_col`),
+    each along the axis its side lies on. Under a causal mask the reduce
+    side stops (dk/dv: starts) at the out block, so a grid step that
+    computes nothing fetches nothing either."""
+    block, c, sub = plan
+
+    def red(b, i, j):
+        if not causal:
+            return j
+        return jnp.minimum(j, i) if out_is_q else jnp.maximum(j, i)
+
+    def spec(shape, index):
+        return pl.BlockSpec(shape, index, memory_space=pltpu.VMEM)
+
+    return dict(
+        out=spec((1, block, d), lambda b, i, j: (b, i, 0)),
+        red=spec((1, block, d), lambda *g: (g[0], red(*g), 0)),
+        stat_out=spec((1, block, LANE), lambda b, i, j: (b, i, 0)),
+        stat_rows=spec((1, 1, block // sub, sub),
+                       lambda *g: (g[0], red(*g), 0, 0)),
+        mask_rows=spec((1, 1, block // c, c),
+                       lambda *g: (g[0] // heads, red(*g), 0, 0)),
+        mask_col=spec((1, block, LANE),
+                      lambda b, i, j: (b // heads, i, 0)))
 
 
 def _fwd(q3, k3, v3, causal, scale, mask3=None, heads=1):
     bh, s, d = q3.shape
-    blk = _block_for(s)
-    n = s // blk
-    qt = pl.BlockSpec((1, blk, d), lambda b, i, j: (b, i, 0),
-                      memory_space=pltpu.VMEM)
-    kt = pl.BlockSpec((1, blk, d), lambda b, i, j: (b, j, 0),
-                      memory_space=pltpu.VMEM)
-    in_specs = [qt, kt, kt]
+    plan = _plan(s, d, q3.dtype, causal)
+    n = s // plan.block
+    sp = _specs(plan, d, causal, heads, out_is_q=True)
+    in_specs = [sp["out"], sp["red"], sp["red"]]
     args = [q3, k3, v3]
     if mask3 is not None:
-        # k-side mask is [batch, 1, s], tiled by the K index along lanes;
-        # every head of a batch row reads the same block via the
-        # b // heads index map (heads is static)
-        in_specs.append(pl.BlockSpec((1, 1, blk),
-                                     lambda b, i, j: (b // heads, 0, j),
-                                     memory_space=pltpu.VMEM))
-        args.append(mask3)
+        # k-side mask [batch, 1, s] as rows of one k chunk each; every head
+        # of a batch row reads the same block via the b // heads index map
+        # (heads is static)
+        in_specs.append(sp["mask_rows"])
+        args.append(mask3.reshape(-1, n, plan.block // plan.chunk,
+                                  plan.chunk))
+    carried = [pltpu.VMEM((plan.block, w), jnp.float32)
+               for w in (1, 1, d)] if n > 1 else []
     o, lse = pl.pallas_call(
-        functools.partial(_fwd_kernel, causal=causal, scale=scale, nk=n,
-                          masked=mask3 is not None),
+        functools.partial(_fwd_kernel, causal=causal, scale=scale,
+                          plan=plan, n=n, masked=mask3 is not None),
         grid=(bh, n, n),
         in_specs=in_specs,
-        out_specs=[qt,
-                   pl.BlockSpec((1, blk, LANE), lambda b, i, j: (b, i, 0),
-                                memory_space=pltpu.VMEM)],
+        out_specs=[sp["out"], sp["stat_out"]],
         out_shape=[jax.ShapeDtypeStruct((bh, s, d), q3.dtype),
                    jax.ShapeDtypeStruct((bh, s, LANE), jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((blk, d), jnp.float32),
-                        pltpu.VMEM((blk, 128), jnp.float32),
-                        pltpu.VMEM((blk, 128), jnp.float32)],
+        scratch_shapes=carried,
         interpret=_interpret(),
         compiler_params=_COMPILER_PARAMS,
         # the name is the innermost component of the kernel's `op_name`,
@@ -161,159 +389,138 @@ def _fwd(q3, k3, v3, causal, scale, mask3=None, heads=1):
 # --------------------------------------------------------------- backward
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
-               causal, scale, nk, masked=False):
-    if masked:
-        mask_ref, dq_ref, acc_ref = refs
-    else:
-        dq_ref, acc_ref = refs
-        mask_ref = None
-    iq, jk = pl.program_id(1), pl.program_id(2)
+               causal, scale, plan, n, masked):
+    refs = list(refs)
+    mask_ref = refs.pop(0) if masked else None
+    dq_ref = refs[0]
+    _, c, sub = plan
+    d = q_ref.shape[-1]
 
-    @pl.when(jk == 0)
-    def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
+    def prep(r):
+        return (_rows(q_ref, r, c), _rows(do_ref, r, c),
+                _rows(lse_ref, r, c)[:, 0:1], _rows(delta_ref, r, c)[:, 0:1])
 
-    @pl.when(jnp.logical_or(not causal, jk <= iq))
-    def _compute():
-        q = q_ref[0]
-        do = do_ref[0]
-        lse = lse_ref[0][:, 0:1]
-        delta = delta_ref[0][:, 0:1]
-        k = k_ref[0]
-        v = v_ref[0]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        bq, bk = s.shape
-        if causal:
-            q_pos = iq * bq + jax.lax.broadcasted_iota(
-                jnp.int32, (bq, bk), 0)
-            k_pos = jk * bk + jax.lax.broadcasted_iota(
-                jnp.int32, (bq, bk), 1)
-            s = jnp.where(q_pos >= k_pos, s, NEG_INF)
-        if mask_ref is not None:
-            s = jnp.where(mask_ref[0] > 0, s, NEG_INF)
-        p = jnp.exp(s - lse)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta) * scale).astype(k.dtype)
-        acc_ref[:] += jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    def piece(ctx, j, g, lo, hi, tri, carry):
+        q, do, lse, delta = (x[g:g + sub] for x in ctx)
+        k, v = _rows(k_ref, j * c, hi), _rows(v_ref, j * c, hi)
+        s = _dot(q, k, _NT) * scale
+        if tri:
+            s = jnp.where(_keep_tri(sub, hi, g, True), s, NEG_INF)
+        if masked:
+            s = jnp.where(mask_ref[0, 0, pl.ds(j, 1), :hi] > 0, s, NEG_INF)
+        ds = jnp.exp(s - lse) * (_dot(do, v, _NT) - delta) * scale
+        return (carry[0] + _dot(ds.astype(k.dtype), k, _NN),)
 
-    @pl.when(jk == nk - 1)
-    def _finalize():
-        dq_ref[0] = acc_ref[:].astype(dq_ref.dtype)
+    def finalize(ctx, r, carry):
+        dq_ref[0, pl.ds(r, c), :] = carry[0].astype(dq_ref.dtype)
+
+    _walk(plan, n, causal, True, pl.program_id(1), pl.program_id(2),
+          ((0.0, d),), refs[1:], prep, piece, finalize)
 
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
-                causal, scale, nq, masked=False):
-    if masked:
-        mask_ref, dk_ref, dv_ref, dk_acc, dv_acc = refs
-    else:
-        dk_ref, dv_ref, dk_acc, dv_acc = refs
-        mask_ref = None
-    jk, i = pl.program_id(1), pl.program_id(2)
+                causal, scale, plan, n, masked):
+    """dk and dv of the resident k block, a chunk of k rows at a time over
+    the chunks of the resident q block. The scores are computed
+    transposed, sᵀ = k qᵀ: k runs along sublanes and q along lanes, so
+    lse and delta arrive as rows [1, q], the k-side mask as a column, and
+    pᵀ dO and dsᵀ q contract over lanes like any matmul."""
+    refs = list(refs)
+    mask_ref = refs.pop(0) if masked else None
+    dk_ref, dv_ref = refs[:2]
+    _, c, sub = plan
+    d = q_ref.shape[-1]
 
-    @pl.when(i == 0)
-    def _init():
-        dk_acc[:] = jnp.zeros_like(dk_acc)
-        dv_acc[:] = jnp.zeros_like(dv_acc)
+    def prep(r):
+        keep = _rows(mask_ref, r, c)[:, 0:1] > 0 if masked else None
+        return _rows(k_ref, r, c), _rows(v_ref, r, c), keep
 
-    @pl.when(jnp.logical_or(not causal, i >= jk))
-    def _compute():
-        q = q_ref[0]
-        do = do_ref[0]
-        lse = lse_ref[0][:, 0:1]
-        delta = delta_ref[0][:, 0:1]
-        k = k_ref[0]
-        v = v_ref[0]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        bq, bk = s.shape
-        if causal:
-            q_pos = i * bq + jax.lax.broadcasted_iota(
-                jnp.int32, (bq, bk), 0)
-            k_pos = jk * bk + jax.lax.broadcasted_iota(
-                jnp.int32, (bq, bk), 1)
-            s = jnp.where(q_pos >= k_pos, s, NEG_INF)
-        if mask_ref is not None:
-            s = jnp.where(mask_ref[0] > 0, s, NEG_INF)
-        p = jnp.exp(s - lse)                              # [BQ, BK]
-        pc = p.astype(do.dtype)
-        dv_acc[:] += jax.lax.dot_general(
-            pc, do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta) * scale).astype(q.dtype)
-        dk_acc[:] += jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    def stat_row(ref, start, size):
+        # [1, size] from rows of `sub`: a row whose lanes are cut after the
+        # load keeps its lane offset, which no broadcast accepts
+        return jnp.concatenate(
+            [ref[0, 0, pl.ds((start + u) // sub, 1), :]
+             for u in range(0, size, sub)], axis=1)
 
-    @pl.when(i == nq - 1)
-    def _finalize():
-        dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
-        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+    def piece(ctx, i, g, lo, hi, tri, carry):
+        k, v, keep = (x[g:g + sub] if x is not None else None for x in ctx)
+        dk, dv = carry
+        q0, nq = i * c + lo, hi - lo
+        q, do = _rows(q_ref, q0, nq), _rows(do_ref, q0, nq)
+        st = _dot(k, q, _NT) * scale                      # [sub, nq] fp32
+        if tri:
+            st = jnp.where(_keep_tri(sub, nq, 0, False), st, NEG_INF)
+        if masked:
+            st = jnp.where(keep, st, NEG_INF)
+        pt = jnp.exp(st - stat_row(lse_ref, q0, nq))
+        dv = dv + _dot(pt.astype(do.dtype), do, _NN)
+        dst = pt * (_dot(v, do, _NT) - stat_row(delta_ref, q0, nq)) * scale
+        return dk + _dot(dst.astype(q.dtype), q, _NN), dv
+
+    def finalize(ctx, r, carry):
+        dk, dv = carry
+        dk_ref[0, pl.ds(r, c), :] = dk.astype(dk_ref.dtype)
+        dv_ref[0, pl.ds(r, c), :] = dv.astype(dv_ref.dtype)
+
+    _walk(plan, n, causal, False, pl.program_id(1), pl.program_id(2),
+          ((0.0, d), (0.0, d)), refs[2:], prep, piece, finalize)
 
 
 def _bwd_impl(causal, scale, res, g, mask3=None, heads=1):
     q3, k3, v3, o3, lse = res
     bh, s, d = q3.shape
-    blk = _block_for(s)
-    n = s // blk
+    plan = _plan(s, d, q3.dtype, causal)
+    block, c, sub = plan
+    n = s // block
     do3 = g
-    # softmax delta rowsum(dO·O), precomputed once (not per k-tile) and
-    # broadcast over the stat-lane layout like lse
+    # softmax delta rowsum(dO·O), precomputed once (not per k chunk). dq
+    # reads it, like lse, as a column over the stat-lane layout; dk/dv
+    # reads both along lanes, one row a diagonal group.
     delta = jnp.sum(do3.astype(jnp.float32) * o3.astype(jnp.float32),
                     axis=-1)
     delta3 = jnp.broadcast_to(delta[..., None], (bh, s, LANE))
-
-    def tile_i(b, i, j):
-        return (b, i, 0)
-
-    def tile_j(b, i, j):
-        return (b, j, 0)
-
-    ti = pl.BlockSpec((1, blk, d), tile_i, memory_space=pltpu.VMEM)
-    tj = pl.BlockSpec((1, blk, d), tile_j, memory_space=pltpu.VMEM)
-    lse_i = pl.BlockSpec((1, blk, LANE), tile_i, memory_space=pltpu.VMEM)
-    lse_j = pl.BlockSpec((1, blk, LANE), tile_j, memory_space=pltpu.VMEM)
-
+    delta_rows = delta.reshape(bh, n, block // sub, sub)
+    lse_rows = lse[..., 0].reshape(bh, n, block // sub, sub)
     masked = mask3 is not None
-    mj = pl.BlockSpec((1, 1, blk), lambda b, i, j: (b // heads, 0, j),
-                      memory_space=pltpu.VMEM)
-    mi = pl.BlockSpec((1, 1, blk), lambda b, i, j: (b // heads, 0, i),
-                      memory_space=pltpu.VMEM)
-    # dq grid: (bh, q_tile, k_tile) — the k-side mask follows axis 2
-    dq_in = [ti, tj, tj, ti, lse_i, lse_i] + ([mj] if masked else [])
-    dq_args = [q3, k3, v3, do3, lse, delta3] + ([mask3] if masked else [])
+    acc = [pltpu.VMEM((block, d), jnp.float32)] if n > 1 else []
+
+    # dq grid: (bh, q_block, k_block) — the k-side mask follows axis 2
+    sp = _specs(plan, d, causal, heads, out_is_q=True)
+    dq_in = [sp["out"], sp["red"], sp["red"], sp["out"], sp["stat_out"],
+             sp["stat_out"]] + ([sp["mask_rows"]] if masked else [])
+    dq_args = [q3, k3, v3, do3, lse, delta3] + (
+        [mask3.reshape(-1, n, block // c, c)] if masked else [])
     dq = pl.pallas_call(
-        functools.partial(_dq_kernel, causal=causal, scale=scale, nk=n,
-                          masked=masked),
+        functools.partial(_dq_kernel, causal=causal, scale=scale,
+                          plan=plan, n=n, masked=masked),
         grid=(bh, n, n),
         in_specs=dq_in,
-        out_specs=[ti],
+        out_specs=[sp["out"]],
         out_shape=[jax.ShapeDtypeStruct((bh, s, d), q3.dtype)],
-        scratch_shapes=[pltpu.VMEM((blk, d), jnp.float32)],
+        scratch_shapes=acc,
         interpret=_interpret(),
         compiler_params=_COMPILER_PARAMS,
         name=FLASH_BWD_DQ,
     )(*dq_args)[0]
 
-    # grid dims: (bh, k_tile, q_tile) — q is the reduce (innermost) dim;
-    # the k-side mask follows axis 1 here
-    dkv_in = [tj, ti, ti, tj, lse_j, lse_j] + ([mi] if masked else [])
-    dkv_args = [q3, k3, v3, do3, lse, delta3] + ([mask3] if masked else [])
+    # grid dims: (bh, k_block, q_block) — q is the reduce (innermost) dim;
+    # the k-side mask follows axis 1 here, as a column beside k's rows
+    sp = _specs(plan, d, causal, heads, out_is_q=False)
+    dkv_in = [sp["red"], sp["out"], sp["out"], sp["red"],
+              sp["stat_rows"], sp["stat_rows"]] + (
+        [sp["mask_col"]] if masked else [])
+    dkv_args = [q3, k3, v3, do3, lse_rows, delta_rows] + (
+        [jnp.broadcast_to(mask3.reshape(-1, s, 1), (mask3.shape[0], s, LANE))]
+        if masked else [])
     dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, causal=causal, scale=scale, nq=n,
-                          masked=masked),
+        functools.partial(_dkv_kernel, causal=causal, scale=scale,
+                          plan=plan, n=n, masked=masked),
         grid=(bh, n, n),
         in_specs=dkv_in,
-        out_specs=[ti, ti],
+        out_specs=[sp["out"], sp["out"]],
         out_shape=[jax.ShapeDtypeStruct((bh, s, d), k3.dtype),
                    jax.ShapeDtypeStruct((bh, s, d), v3.dtype)],
-        scratch_shapes=[pltpu.VMEM((blk, d), jnp.float32),
-                        pltpu.VMEM((blk, d), jnp.float32)],
+        scratch_shapes=acc * 2,
         interpret=_interpret(),
         compiler_params=_COMPILER_PARAMS,
         name=FLASH_BWD_DKV,

@@ -47,6 +47,129 @@ def test_grads_match_xla():
                                    rtol=1e-4, atol=5e-5)
 
 
+def _against_xla(s, causal, masked, d, dtype, fwd_tol, grad_tol):
+    """Forward and all three gradients against `_xla_attention`, through a
+    loss that weighs every output element differently."""
+    rs = np.random.RandomState(s + d)
+    q, k, v = [jnp.asarray(rs.randn(2, s, 1, d) * 0.5, dtype)
+               for _ in range(3)]
+    w = jnp.asarray(rs.randn(2, s, 1, d), jnp.float32)
+    mask = m4 = None
+    if masked:
+        mask = jnp.asarray(np.arange(s)[None, :] <
+                           np.array([s - s // 3, s // 2 + 3])[:, None])
+        m4 = mask[:, None, None, :]
+
+    def run(attend):
+        def loss(a, b, c):
+            out = attend(a, b, c)
+            return jnp.sum(out.astype(jnp.float32) * w), out
+        (_, out), grads = jax.value_and_grad(
+            loss, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+        return (out,) + grads
+
+    got = run(lambda a, b, c: flash_attention(a, b, c, causal=causal,
+                                              kv_mask=mask))
+    ref = run(lambda a, b, c: _xla_attention(a, b, c, m4, 0.0, causal,
+                                             False, None))
+    for a, b, tol in zip(got, ref, [fwd_tol] + [grad_tol] * 3):
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(b, np.float32), **tol)
+
+
+@pytest.mark.parametrize("d", [64, 80])
+@pytest.mark.parametrize("masked", [False, True], ids=["dense", "kvmask"])
+@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+@pytest.mark.parametrize("s", [128, 384, 1024, 2048])
+def test_forward_and_grads_match_xla(s, causal, masked, d):
+    """The walks `_plan` chooses for float32 operands: one chunk (128),
+    three groups of one chunk (384), the whole-sequence block with a group
+    that stops at its diagonal (1024), and several resident blocks with
+    carried state (2048: float32 operands leave the whole-sequence path
+    there)."""
+    _against_xla(s, causal, masked, d, jnp.float32,
+                 dict(rtol=1e-5, atol=2e-5), dict(rtol=1e-4, atol=5e-5))
+
+
+@pytest.mark.parametrize("causal,masked", [
+    (True, False), (False, True), (True, True)],
+    ids=["causal", "kvmask", "causal-kvmask"])
+@pytest.mark.parametrize("s", [1024, 1152, 2048])
+def test_bf16_operands_match_xla(s, causal, masked):
+    """bf16 operands, where both sides round p and ds to bf16 at different
+    places of the sum: the GPT shape's sequence (one chunk), three blocks
+    of one 384-row chunk with carried state (1152), and the one walk of
+    two chunks a side, which only bf16 operands reach (2048: a resident
+    block of two 1024-row chunks)."""
+    assert fa._plan(s, 64, jnp.bfloat16, causal)[:2] == {
+        1024: (1024, 1024), 1152: (384, 384), 2048: (2048, 1024)}[s]
+    _against_xla(s, causal, masked, 64, jnp.bfloat16,
+                 dict(rtol=2e-2, atol=2e-2), dict(rtol=5e-2, atol=5e-2))
+
+
+@pytest.mark.parametrize("transposed", [False, True], ids=["fwd-dq", "dkv"])
+@pytest.mark.parametrize("s,causal,d,dtype", [
+    (1024, True, 64, jnp.bfloat16), (1024, False, 64, jnp.bfloat16),
+    (384, True, 64, jnp.bfloat16), (2048, True, 64, jnp.bfloat16),
+    (2048, True, 64, jnp.float32), (1152, True, 80, jnp.bfloat16),
+    (512, False, 64, jnp.bfloat16)])
+def test_score_work_is_what_the_walk_covers(s, causal, d, dtype, transposed):
+    """`score_work` against the geometry: laid over the (q, k) plane, the
+    spans and groups the kernels are built from cover every needed score
+    exactly once, a group that applies no causal mask holds none above
+    the diagonal, and the area is what the counter says."""
+    _, c, sub = fa._plan(s, d, dtype, causal)
+    seen = np.zeros((s, s), np.int32)                     # [q, k]
+    bodies = masked_bodies = 0
+    for o0 in range(0, s, c):
+        f_lo, f_hi, d_lo, d_hi = fa._chunk_spans(o0, c, s // c, causal,
+                                                 not transposed)
+        for lo, hi, diag in ((f_lo, f_hi, False), (d_lo, d_hi, True)):
+            for j in range(lo, hi):
+                bodies += 1
+                masked_bodies += diag
+                for g, r_lo, r_hi in fa._groups(c, sub, not transposed,
+                                                diag):
+                    out = slice(o0 + g, o0 + g + sub)
+                    red = slice(j * c + r_lo, j * c + r_hi)
+                    q, k = (red, out) if transposed else (out, red)
+                    if causal and not diag:
+                        assert k.stop - 1 <= q.start    # needs no mask
+                    seen[q, k] += 1
+    needed = np.tril(np.ones((s, s), np.int32)) if causal \
+        else np.ones((s, s), np.int32)
+    assert seen.max() == 1 and (seen >= needed).all()
+    work = fa.score_work(s, causal, d, dtype, transposed)
+    assert (work.chunks, work.masked_chunks) == (bodies, masked_bodies)
+    assert work.ratio == pytest.approx(seen.sum() / needed.sum())
+
+
+def test_score_work_bounds():
+    """Causal attention at the GPT cell's shape computed 1.50 times the
+    scores it needs on 512 x 512 tiles; the groups bring it under 1.25,
+    and without a causal mask nothing is computed twice or in vain."""
+    assert fa.score_work(1024, True).ratio <= 1.25
+    assert fa.score_work(1024, True, transposed=True).ratio <= 1.25
+    for s in (128, 512, 1024, 4096):
+        assert fa.score_work(s, False).ratio == 1.0
+
+
+def test_kernels_trace_the_groups_score_work_counts(monkeypatch):
+    """The forward kernel's body at the GPT shape, traced: two matmuls a
+    group, and as many groups as the counter's chunks hold."""
+    dots = []
+    real = fa._dot
+    monkeypatch.setattr(fa, "_dot", lambda a, b, dims: dots.append(
+        (a.shape, b.shape)) or real(a, b, dims))
+    x = jax.ShapeDtypeStruct((2, 1024, 64), jnp.bfloat16)
+    jax.eval_shape(lambda q, k, v: fa._fwd(q, k, v, True, 0.125), x, x, x)
+    _, c, sub = fa._plan(1024, 64, jnp.bfloat16, True)
+    assert len(dots) == 2 * fa.score_work(1024, True).chunks * (c // sub)
+    scores = sum(a[0] * b[0] for a, b in dots[0::2])      # q k^T shapes
+    assert scores == pytest.approx(
+        fa.score_work(1024, True).ratio * 1024 * 1025 / 2)
+
+
 def test_library_does_not_interpret_unasked(monkeypatch):
     """Off-chip the kernel raises; it does not quietly become a CPU
     emulation of itself."""

@@ -32,7 +32,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P, \
 
 from paddle_tpu.ops import flash_attention as fa
 
-# Each kernel compiles in about a second here; the whole file in ~15 s.
+# Each kernel compiles in about a second here; the whole file in ~30 s.
 COMPILE_LIMIT_S = 120
 
 
@@ -122,6 +122,27 @@ KERNEL_CASES = [
                  id="tile256-kvmask"),
     pytest.param((2, 384, 4, 64), True, True, jnp.bfloat16,
                  id="tile128-three-blocks-causal-kvmask"),
+    # one case on each side of every boundary of `_plan` (PR 28)
+    pytest.param((16, 512, 16, 64), False, True, jnp.bfloat16,
+                 id="bert_large-kvmask-one-group"),
+    pytest.param((2, 2048, 4, 64), True, False, jnp.bfloat16,
+                 id="seq2048-last-whole-sequence-block"),
+    pytest.param((2, 2176, 4, 64), True, False, jnp.bfloat16,
+                 id="seq2176-first-off-the-whole-sequence-block"),
+    pytest.param((2, 4096, 4, 64), True, False, jnp.bfloat16,
+                 id="seq4096-four-blocks-causal"),
+    pytest.param((2, 4096, 4, 64), False, True, jnp.bfloat16,
+                 id="seq4096-four-blocks-kvmask"),
+    pytest.param((2, 2048, 4, 64), True, False, jnp.float32,
+                 id="fp32-operands-four-blocks"),
+    pytest.param((2, 1152, 4, 64), True, False, jnp.bfloat16,
+                 id="seq1152-three-blocks-of-one-chunk"),
+    pytest.param((2, 1280, 4, 64), True, False, jnp.bfloat16,
+                 id="seq1280-first-block-of-two-chunks"),
+    pytest.param((2, 2048, 4, 64), False, True, jnp.bfloat16,
+                 id="seq2048-two-chunks-kvmask"),
+    pytest.param((2, 1024, 4, 256), True, False, jnp.bfloat16,
+                 id="head_dim256-last-whole-sequence-block"),
 ]
 
 
