@@ -44,4 +44,4 @@ from ...framework.random import (  # noqa: F401
     get_rng_state_tracker,
     model_parallel_random_seed,
 )
-from .moe import MoEMLP, top2_gating  # noqa: F401
+from .moe import MoEMLP, topk_gating  # noqa: F401

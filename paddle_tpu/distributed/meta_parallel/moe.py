@@ -1,118 +1,276 @@
-"""Mixture-of-Experts with expert parallelism (beyond-reference).
+"""Mixture-of-Experts: top-k routing and a dropless grouped expert product
+(beyond-reference).
 
 The reference snapshot has NO MoE layers (SURVEY §2.3: expert parallel ✗;
 its only hook is the `alltoall` collective, `operators/collective/
-alltoall_op.cc`). This module adds the capability TPU-first, GShard
-style: expert weights carry a PartitionSpec over an expert axis and
-token dispatch/combine are einsums against a capacity-bounded dispatch
-mask — under GSPMD those einsums lower to exactly the all-to-all the
-reference would have hand-written.
+alltoall_op.cc`). This layer is one chip's share of an expert-parallel
+layer: the router scores ALL `num_experts`, every token takes its `top_k`,
+and the chip computes the assignments that fall on the experts it holds
+(`expert_offset` .. `expert_offset + experts_held`). What the absent
+experts would add is their chips' to add: the exchange across chips is
+not here (ROADMAP "Reach"), and nothing stands in for it.
 
-Gating follows GShard top-2: top-1 expert + probabilistic second expert,
-position-in-expert capacity enforcement via cumsum (tokens over capacity
-are dropped — dense shapes, no sorting, XLA-friendly).
+Routing is sort-based, not mask-based: the assignments are ordered by held
+expert (absent ones last), their tokens' rows are gathered into a row
+buffer, and the three expert matrices are applied as grouped products over
+that buffer (`jax.lax.ragged_dot`, which the TPU compiler turns into a
+tiled grouped matmul). The buffer is static and sized for the load the
+router is expected to send here with half as much again (`rows_buffer`);
+nothing is dropped, because the ordered assignments are taken a buffer at
+a time for as many rounds as they need (`grouped_experts`: a loop whose
+trip count is the data's, one round while the load stays under the buffer,
+tokens x min(top_k, held) / buffer rounds at most), so all rows routed to
+one expert still come out right, and memory is bounded by the buffer
+whatever the router does. Dispatch is a row gather and combine a
+scatter-add by token over the buffer's rows, each the other's transpose,
+so their cost is the buffer's; the products' is the load's.
 """
 from __future__ import annotations
 
-from typing import Optional, Tuple
+import functools
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
 
 from ...nn import functional as F
 from ...nn import initializer as I
 from ...nn.layer import Layer
-from .mp_layers import _constrain
+from ...profiler import MOE_EXPERTS, MOE_ROUTE, stats
 
 
-def top2_gating(logits, capacity: int):
-    """GShard top-2 gating. logits [g, s, e] fp32 →
-    (dispatch [g, s, e, c] bool-ish, combine [g, s, e, c] fp32, aux)."""
-    g, s, e = logits.shape
-    probs = jax.nn.softmax(logits, axis=-1)
-    # top-1
-    idx1 = jnp.argmax(probs, axis=-1)                      # [g, s]
-    mask1 = jax.nn.one_hot(idx1, e, dtype=probs.dtype)
-    # top-2: mask out the winner, argmax again
-    probs2 = probs * (1.0 - mask1)
-    idx2 = jnp.argmax(probs2, axis=-1)
-    mask2 = jax.nn.one_hot(idx2, e, dtype=probs.dtype)
-    # load-balancing auxiliary loss (GShard eq. 4 / Switch aux)
-    density = jnp.mean(mask1, axis=1)                      # [g, e]
-    density_proxy = jnp.mean(probs, axis=1)
-    aux = jnp.mean(density * density_proxy) * (e * e)
-    # capacity positions (top-1 tokens first, then top-2)
-    pos1 = jnp.cumsum(mask1, axis=1) * mask1               # 1-based
-    pos2 = (jnp.cumsum(mask2, axis=1) +
-            jnp.sum(mask1, axis=1, keepdims=True)) * mask2
-    keep1 = mask1 * (pos1 <= capacity)
-    keep2 = mask2 * (pos2 <= capacity)
-    w1 = jnp.sum(probs * keep1, axis=-1)                   # [g, s]
-    w2 = jnp.sum(probs * keep2, axis=-1)
-    denom = jnp.maximum(w1 + w2, 1e-9)
-    w1, w2 = w1 / denom, w2 / denom
+def topk_gating(logits, top_k: int, norm_topk_prob: bool = True):
+    """Router logits [t, e] -> (experts [t, k] int32, weights [t, k] fp32,
+    probs [t, e]): a softmax over all experts in float32, the `top_k`
+    largest (ties to the smaller id), renormalised over themselves where
+    `norm_topk_prob`."""
+    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    weights, experts = jax.lax.top_k(probs, top_k)
+    if norm_topk_prob:
+        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    return experts, weights, probs
 
-    def to_cap(keep, pos, w):
-        # [g, s, e] one-hot rows at capacity slot pos-1 → [g, s, e, c]
-        slot = jax.nn.one_hot((pos - 1.0) * keep, capacity,
-                              dtype=keep.dtype) * keep[..., None]
-        return slot * w[..., None, None]
 
-    combine = to_cap(keep1, pos1, w1) + to_cap(keep2, pos2, w2)
-    dispatch = (combine > 0.0).astype(logits.dtype)
-    return dispatch, combine, aux
+def balance_loss(experts, probs):
+    """Switch / GShard load-balancing loss: e x sum_e (share of the
+    assignments expert e got) x (mean router probability of e); 1 at
+    balance."""
+    e = probs.shape[-1]
+    share = jnp.mean(jax.nn.one_hot(experts, e, dtype=probs.dtype),
+                     axis=(0, 1))
+    return e * jnp.sum(share * jnp.mean(probs, axis=0))
+
+
+class Dispatch(NamedTuple):
+    """Which assignment each row of the row buffer holds."""
+    token: jax.Array    # [rows] the token a buffer row holds
+    choice: jax.Array   # [rows] which of its token's k choices it is
+    filled: jax.Array   # [rows] the row holds an assignment held here
+    sizes: jax.Array    # [held] rows of each held expert, in buffer order
+
+
+def dispatch_plan(experts, offset: int, held: int, rows: int) -> Dispatch:
+    """Order the [t, k] assignments by held expert (a stable sort: within
+    an expert by token), absent experts last, and lay the first `rows` of
+    them out as buffer rows. `rows` >= t x min(k, held) holds every
+    assignment that can fall here."""
+    t, k = experts.shape
+    local = experts - offset
+    key = jnp.where((local >= 0) & (local < held), local, held).reshape(-1)
+    order = jnp.argsort(key, stable=True)
+    sizes = jnp.sum(jax.nn.one_hot(key, held + 1, dtype=jnp.int32),
+                    axis=0)[:held]
+    if rows < t * k:
+        order = order[:rows]
+    else:
+        order = jnp.pad(order, (0, rows - t * k))
+    filled = jnp.arange(rows) < jnp.sum(sizes)
+    return Dispatch(order // k, order % k, filled, sizes)
+
+
+def _round_of(plan: Dispatch, start, rows: int) -> Dispatch:
+    """The plan of the buffer rows [start, start + rows) alone, as a plan
+    of its own: a group keeps the rows it has inside."""
+    ends = jnp.cumsum(plan.sizes)
+    inside = jnp.clip(ends - start, 0, rows) \
+        - jnp.clip(ends - plan.sizes - start, 0, rows)
+    cut = functools.partial(jax.lax.dynamic_slice_in_dim, start_index=start,
+                            slice_size=rows)
+    return Dispatch(cut(plan.token), cut(plan.choice), cut(plan.filled),
+                    inside)
+
+
+@jax.custom_vjp
+def _take_rows(x, plan: Dispatch):
+    """x [t, d] -> [rows, d], row r from token[r]."""
+    return x[plan.token]
+
+
+def _take_rows_fwd(x, plan):
+    return x[plan.token], (plan, x.shape[0])
+
+
+def _take_rows_bwd(res, g):
+    # a token's gradient is the sum over its rows, added up in float32
+    # whatever the rows' dtype; what comes back for the rows of no group
+    # was never computed
+    plan, tokens = res
+    rows = jnp.where(plan.filled[:, None], g.astype(jnp.float32), 0.0)
+    dx = jnp.zeros((tokens, g.shape[-1]), jnp.float32).at[plan.token].add(
+        rows)
+    return dx.astype(g.dtype), None
+
+
+_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+
+
+def _one_round(x, weights, w_gate, w_up, w_down, plan: Dispatch):
+    """The held experts over one buffer of rows: [t, d] fp32, the sum over
+    a token's assignments in the buffer of weight x expert(x). The rows
+    behind the last assignment belong to no group: the grouped product
+    skips their tiles and what it leaves there is not a number to use, so
+    they are masked on the way out (here) and on the way back
+    (`_take_rows`)."""
+    rows = _take_rows(x, plan)
+
+    def product(a, w):
+        return jax.lax.ragged_dot(a, w, plan.sizes,
+                                  preferred_element_type=jnp.float32)
+    hidden = F.swiglu(product(rows, w_gate),
+                      product(rows, w_up)).astype(x.dtype)
+    # masked before the weight: nought times what is not a number is not
+    # a number either, in the weight's gradient
+    y = jnp.where(plan.filled[:, None], product(hidden, w_down), 0.0)
+    w_row = weights[plan.token, plan.choice]
+    return jnp.zeros(x.shape, jnp.float32).at[plan.token].add(
+        y * w_row[:, None])
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _rounds(x, weights, w_gate, w_up, w_down, plan: Dispatch, rows: int):
+    """`_one_round` over the plan's rows, `rows` at a time, for as many
+    rounds as hold an assignment: a loop with the data's trip count,
+    which reverse mode cannot go through, so the backward is a loop of
+    its own that replays a round and takes its gradient."""
+    def body(i, y):
+        return y + _one_round(x, weights, w_gate, w_up, w_down,
+                              _round_of(plan, i * rows, rows))
+    return jax.lax.fori_loop(0, _rounds_needed(plan, rows), body,
+                             jnp.zeros(x.shape, jnp.float32))
+
+
+def _rounds_needed(plan: Dispatch, rows: int):
+    return (jnp.sum(plan.sizes) + rows - 1) // rows
+
+
+def _rounds_fwd(x, weights, w_gate, w_up, w_down, plan, rows):
+    return _rounds(x, weights, w_gate, w_up, w_down, plan, rows), \
+        (x, weights, w_gate, w_up, w_down, plan)
+
+
+def _rounds_bwd(rows, res, g):
+    *args, plan = res
+
+    def body(i, acc):
+        _, vjp = jax.vjp(
+            lambda *a: _one_round(*a, _round_of(plan, i * rows, rows)),
+            *args)
+        return jax.tree.map(jnp.add, acc, vjp(g))
+    zero = tuple(jnp.zeros(a.shape, a.dtype) for a in args)
+    grads = jax.lax.fori_loop(0, _rounds_needed(plan, rows), body, zero)
+    return (*grads, None)
+
+
+_rounds.defvjp(_rounds_fwd, _rounds_bwd)
+
+
+def grouped_experts(x, plan: Dispatch, weights, w_gate, w_up, w_down,
+                    compute_dtype=None, rows: Optional[int] = None):
+    """The held experts over their rows. x [t, d]; weights [t, k] fp32;
+    w_gate / w_up [held, d, f], w_down [held, f, d]. Returns [t, d] fp32:
+    sum over a token's held assignments of weight x expert(x). `rows`:
+    buffer rows a round (default: the whole plan in one round); the
+    plan's rows are a multiple of it."""
+    dt = compute_dtype or x.dtype
+    total = plan.token.shape[0]
+    rows = rows or total
+    assert total % rows == 0, (total, rows)
+    args = (x.astype(dt), weights, w_gate.astype(dt), w_up.astype(dt),
+            w_down.astype(dt))
+    if rows == total:
+        return _one_round(*args, plan)
+    return _rounds(*args, plan, rows)
 
 
 class MoEMLP(Layer):
-    """Expert-parallel FFN block: gate → dispatch → per-expert MLP →
-    combine. Expert weights are sharded over `expert_axis` (defaults to
-    the 'model' mesh axis — expert parallelism rides the TP axis the way
-    alltoall-based MoE rides NCCL groups)."""
+    """Top-k routed, SiLU-gated experts; one chip's share of the layer.
+
+    `experts_held` of the `num_experts` live here, from `expert_offset`
+    (default: all of them). The router is `num_experts` wide whatever is
+    held. `aux_loss=True` keeps the load-balancing loss of the last call
+    in a buffer (it survives `functional_call` / jit as a new buffer)."""
 
     def __init__(self, d_model: int, d_ff: int, num_experts: int,
-                 capacity_factor: float = 1.25,
-                 expert_axis: str = "model", compute_dtype=None):
+                 top_k: int = 2, experts_held: Optional[int] = None,
+                 expert_offset: int = 0, norm_topk_prob: bool = True,
+                 compute_dtype=None, initializer_range: float = 0.02,
+                 aux_loss: bool = False, rows_buffer: Optional[int] = None):
         super().__init__()
-        self.num_experts = num_experts
-        self.capacity_factor = capacity_factor
-        init = I.Normal(0.0, 0.02)
+        held = num_experts if experts_held is None else experts_held
+        if not 0 <= expert_offset <= num_experts - held:
+            raise ValueError(f"experts {expert_offset}..{expert_offset + held}"
+                             f" of {num_experts}")
+        self.num_experts, self.top_k = num_experts, top_k
+        self.experts_held, self.expert_offset = held, expert_offset
+        self.norm_topk_prob = norm_topk_prob
+        init = I.Normal(0.0, initializer_range)
         self.gate_weight = self.create_parameter(
             (d_model, num_experts), default_initializer=init)
-        self.w1 = self.create_parameter((num_experts, d_model, d_ff),
-                                        default_initializer=init)
-        self.w2 = self.create_parameter((num_experts, d_ff, d_model),
-                                        default_initializer=init)
-        self.w1.sharding_spec = P(expert_axis, None, None)
-        self.w2.sharding_spec = P(expert_axis, None, None)
-        self._axis = expert_axis
+        self.w_gate = self.create_parameter((held, d_model, d_ff),
+                                            default_initializer=init)
+        self.w_up = self.create_parameter((held, d_model, d_ff),
+                                          default_initializer=init)
+        self.w_down = self.create_parameter((held, d_ff, d_model),
+                                            default_initializer=init)
         self._cdt = compute_dtype
-        # aux loss rides a BUFFER so it survives functional_call/jit
-        # (a plain attribute would hold a leaked tracer); jitted steps
-        # read it from the returned new_buffers, eager from .value
-        self.register_buffer("aux_loss", jnp.zeros((), jnp.float32))
+        self._rows = rows_buffer
+        if aux_loss:
+            self.register_buffer("aux_loss", jnp.zeros((), jnp.float32))
+        self._aux = aux_loss
+
+    def rows_buffer(self, tokens: int) -> tuple:
+        """(rows of the static buffer, rounds that hold every assignment
+        that can fall on the held experts). The buffer: what balanced
+        routing sends here, tokens x top_k x held / experts, and half as
+        much again, in whole tiles of the grouped product (512 rows);
+        never more than the worst case, tokens x min(top_k, held)."""
+        worst = tokens * min(self.top_k, self.experts_held)
+        rows = self._rows or -(-3 * tokens * self.top_k * self.experts_held
+                               // (2 * self.num_experts * 512)) * 512
+        rows = min(rows, worst)
+        return rows, -(-worst // rows)
 
     def forward(self, x):
         b, s, d = x.shape
-        e = self.num_experts
-        cap = max(1, int(self.capacity_factor * s * 2 / e))
-        xf = x.astype(jnp.float32)
-        logits = xf @ jnp.asarray(self.gate_weight).astype(jnp.float32)
-        dispatch, combine, aux = top2_gating(logits, cap)
-        self.aux_loss.value = aux
-        dt = self._cdt or x.dtype
-        # dispatch: [b,s,d] x [b,s,e,c] -> [e,b,c,d] — under GSPMD with
-        # tokens sharded on 'data' and experts on the expert axis this
-        # IS the all-to-all (`alltoall_op.cc` equivalent)
-        xin = jnp.einsum("bsd,bsec->ebcd", x.astype(dt),
-                         dispatch.astype(dt))
-        xin = _constrain(xin, self._axis, None, None, None)
-        w1 = jnp.asarray(self.w1).astype(dt)
-        w2 = jnp.asarray(self.w2).astype(dt)
-        h = jnp.einsum("ebcd,edf->ebcf", xin, w1)
-        h = F.gelu(h, approximate=True)
-        out = jnp.einsum("ebcf,efd->ebcd", h, w2)
-        out = _constrain(out, self._axis, None, None, None)
-        y = jnp.einsum("ebcd,bsec->bsd", out.astype(jnp.float32),
-                       combine)
-        return y.astype(x.dtype)
+        xt = x.reshape(b * s, d)
+        with jax.named_scope(MOE_ROUTE):
+            # the router in float32, operands too: a flipped expert is
+            # not a rounding error
+            logits = jnp.matmul(
+                xt.astype(jnp.float32),
+                jnp.asarray(self.gate_weight).astype(jnp.float32),
+                precision=jax.lax.Precision.HIGHEST)
+            experts, weights, probs = topk_gating(logits, self.top_k,
+                                                  self.norm_topk_prob)
+            if self._aux:
+                self.aux_loss.value = balance_loss(experts, probs)
+            rows, rounds = self.rows_buffer(b * s)
+            plan = dispatch_plan(experts, self.expert_offset,
+                                 self.experts_held, rows * rounds)
+        stats.static("moe.experts_held", self.experts_held)
+        stats.static("moe.rows_buffer", rows)
+        with jax.named_scope(MOE_EXPERTS):
+            y = grouped_experts(xt, plan, weights, jnp.asarray(self.w_gate),
+                                jnp.asarray(self.w_up),
+                                jnp.asarray(self.w_down), self._cdt, rows)
+        return y.reshape(b, s, d).astype(x.dtype)

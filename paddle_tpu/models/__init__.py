@@ -21,6 +21,12 @@ from .gpt import (  # noqa: F401
     gpt_6p7b,
     ernie_10b,
 )
+from .keye import (  # noqa: F401
+    KeyeConfig,
+    KeyeForCausalLM,
+    KeyeModel,
+    keye_tiny,
+)
 from .bert import (  # noqa: F401
     BertConfig,
     BertForPretraining,

@@ -51,6 +51,14 @@ def silu(x, name=None):
 swish = silu
 
 
+def swiglu(gate, up):
+    """SiLU-gated linear unit, silu(gate) * up: the two halves of a gated
+    MLP's (or a gated expert's) first product, in float32 whatever the
+    operands, returned in `up`'s dtype."""
+    out = jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)
+    return out.astype(up.dtype)
+
+
 def mish(x):
     return x * jnp.tanh(jax.nn.softplus(x))
 

@@ -176,10 +176,53 @@ def _pallas_ok(q, k, causal: bool) -> bool:
         if any(n > 1 for a, n in size.items() if a not in _KERNEL_AXES):
             return False
         if b % math.prod(size[a] for a in batch) or \
-                (head and h % size[head]):
+                (head and (h % size[head] or k.shape[2] % size[head])):
             return False
-    return (k.shape == q.shape and s % 128 == 0 and s >= floor
-            and d <= 256)
+    # key/value may hold fewer heads (grouped queries)
+    same = k.shape[:2] == q.shape[:2] and k.shape[3] == d \
+        and h % k.shape[2] == 0
+    return same and s % 128 == 0 and s >= floor and d <= 256
+
+
+def rotary_embedding(x, theta: float = 10000.0, positions=None):
+    """Rotary positions on [batch, seq, heads, n]: the pairs (i, i + n/2)
+    (the half-split convention of the Llama / Qwen checkpoints) are turned
+    by position x theta^(-2i/n). `positions` [batch, seq] defaults to
+    0 .. seq-1. Computed in float32, returned in x's dtype."""
+    b, s, _, n = x.shape
+    inv = theta ** (-jnp.arange(0, n, 2, dtype=jnp.float32) / n)
+    pos = (jnp.arange(s, dtype=jnp.float32)[None] if positions is None
+           else positions.astype(jnp.float32))
+    ang = pos[..., None] * inv                          # [b or 1, s, n/2]
+    cos, sin = jnp.cos(ang)[:, :, None], jnp.sin(ang)[:, :, None]
+    xf = x.astype(jnp.float32)
+    a, c = xf[..., :n // 2], xf[..., n // 2:]
+    out = jnp.concatenate([a * cos - c * sin, c * cos + a * sin], -1)
+    return out.astype(x.dtype)
+
+
+@jax.named_scope(ATTENTION)
+def selected_attention(query, key, value, selection, scale=None):
+    """Attention over a learned selection of keys. query [b, s, h, d];
+    key/value [b, s, h_kv, d] (query head i reads key/value head
+    i // (h / h_kv)); selection [b, s_q, s_k] int8, 1 where query q may
+    read key k, the same for all heads of a row and under the diagonal.
+    The softmax runs over the selected keys alone. The selection carries
+    no gradient."""
+    if flag("enable_pallas_kernels") and _pallas_ok(query, key, True) \
+            and _mesh_shards(query) is None:
+        return flash_attention(query, key, value, causal=True, scale=scale,
+                               selection=selection)
+    b, s, h, d = query.shape
+    group = h // key.shape[2]
+    scale = scale if scale is not None else 1.0 / math.sqrt(d)
+    q = query.reshape(b, s, key.shape[2], group, d)
+    scores = jnp.einsum("bqcgd,bkcd->bcgqk", q, key,
+                        preferred_element_type=jnp.float32) * scale
+    scores = jnp.where(selection[:, None, None] > 0, scores, -jnp.inf)
+    probs = jax.nn.softmax(scores, axis=-1).astype(query.dtype)
+    out = jnp.einsum("bcgqk,bkcd->bqcgd", probs, value)
+    return out.reshape(b, s, h, d)
 
 
 def _xla_attention(query, key, value, attn_mask, dropout_p, is_causal,

@@ -50,7 +50,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..profiler import FLASH_BWD_DKV, FLASH_BWD_DQ, FLASH_FWD
+from ..profiler import (FLASH_BWD_DKV, FLASH_BWD_DQ, FLASH_FWD,
+                        FLASH_SEL_BWD_DKV, FLASH_SEL_BWD_DQ, FLASH_SEL_FWD)
 
 # Row statistics (lse/delta) ride an 8-lane broadcast: TPU block layouts
 # need the last two dims (sublane, lane) to divide (8, 128) or equal the
@@ -275,18 +276,29 @@ def _walk(plan, n, causal, before, out_id, red_id, init, scratch, prep,
 
 # ---------------------------------------------------------------- forward
 
-def _fwd_kernel(q_ref, k_ref, v_ref, *refs, causal, scale, plan, n, masked):
+def _selected(sel_ref, row0, rows, col0, cols):
+    """Where the selection operand keeps a score: rows [row0, row0 + rows)
+    against columns [col0, col0 + cols) of the resident [1, out, red]
+    block of 0/1 bytes."""
+    return sel_ref[0, pl.ds(row0, rows),
+                   pl.ds(col0, cols)].astype(jnp.int32) > 0
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, *refs, causal, scale, plan, n, masked,
+                selected=False):
     refs = list(refs)
     mask_ref = refs.pop(0) if masked else None
+    sel_ref = refs.pop(0) if selected else None
     o_ref, lse_ref = refs[:2]
     _, c, sub = plan
     d = q_ref.shape[-1]
 
     def prep(r):
-        return _rows(q_ref, r, c)
+        return _rows(q_ref, r, c), r
 
-    def piece(q, j, g, lo, hi, tri, carry):
+    def piece(ctx, j, g, lo, hi, tri, carry):
         """One online-softmax step of a group's q rows over k/v rows."""
+        q, r = ctx
         m, l, acc = carry
         k, v = _rows(k_ref, j * c, hi), _rows(v_ref, j * c, hi)
         s = _dot(q[g:g + sub], k, _NT) * scale            # [sub, hi] fp32
@@ -296,8 +308,12 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, causal, scale, plan, n, masked):
             # k-side padding mask (1=keep), a row [1, k]: k runs along
             # lanes like the scores' columns
             s = jnp.where(mask_ref[0, 0, pl.ds(j, 1), :hi] > 0, s, NEG_INF)
+        if selected:
+            # per-(q, k) selection, the same for every head of a row
+            s = jnp.where(_selected(sel_ref, r + g, sub, j * c, hi), s,
+                          NEG_INF)
         m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
-        if masked:
+        if masked or selected:
             # fully-masked row guard: m_new == NEG_INF would make the
             # masked exp(s - m_new) = 1; clamp so p stays 0 and the row
             # sums to 0. Without a k-side mask a row's first chunk always
@@ -309,7 +325,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, causal, scale, plan, n, masked):
         acc = acc * corr + _dot(p.astype(v.dtype), v, _NN)
         return m_new, l, acc
 
-    def finalize(q, r, carry):
+    def finalize(ctx, r, carry):
         m, l, acc = carry
         l_safe = jnp.maximum(l, 1e-30)
         o_ref[0, pl.ds(r, c), :] = (acc / l_safe).astype(o_ref.dtype)
@@ -321,14 +337,18 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, causal, scale, plan, n, masked):
           finalize)
 
 
-def _specs(plan, d, causal, heads, out_is_q):
+def _specs(plan, d, causal, heads, out_is_q, group=1):
     """Block specs of a grid (bh, out_block, reduce_block): operand rows of
     the out side and of the reduce side, the q side's statistics as
-    columns (`stat_out`) or one row a group (`stat_rows`), and the k-side
+    columns (`stat_out`) or one row a group (`stat_rows`), the k-side
     mask one row a chunk (`mask_rows`) or as a column (`mask_col`),
-    each along the axis its side lies on. Under a causal mask the reduce
-    side stops (dk/dv: starts) at the out block, so a grid step that
-    computes nothing fetches nothing either."""
+    each along the axis its side lies on, and the (out, reduce) block of
+    a per-pair selection (`sel`), which like the mask is one per batch
+    row. `kv_out` / `kv_red` are the key/value side where `group` query
+    heads share one key/value head: the grid runs over query heads and
+    the index map folds them (b // group), so no copy is made. Under a
+    causal mask the reduce side stops (dk/dv: starts) at the out block,
+    so a grid step that computes nothing fetches nothing either."""
     block, c, sub = plan
 
     def red(b, i, j):
@@ -336,27 +356,34 @@ def _specs(plan, d, causal, heads, out_is_q):
             return j
         return jnp.minimum(j, i) if out_is_q else jnp.maximum(j, i)
 
+    def kv(b):
+        return b if group == 1 else b // group
+
     def spec(shape, index):
         return pl.BlockSpec(shape, index, memory_space=pltpu.VMEM)
 
     return dict(
         out=spec((1, block, d), lambda b, i, j: (b, i, 0)),
         red=spec((1, block, d), lambda *g: (g[0], red(*g), 0)),
+        kv_out=spec((1, block, d), lambda b, i, j: (kv(b), i, 0)),
+        kv_red=spec((1, block, d), lambda *g: (kv(g[0]), red(*g), 0)),
         stat_out=spec((1, block, LANE), lambda b, i, j: (b, i, 0)),
         stat_rows=spec((1, 1, block // sub, sub),
                        lambda *g: (g[0], red(*g), 0, 0)),
         mask_rows=spec((1, 1, block // c, c),
                        lambda *g: (g[0] // heads, red(*g), 0, 0)),
         mask_col=spec((1, block, LANE),
-                      lambda b, i, j: (b // heads, i, 0)))
+                      lambda b, i, j: (b // heads, i, 0)),
+        sel=spec((1, block, block),
+                 lambda *g: (g[0] // heads, g[1], red(*g))))
 
 
-def _fwd(q3, k3, v3, causal, scale, mask3=None, heads=1):
+def _fwd(q3, k3, v3, causal, scale, mask3=None, heads=1, sel=None, group=1):
     bh, s, d = q3.shape
     plan = _plan(s, d, q3.dtype, causal)
     n = s // plan.block
-    sp = _specs(plan, d, causal, heads, out_is_q=True)
-    in_specs = [sp["out"], sp["red"], sp["red"]]
+    sp = _specs(plan, d, causal, heads, out_is_q=True, group=group)
+    in_specs = [sp["out"], sp["kv_red"], sp["kv_red"]]
     args = [q3, k3, v3]
     if mask3 is not None:
         # k-side mask [batch, 1, s] as rows of one k chunk each; every head
@@ -365,11 +392,15 @@ def _fwd(q3, k3, v3, causal, scale, mask3=None, heads=1):
         in_specs.append(sp["mask_rows"])
         args.append(mask3.reshape(-1, n, plan.block // plan.chunk,
                                   plan.chunk))
+    if sel is not None:
+        in_specs.append(sp["sel"])
+        args.append(sel)
     carried = [pltpu.VMEM((plan.block, w), jnp.float32)
                for w in (1, 1, d)] if n > 1 else []
     o, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, causal=causal, scale=scale,
-                          plan=plan, n=n, masked=mask3 is not None),
+                          plan=plan, n=n, masked=mask3 is not None,
+                          selected=sel is not None),
         grid=(bh, n, n),
         in_specs=in_specs,
         out_specs=[sp["out"], sp["stat_out"]],
@@ -380,8 +411,9 @@ def _fwd(q3, k3, v3, causal, scale, mask3=None, heads=1):
         compiler_params=_COMPILER_PARAMS,
         # the name is the innermost component of the kernel's `op_name`,
         # and with it the compiled instruction's name ("%flash_fwd.N"):
-        # a trace tells the three kernels apart without knowing shapes
-        name=FLASH_FWD,
+        # a trace tells the three kernels apart without knowing shapes,
+        # and the selected path from the plain one
+        name=FLASH_FWD if sel is None else FLASH_SEL_FWD,
     )(*args)
     return o, lse
 
@@ -389,25 +421,30 @@ def _fwd(q3, k3, v3, causal, scale, mask3=None, heads=1):
 # --------------------------------------------------------------- backward
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
-               causal, scale, plan, n, masked):
+               causal, scale, plan, n, masked, selected=False):
     refs = list(refs)
     mask_ref = refs.pop(0) if masked else None
+    sel_ref = refs.pop(0) if selected else None
     dq_ref = refs[0]
     _, c, sub = plan
     d = q_ref.shape[-1]
 
     def prep(r):
         return (_rows(q_ref, r, c), _rows(do_ref, r, c),
-                _rows(lse_ref, r, c)[:, 0:1], _rows(delta_ref, r, c)[:, 0:1])
+                _rows(lse_ref, r, c)[:, 0:1],
+                _rows(delta_ref, r, c)[:, 0:1]), r
 
     def piece(ctx, j, g, lo, hi, tri, carry):
-        q, do, lse, delta = (x[g:g + sub] for x in ctx)
+        q, do, lse, delta = (x[g:g + sub] for x in ctx[0])
         k, v = _rows(k_ref, j * c, hi), _rows(v_ref, j * c, hi)
         s = _dot(q, k, _NT) * scale
         if tri:
             s = jnp.where(_keep_tri(sub, hi, g, True), s, NEG_INF)
         if masked:
             s = jnp.where(mask_ref[0, 0, pl.ds(j, 1), :hi] > 0, s, NEG_INF)
+        if selected:
+            s = jnp.where(_selected(sel_ref, ctx[1] + g, sub, j * c, hi), s,
+                          NEG_INF)
         ds = jnp.exp(s - lse) * (_dot(do, v, _NT) - delta) * scale
         return (carry[0] + _dot(ds.astype(k.dtype), k, _NN),)
 
@@ -419,21 +456,23 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
 
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
-                causal, scale, plan, n, masked):
+                causal, scale, plan, n, masked, selected=False):
     """dk and dv of the resident k block, a chunk of k rows at a time over
     the chunks of the resident q block. The scores are computed
     transposed, sᵀ = k qᵀ: k runs along sublanes and q along lanes, so
     lse and delta arrive as rows [1, q], the k-side mask as a column, and
-    pᵀ dO and dsᵀ q contract over lanes like any matmul."""
+    pᵀ dO and dsᵀ q contract over lanes like any matmul; a per-pair
+    selection arrives transposed too, [k, q]."""
     refs = list(refs)
     mask_ref = refs.pop(0) if masked else None
+    sel_ref = refs.pop(0) if selected else None
     dk_ref, dv_ref = refs[:2]
     _, c, sub = plan
     d = q_ref.shape[-1]
 
     def prep(r):
         keep = _rows(mask_ref, r, c)[:, 0:1] > 0 if masked else None
-        return _rows(k_ref, r, c), _rows(v_ref, r, c), keep
+        return (_rows(k_ref, r, c), _rows(v_ref, r, c), keep), r
 
     def stat_row(ref, start, size):
         # [1, size] from rows of `sub`: a row whose lanes are cut after the
@@ -443,7 +482,8 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
              for u in range(0, size, sub)], axis=1)
 
     def piece(ctx, i, g, lo, hi, tri, carry):
-        k, v, keep = (x[g:g + sub] if x is not None else None for x in ctx)
+        k, v, keep = (x[g:g + sub] if x is not None else None
+                      for x in ctx[0])
         dk, dv = carry
         q0, nq = i * c + lo, hi - lo
         q, do = _rows(q_ref, q0, nq), _rows(do_ref, q0, nq)
@@ -452,6 +492,9 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
             st = jnp.where(_keep_tri(sub, nq, 0, False), st, NEG_INF)
         if masked:
             st = jnp.where(keep, st, NEG_INF)
+        if selected:
+            st = jnp.where(_selected(sel_ref, ctx[1] + g, sub, q0, nq), st,
+                           NEG_INF)
         pt = jnp.exp(st - stat_row(lse_ref, q0, nq))
         dv = dv + _dot(pt.astype(do.dtype), do, _NN)
         dst = pt * (_dot(v, do, _NT) - stat_row(delta_ref, q0, nq)) * scale
@@ -466,7 +509,8 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
           ((0.0, d), (0.0, d)), refs[2:], prep, piece, finalize)
 
 
-def _bwd_impl(causal, scale, res, g, mask3=None, heads=1):
+def _bwd_impl(causal, scale, res, g, mask3=None, heads=1, sel=None,
+              group=1):
     q3, k3, v3, o3, lse = res
     bh, s, d = q3.shape
     plan = _plan(s, d, q3.dtype, causal)
@@ -482,17 +526,21 @@ def _bwd_impl(causal, scale, res, g, mask3=None, heads=1):
     delta_rows = delta.reshape(bh, n, block // sub, sub)
     lse_rows = lse[..., 0].reshape(bh, n, block // sub, sub)
     masked = mask3 is not None
+    selected = sel is not None
     acc = [pltpu.VMEM((block, d), jnp.float32)] if n > 1 else []
 
     # dq grid: (bh, q_block, k_block) — the k-side mask follows axis 2
-    sp = _specs(plan, d, causal, heads, out_is_q=True)
-    dq_in = [sp["out"], sp["red"], sp["red"], sp["out"], sp["stat_out"],
-             sp["stat_out"]] + ([sp["mask_rows"]] if masked else [])
+    sp = _specs(plan, d, causal, heads, out_is_q=True, group=group)
+    dq_in = [sp["out"], sp["kv_red"], sp["kv_red"], sp["out"],
+             sp["stat_out"], sp["stat_out"]] + (
+        [sp["mask_rows"]] if masked else []) + (
+        [sp["sel"]] if selected else [])
     dq_args = [q3, k3, v3, do3, lse, delta3] + (
-        [mask3.reshape(-1, n, block // c, c)] if masked else [])
+        [mask3.reshape(-1, n, block // c, c)] if masked else []) + (
+        [sel] if selected else [])
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, causal=causal, scale=scale,
-                          plan=plan, n=n, masked=masked),
+                          plan=plan, n=n, masked=masked, selected=selected),
         grid=(bh, n, n),
         in_specs=dq_in,
         out_specs=[sp["out"]],
@@ -500,77 +548,82 @@ def _bwd_impl(causal, scale, res, g, mask3=None, heads=1):
         scratch_shapes=acc,
         interpret=_interpret(),
         compiler_params=_COMPILER_PARAMS,
-        name=FLASH_BWD_DQ,
+        name=FLASH_SEL_BWD_DQ if selected else FLASH_BWD_DQ,
     )(*dq_args)[0]
 
     # grid dims: (bh, k_block, q_block) — q is the reduce (innermost) dim;
-    # the k-side mask follows axis 1 here, as a column beside k's rows
-    sp = _specs(plan, d, causal, heads, out_is_q=False)
-    dkv_in = [sp["red"], sp["out"], sp["out"], sp["red"],
+    # the k-side mask follows axis 1 here, as a column beside k's rows,
+    # and a selection arrives transposed, [k, q]. Where `group` query
+    # heads share a key/value head the grid still runs over query heads:
+    # each writes its own dk, dv in fp32 and XLA adds a group's up, which
+    # costs one pass over [bh, s, d] where a second reduce axis would
+    # cost the kernels their static walk.
+    sp = _specs(plan, d, causal, heads, out_is_q=False, group=group)
+    dkv_in = [sp["red"], sp["kv_out"], sp["kv_out"], sp["red"],
               sp["stat_rows"], sp["stat_rows"]] + (
-        [sp["mask_col"]] if masked else [])
+        [sp["mask_col"]] if masked else []) + (
+        [sp["sel"]] if selected else [])
     dkv_args = [q3, k3, v3, do3, lse_rows, delta_rows] + (
         [jnp.broadcast_to(mask3.reshape(-1, s, 1), (mask3.shape[0], s, LANE))]
-        if masked else [])
+        if masked else []) + (
+        [jnp.swapaxes(sel, 1, 2)] if selected else [])
+    part = jnp.float32 if group > 1 else None
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, causal=causal, scale=scale,
-                          plan=plan, n=n, masked=masked),
+                          plan=plan, n=n, masked=masked, selected=selected),
         grid=(bh, n, n),
         in_specs=dkv_in,
         out_specs=[sp["out"], sp["out"]],
-        out_shape=[jax.ShapeDtypeStruct((bh, s, d), k3.dtype),
-                   jax.ShapeDtypeStruct((bh, s, d), v3.dtype)],
+        out_shape=[jax.ShapeDtypeStruct((bh, s, d), part or k3.dtype),
+                   jax.ShapeDtypeStruct((bh, s, d), part or v3.dtype)],
         scratch_shapes=acc * 2,
         interpret=_interpret(),
         compiler_params=_COMPILER_PARAMS,
-        name=FLASH_BWD_DKV,
+        name=FLASH_SEL_BWD_DKV if selected else FLASH_BWD_DKV,
     )(*dkv_args)
+    if group > 1:
+        dk, dv = (x.reshape(bh // group, group, s, d).sum(1).astype(k3.dtype)
+                  for x in (dk, dv))
     return dq, dk, dv
-
-
-def _bwd(causal, scale, res, g):
-    return _bwd_impl(causal, scale, res, g)
 
 
 # ------------------------------------------------------------- public op
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _flash3(q3, k3, v3, causal, scale):
-    o, _ = _fwd(q3, k3, v3, causal, scale)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def _flash3(q3, k3, v3, mask3, sel, causal, scale, heads, group):
+    """The one differentiable wrapper of the three kernels. `mask3`
+    ([batch, 1, s] float, or None): the k-side padding mask; `sel`
+    ([batch, s_q, s_k] 0/1 bytes, or None): a per-pair selection; a
+    row's `heads` query heads share both. `group` query heads share one
+    key/value head."""
+    o, _ = _fwd(q3, k3, v3, causal, scale, mask3=mask3, heads=heads, sel=sel,
+                group=group)
     return o
 
 
-def _flash3_fwd(q3, k3, v3, causal, scale):
-    o, lse = _fwd(q3, k3, v3, causal, scale)
-    return o, (q3, k3, v3, o, lse)
+def _flash3_fwd(q3, k3, v3, mask3, sel, causal, scale, heads, group):
+    o, lse = _fwd(q3, k3, v3, causal, scale, mask3=mask3, heads=heads,
+                  sel=sel, group=group)
+    return o, (q3, k3, v3, o, lse, mask3, sel)
 
 
-_flash3.defvjp(_flash3_fwd, _bwd)
+def _flash3_bwd(causal, scale, heads, group, res, g):
+    import numpy as np
+    *res, mask3, sel = res
+    dq, dk, dv = _bwd_impl(causal, scale, res, g, mask3=mask3, heads=heads,
+                           sel=sel, group=group)
+    # neither is a value: the mask's cotangent is zero, an integer
+    # operand's is float0
+    dmask = None if mask3 is None else jnp.zeros_like(mask3)
+    dsel = None if sel is None else np.zeros(sel.shape, jax.dtypes.float0)
+    return dq, dk, dv, dmask, dsel
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
-def _flash3m(q3, k3, v3, mask3, causal, scale, heads):
-    o, _ = _fwd(q3, k3, v3, causal, scale, mask3=mask3, heads=heads)
-    return o
-
-
-def _flash3m_fwd(q3, k3, v3, mask3, causal, scale, heads):
-    o, lse = _fwd(q3, k3, v3, causal, scale, mask3=mask3, heads=heads)
-    return o, (q3, k3, v3, o, lse, mask3)
-
-
-def _flash3m_bwd(causal, scale, heads, res, g):
-    q3, k3, v3, o3, lse, mask3 = res
-    dq, dk, dv = _bwd_impl(causal, scale, (q3, k3, v3, o3, lse), g,
-                           mask3=mask3, heads=heads)
-    return dq, dk, dv, jnp.zeros_like(mask3)
-
-
-_flash3m.defvjp(_flash3m_fwd, _flash3m_bwd)
+_flash3.defvjp(_flash3_fwd, _flash3_bwd)
 
 
 def flash_attention(query, key, value, causal: bool = False,
-                    scale=None, kv_mask=None):
+                    scale=None, kv_mask=None, selection=None):
     """[b, s, h, d] fused attention. Requires s % 128 == 0.
 
     kv_mask ([b, s], bool/0-1, optional): k-side padding mask — 1 keeps
@@ -578,6 +631,17 @@ def flash_attention(query, key, value, causal: bool = False,
     attention mask; reference: the mask input of
     `operators/fused/multihead_matmul_op.cu:1`). Fully-masked rows
     return 0. Mask cotangent is zero (it is a selection, not a value).
+
+    key/value may hold fewer heads, [b, s, h_kv, d] with h % h_kv == 0:
+    query head i reads key/value head i // (h / h_kv), through the
+    kernels' index maps and without a copy (dk, dv: one partial sum a
+    query head, added up outside the kernel).
+
+    selection ([b, s_q, s_k] int8, optional): 1 where query position q may
+    read key position k, the same for every head of a row (a learned
+    top-k key selection). `causal` still says which blocks the walk may
+    skip, so a selection that lies under the diagonal is passed WITH
+    causal=True. Rows that select nothing return 0.
     """
     b, s, h, d = query.shape
     if s % 128 != 0:
@@ -586,14 +650,20 @@ def flash_attention(query, key, value, causal: bool = False,
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
 
     def to3(x):
-        return jnp.swapaxes(x, 1, 2).reshape(b * h, s, d)
+        return jnp.swapaxes(x, 1, 2).reshape(b * x.shape[2], s, d)
 
-    if kv_mask is None:
-        o3 = _flash3(to3(query), to3(key), to3(value), causal, scale)
-    else:
-        # [batch, 1, s] — heads share the batch row via the kernels'
-        # b // heads index map (no h-fold HBM duplication)
-        m3 = jnp.asarray(kv_mask, jnp.float32).reshape(b, 1, s)
-        o3 = _flash3m(to3(query), to3(key), to3(value), m3, causal, scale,
-                      h)
+    h_kv = key.shape[2]
+    if h % h_kv:
+        raise ValueError(f"{h} query heads over {h_kv} key/value heads")
+    if kv_mask is not None and (selection is not None or h_kv != h):
+        raise NotImplementedError(
+            "kv_mask with grouped heads or a selection: fold the padding "
+            "into the selection")
+    # [batch, 1, s] / [batch, s_q, s_k]: heads share the batch row via the
+    # kernels' b // heads index map (no h-fold HBM duplication)
+    m3 = None if kv_mask is None else jnp.asarray(
+        kv_mask, jnp.float32).reshape(b, 1, s)
+    sel = None if selection is None else jnp.asarray(selection, jnp.int8)
+    o3 = _flash3(to3(query), to3(key), to3(value), m3, sel, causal, scale,
+                 h, h // h_kv)
     return jnp.swapaxes(o3.reshape(b, h, s, d), 1, 2)

@@ -43,7 +43,18 @@ GPT_OFFLOAD_OUTER = "gpt_offload_outer"
 FLASH_FWD = "flash_fwd"
 FLASH_BWD_DQ = "flash_bwd_dq"
 FLASH_BWD_DKV = "flash_bwd_dkv"
+# the same three under a per-(q, k) selection, and the indexer that makes
+# the selection: its scores, and the exact top-k over them
+FLASH_SEL_FWD = "flash_sel_fwd"
+FLASH_SEL_BWD_DQ = "flash_sel_bwd_dq"
+FLASH_SEL_BWD_DKV = "flash_sel_bwd_dkv"
+INDEX_SCORES = "index_scores"
 KERNELS = (FLASH_FWD, FLASH_BWD_DQ, FLASH_BWD_DKV)
+SEL_KERNELS = (FLASH_SEL_FWD, FLASH_SEL_BWD_DQ, FLASH_SEL_BWD_DKV)
+# not ours to choose: the instruction the TPU compiler makes of
+# `jax.lax.ragged_dot` (the expert layer's grouped product) is a custom
+# call of this name, "%ragged-dot-none.3", forward and backward alike
+RAGGED_DOT = "ragged-dot-none"
 # scopes, outermost first: the step's phases, a block's two halves, the
 # attention dispatch inside `attn`, the clip inside `optimizer`
 EMBED = "embed"
@@ -55,6 +66,12 @@ CLIP = "clip"
 ATTN = "attn"
 MLP = "mlp"
 ATTENTION = "attention"
+# the sparse-attention indexer inside `attn` and its exact top-k, the
+# expert layer's two halves inside `mlp`
+INDEXER = "indexer"
+INDEXER_SELECT = "indexer.select"
+MOE_ROUTE = "moe.route"
+MOE_EXPERTS = "moe.experts"
 
 
 class Span(NamedTuple):

@@ -129,6 +129,15 @@ class Registry:
 REGISTRY = Registry()
 
 
+def static(name: str, value: int) -> None:
+    """A counter that states a size the program fixed when it was built
+    or traced (experts held, rows of a buffer, a top-k): set, not added
+    to, so building twice reads the same."""
+    c = REGISTRY.counter(name)
+    c.reset()
+    c.add(int(value))
+
+
 def merge(*snapshots) -> dict:
     """Sum snapshot dicts field-for-field: numbers add, bucket lists
     add element-wise, nested dicts (histograms, per-table sections)

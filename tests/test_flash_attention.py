@@ -377,3 +377,80 @@ def test_train_step_traces_for_its_own_mesh():
     devs = {d for leaf in jax.tree.leaves(state)
             for d in leaf.sharding.device_set}
     assert devs == {jax.devices()[0]}
+
+
+# ------------------------------------------------------------ PR 29
+# grouped key/value heads, head dim 128 and a per-(q, k) selection,
+# against masked XLA attention: forward and the three gradients
+
+def _masked_xla(q, k, v, keep):
+    """Plain attention over the pairs `keep` [b, s, s] allows, grouped
+    heads repeated; rows that keep nothing give 0, like the kernels."""
+    g = q.shape[2] // k.shape[2]
+    k, v = jnp.repeat(k, g, 2), jnp.repeat(v, g, 2)
+    sc = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(q.shape[-1])
+    p = jax.nn.softmax(jnp.where(keep[:, None], sc, -1e30), -1)
+    out = jnp.einsum("bhqk,bkhd->bqhd", p, v)
+    return jnp.where(keep.any(-1)[:, :, None, None], out, 0.0)
+
+
+@pytest.mark.parametrize("selected", [False, True], ids=["causal", "sel"])
+@pytest.mark.parametrize("s,h,h_kv,d", [
+    (256, 4, 2, 128), (256, 8, 1, 64), (128, 2, 2, 128),
+    (2304, 2, 1, 128),      # six blocks of 384 rows: carried state
+], ids=["s256-4over2-d128", "s256-8over1-d64", "s128-2over2-d128",
+        "s2304-2over1-d128"])
+def test_grouped_heads_and_selection_match_masked_xla(s, h, h_kv, d,
+                                                      selected):
+    rs = np.random.RandomState(s + h + d)
+    b = 2 if s <= 256 else 1
+    q = jnp.asarray(rs.randn(b, s, h, d) * 0.5, jnp.float32)
+    k, v = [jnp.asarray(rs.randn(b, s, h_kv, d) * 0.5, jnp.float32)
+            for _ in range(2)]
+    w = jnp.asarray(rs.randn(b, s, h, d), jnp.float32)
+    keep = jnp.broadcast_to(jnp.tril(jnp.ones((s, s), bool)), (b, s, s))
+    sel = None
+    if selected:
+        # a third of the causal pairs, every query its own position, but
+        # for one row that selects nothing at all
+        keep = (keep & jnp.asarray(rs.rand(b, s, s) < 0.3)) \
+            | jnp.eye(s, dtype=bool)
+        keep = keep.at[:, 5, :].set(False)
+        sel = keep.astype(jnp.int8)
+
+    def kernel(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, causal=True,
+                                       selection=sel) * w)
+
+    def plain(q, k, v):
+        return jnp.sum(_masked_xla(q, k, v, keep) * w)
+
+    np.testing.assert_allclose(
+        np.asarray(flash_attention(q, k, v, causal=True, selection=sel)),
+        np.asarray(_masked_xla(q, k, v, keep)), rtol=1e-5, atol=2e-5)
+    g1 = jax.grad(kernel, (0, 1, 2))(q, k, v)
+    g2 = jax.grad(plain, (0, 1, 2))(q, k, v)
+    for a, b_ in zip(g1, g2):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
+                                   rtol=1e-4, atol=5e-5)
+
+
+def test_selected_kernels_carry_their_own_names():
+    from paddle_tpu import profiler as prof
+    x = jnp.zeros((1, 128, 2, 64), jnp.float32)
+    sel = jnp.ones((1, 128, 128), jnp.int8)
+    text = str(jax.make_jaxpr(jax.grad(lambda q: jnp.sum(
+        flash_attention(q, x[:, :, :1], x[:, :, :1], causal=True,
+                        selection=sel))))(x))
+    for name in prof.SEL_KERNELS:
+        assert f"name={name}" in text or name in text, name
+    assert "name=flash_fwd" not in text
+
+
+def test_selection_with_a_kv_mask_is_refused():
+    x = jnp.zeros((1, 128, 2, 64), jnp.float32)
+    with pytest.raises(NotImplementedError):
+        flash_attention(x, x, x, kv_mask=jnp.ones((1, 128), bool),
+                        selection=jnp.ones((1, 128, 128), jnp.int8))
+    with pytest.raises(ValueError):
+        flash_attention(jnp.zeros((1, 128, 3, 64)), x, x)

@@ -1,41 +1,199 @@
-"""Mixture-of-Experts + expert parallelism (beyond-reference; the
+"""Mixture-of-Experts: top-k routing over all experts, the share of them
+one chip holds, and the dropless grouped product (beyond-reference; the
 reference snapshot only ships the alltoall building block,
-`operators/collective/alltoall_op.cc`)."""
+`operators/collective/alltoall_op.cc`). The cases of the old GShard top-2
+layer's tests live on here, against the layer that took its place."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 import paddle_tpu as pt
-from paddle_tpu.distributed import build_mesh
-from paddle_tpu.distributed.meta_parallel import MoEMLP, top2_gating
-from paddle_tpu.nn.layer import functional_call, trainable_state
+from paddle_tpu.distributed.meta_parallel import MoEMLP, topk_gating
+from paddle_tpu.distributed.meta_parallel.moe import (
+    balance_loss, dispatch_plan, grouped_experts)
+from paddle_tpu.nn.layer import buffer_state, functional_call, \
+    trainable_state
+
+
+def dense_moe(x, experts, weights, w_gate, w_up, w_down, offset=0):
+    """Every token through every held expert, masked: the plain sum."""
+    out = jnp.zeros(x.shape, jnp.float32)
+    for e in range(w_gate.shape[0]):
+        g = jnp.sum(jnp.where(experts == e + offset, weights, 0.0), -1)
+        y = (jax.nn.silu(x @ w_gate[e]) * (x @ w_up[e])) @ w_down[e]
+        out = out + g[:, None] * y
+    return out
 
 
 class TestGating:
-    def test_top2_weights_normalized_and_capacity_bounded(self):
+    @pytest.mark.parametrize("top_k", [1, 2, 3])
+    def test_topk_weights_normalized_and_nothing_dropped(self, top_k):
         rs = np.random.RandomState(0)
-        logits = jnp.asarray(rs.randn(2, 16, 4), jnp.float32)
-        dispatch, combine, aux = top2_gating(logits, capacity=6)
-        assert dispatch.shape == (2, 16, 4, 6)
-        # each token sends to at most 2 expert/slot pairs
-        per_tok = np.asarray(dispatch.sum(axis=(2, 3)))
-        assert per_tok.max() <= 2
-        # combine weights of a fully-routed token sum to ~1
-        w = np.asarray(combine.sum(axis=(2, 3)))
-        full = per_tok == 2
-        np.testing.assert_allclose(w[full], 1.0, rtol=1e-5)
-        # capacity: no expert receives more than capacity tokens
-        load = np.asarray(dispatch.sum(axis=(1, 3)))
-        assert load.max() <= 6
-        assert float(aux) > 0
+        logits = jnp.asarray(rs.randn(32, 4), jnp.float32)
+        experts, weights, probs = topk_gating(logits, top_k)
+        assert experts.shape == weights.shape == (32, top_k)
+        # every token keeps all its k assignments and their weights sum to 1
+        np.testing.assert_allclose(np.asarray(weights.sum(-1)), 1.0,
+                                   rtol=1e-6)
+        # they are the k largest of the softmax, largest first
+        order = np.argsort(-np.asarray(probs), axis=-1)[:, :top_k]
+        np.testing.assert_array_equal(np.asarray(experts), order)
+        plan = dispatch_plan(experts, 0, 4, 32 * top_k)
+        assert int(plan.sizes.sum()) == 32 * top_k
+        assert bool(plan.filled.all())
+        assert float(balance_loss(experts, probs)) > 0
 
-    def test_overflow_tokens_dropped(self):
-        # all tokens prefer expert 0 -> only `capacity` survive
-        logits = jnp.zeros((1, 10, 3)).at[:, :, 0].set(10.0)
-        dispatch, combine, _ = top2_gating(logits, capacity=4)
-        load0 = float(dispatch[0, :, 0].sum())
-        assert load0 == 4.0
+    def test_unnormalised_weights_are_the_router_probabilities(self):
+        logits = jnp.asarray(np.random.RandomState(1).randn(8, 6),
+                             jnp.float32)
+        experts, weights, probs = topk_gating(logits, 2,
+                                              norm_topk_prob=False)
+        np.testing.assert_allclose(
+            np.asarray(weights),
+            np.take_along_axis(np.asarray(probs), np.asarray(experts), -1))
+
+    def test_overflow_tokens_are_kept(self):
+        # all tokens prefer expert 0: the old layer kept `capacity` of
+        # them, this one keeps every one
+        logits = jnp.zeros((10, 3)).at[:, 0].set(10.0)
+        experts, weights, _ = topk_gating(logits, 2)
+        plan = dispatch_plan(experts, 0, 3, 20)
+        assert int(plan.sizes[0]) == 10
+        assert int(plan.sizes.sum()) == 20 and bool(plan.filled.all())
+        # rows of one expert lie together, in token order
+        np.testing.assert_array_equal(np.asarray(plan.token[:10]),
+                                      np.arange(10))
+
+    def test_ties_go_to_the_smaller_expert(self):
+        experts, _, _ = topk_gating(jnp.zeros((4, 5)), 2)
+        np.testing.assert_array_equal(np.asarray(experts),
+                                      [[0, 1]] * 4)
+
+
+class TestGroupedExperts:
+    def _weights(self, held=4, d=16, f=8, seed=0):
+        rs = np.random.RandomState(seed)
+        return [jnp.asarray(rs.randn(*s) * 0.3, jnp.float32)
+                for s in ((held, d, f), (held, d, f), (held, f, d))]
+
+    @pytest.mark.parametrize("held,offset", [(8, 0), (4, 0), (4, 2), (2, 6)])
+    def test_held_share_is_the_dense_masked_sum(self, held, offset):
+        rs = np.random.RandomState(2)
+        x = jnp.asarray(rs.randn(24, 16), jnp.float32)
+        experts, weights, _ = topk_gating(
+            jnp.asarray(rs.randn(24, 8), jnp.float32), 3)
+        ws = self._weights(held)
+        plan = dispatch_plan(experts, offset, held, 24 * min(3, held))
+        got = grouped_experts(x, plan, weights, *ws)
+        want = dense_moe(x, experts, weights, *ws, offset=offset)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=2e-5, atol=2e-6)
+
+    def test_all_rows_to_one_held_expert(self):
+        """Nothing is dropped: every token's first choice is held expert
+        1 and the buffer still gives the dense answer, gradients too."""
+        rs = np.random.RandomState(3)
+        x = jnp.asarray(rs.randn(40, 16), jnp.float32)
+        logits = jnp.asarray(rs.randn(40, 8), jnp.float32).at[:, 3].set(9.0)
+        experts, weights, _ = topk_gating(logits, 2)
+        assert bool((experts[:, 0] == 3).all())
+        ws = self._weights(4)
+
+        def grouped(x, weights, *ws):
+            plan = dispatch_plan(experts, 2, 4, 80)
+            return jnp.sum(grouped_experts(x, plan, weights, *ws) ** 2)
+
+        def dense(x, weights, *ws):
+            return jnp.sum(dense_moe(x, experts, weights, *ws,
+                                     offset=2) ** 2)
+        plan = dispatch_plan(experts, 2, 4, 80)
+        assert int(plan.sizes[1]) == 40
+        got = jax.value_and_grad(grouped, (0, 1, 2, 3, 4))(x, weights, *ws)
+        want = jax.value_and_grad(dense, (0, 1, 2, 3, 4))(x, weights, *ws)
+        for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-4, atol=1e-5)
+
+    def test_rows_of_no_group_may_hold_anything(self, monkeypatch):
+        """The TPU's grouped product skips the tiles behind the last
+        group and leaves there what it finds: with NaN in those rows, in
+        every product forward and backward, output and gradients are the
+        dense answer still."""
+        from paddle_tpu.distributed.meta_parallel import moe as mod
+        real = jax.lax.ragged_dot
+
+        @jax.custom_vjp
+        def poisoned(a, w, sizes):
+            out = real(a, w, sizes, preferred_element_type=jnp.float32)
+            beyond = jnp.arange(a.shape[0])[:, None] >= jnp.sum(sizes)
+            return jnp.where(beyond, jnp.nan, out)
+
+        def fwd(a, w, sizes):
+            return poisoned(a, w, sizes), (a, w, sizes)
+
+        def bwd(res, g):
+            a, w, sizes = res
+            _, vjp = jax.vjp(lambda a, w: real(
+                a, w, sizes, preferred_element_type=jnp.float32), a, w)
+            da, dw = vjp(g)
+            beyond = jnp.arange(a.shape[0])[:, None] >= jnp.sum(sizes)
+            return jnp.where(beyond, jnp.nan, da), dw, None
+        poisoned.defvjp(fwd, bwd)
+        monkeypatch.setattr(
+            mod.jax.lax, "ragged_dot",
+            lambda a, w, sizes, preferred_element_type=None:
+            poisoned(a, w, sizes))
+        rs = np.random.RandomState(5)
+        x = jnp.asarray(rs.randn(24, 16), jnp.float32)
+        experts, weights, _ = topk_gating(
+            jnp.asarray(rs.randn(24, 8), jnp.float32), 3)
+        ws = self._weights(4)
+
+        def grouped(x, weights, *ws):
+            plan = dispatch_plan(experts, 2, 4, 72)
+            return jnp.sum(grouped_experts(x, plan, weights, *ws) ** 2)
+
+        def dense(x, weights, *ws):
+            return jnp.sum(dense_moe(x, experts, weights, *ws,
+                                     offset=2) ** 2)
+        assert int(dispatch_plan(experts, 2, 4, 72).sizes.sum()) < 72
+        got = jax.value_and_grad(grouped, (0, 1, 2, 3, 4))(x, weights, *ws)
+        want = jax.value_and_grad(dense, (0, 1, 2, 3, 4))(x, weights, *ws)
+        for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-4, atol=1e-5)
+
+    @pytest.mark.parametrize("rows", [8, 16, 40, 80])
+    def test_rounds_of_a_small_buffer_drop_nothing(self, rows):
+        """The static buffer holds `rows`; the loop takes as many rounds
+        as the assignments need, here up to ten, with every first choice
+        on one expert: value and every gradient as the dense sum."""
+        rs = np.random.RandomState(5)
+        x = jnp.asarray(rs.randn(40, 16), jnp.float32)
+        logits = jnp.asarray(rs.randn(40, 8), jnp.float32).at[:, 3].set(9.0)
+        experts, weights, _ = topk_gating(logits, 2)
+        ws = self._weights(4)
+
+        def grouped(x, weights, *ws):
+            plan = dispatch_plan(experts, 2, 4, 80)
+            return jnp.sum(grouped_experts(x, plan, weights, *ws,
+                                           rows=rows) ** 2)
+
+        def dense(x, weights, *ws):
+            return jnp.sum(dense_moe(x, experts, weights, *ws,
+                                     offset=2) ** 2)
+        got = jax.jit(jax.value_and_grad(grouped, (0, 1, 2, 3, 4)))(
+            x, weights, *ws)
+        want = jax.value_and_grad(dense, (0, 1, 2, 3, 4))(x, weights, *ws)
+        for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-4, atol=1e-5)
+
+    def test_a_buffer_that_is_too_small_is_seen(self):
+        experts = jnp.zeros((6, 1), jnp.int32)
+        plan = dispatch_plan(experts, 0, 2, 4)
+        assert int(plan.filled.sum()) == 4 and int(plan.sizes[0]) == 6
 
 
 class TestMoEMLP:
@@ -56,40 +214,36 @@ class TestMoEMLP:
             return jnp.sum(out ** 2)
 
         g = jax.grad(loss)(params)
-        for name in ("w1", "w2", "gate_weight"):
+        for name in ("w_gate", "w_up", "w_down", "gate_weight"):
             assert float(jnp.abs(g[name]).max()) > 0, name
 
-    def test_expert_parallel_matches_single_device(self):
-        """mp=2 expert-sharded forward == mp=1 forward (the reference's
-        dist-vs-single loss-equivalence bar)."""
+    @pytest.mark.parametrize("shares", [1, 2, 4])
+    def test_shares_add_up_to_the_whole_layer(self, shares):
+        """Expert parallelism as the deployment cuts it: the partial
+        results of all the chips that share a layer (each holding
+        experts/shares of them, the router whole on each) add up to the
+        uncut layer. The old layer's sharded-vs-single-device bar."""
         pt.seed(0)
-        moe = MoEMLP(32, 64, num_experts=4)
+        whole = MoEMLP(32, 64, num_experts=4, top_k=2)
         x = self._x()
-        params = trainable_state(moe)
-
-        def fwd(p, x):
-            out, _ = functional_call(moe, p, x)
-            return out
-
-        mesh1 = build_mesh(dp=1)
-        with mesh1:
-            y1 = jax.jit(fwd)(params, x)
-        mesh2 = build_mesh(mp=2)
-        from jax.sharding import NamedSharding, PartitionSpec as P
-        with mesh2:
-            sp = {n: NamedSharding(mesh2, p.sharding_spec or P())
-                  for n, p in moe.named_parameters()}
-            p2 = {n: jax.device_put(v, sp[n]) for n, v in params.items()}
-            y2 = jax.jit(fwd)(p2, jax.device_put(
-                x, NamedSharding(mesh2, P("data", None, None))))
-            # expert weights actually sharded 2-way
-            assert p2["w1"].addressable_shards[0].data.shape[0] == 2
-        np.testing.assert_allclose(np.asarray(y1), np.asarray(y2),
-                                   rtol=2e-4, atol=2e-5)
+        want = whole(x)
+        p = trainable_state(whole)
+        held = 4 // shares
+        total = jnp.zeros_like(want)
+        for i in range(shares):
+            part = MoEMLP(32, 64, num_experts=4, top_k=2, experts_held=held,
+                          expert_offset=i * held)
+            cut = {"gate_weight": p["gate_weight"],
+                   **{n: p[n][i * held:(i + 1) * held]
+                      for n in ("w_gate", "w_up", "w_down")}}
+            out, _ = functional_call(part, cut, x)
+            total = total + out
+        np.testing.assert_allclose(np.asarray(total), np.asarray(want),
+                                   rtol=2e-5, atol=2e-6)
 
     def test_aux_loss_encourages_balance(self):
         pt.seed(0)
-        moe = MoEMLP(16, 32, num_experts=4)
+        moe = MoEMLP(16, 32, num_experts=4, aux_loss=True)
         x = self._x(d=16)
         moe(x)
         # eager path: buffer holds the value
@@ -98,9 +252,8 @@ class TestMoEMLP:
     def test_aux_loss_usable_from_jitted_step(self):
         """The aux loss must flow OUT of a jitted functional step (via
         new_buffers) — a plain attribute would leak a tracer."""
-        from paddle_tpu.nn.layer import buffer_state
         pt.seed(0)
-        moe = MoEMLP(16, 32, num_experts=4)
+        moe = MoEMLP(16, 32, num_experts=4, aux_loss=True)
         x = self._x(d=16)
         params = trainable_state(moe)
         buffers = buffer_state(moe)
@@ -114,3 +267,17 @@ class TestMoEMLP:
         assert np.isfinite(v)
         # and the module attribute did not trap a tracer
         float(moe.aux_loss.value)
+
+    def test_static_counters_say_what_is_held(self):
+        from paddle_tpu.profiler import stats
+        moe = MoEMLP(16, 32, num_experts=8, top_k=2, experts_held=2,
+                     expert_offset=4)
+        moe(self._x(d=16))
+        snap = stats.REGISTRY.snapshot()
+        assert snap["moe.experts_held"] == 2
+        assert snap["moe.rows_buffer"] == 32 * 2
+        # at the cell's size: 16384 tokens, top 8 of 128, 16 held
+        big = MoEMLP(16, 32, num_experts=128, top_k=8, experts_held=16)
+        assert big.rows_buffer(16384) == (24576, 6)
+        with pytest.raises(ValueError):
+            MoEMLP(16, 32, num_experts=8, experts_held=4, expert_offset=6)

@@ -167,6 +167,45 @@ def test_flash_kernels_compile(one_chip, shape, causal, masked, dtype):
     assert text.count("tpu_custom_call") == 3, text.count("tpu_custom_call")
 
 
+def test_selected_grouped_kernels_compile_at_keye_widths(one_chip):
+    """The Keye cell's attention at its real size: 32 query heads over 4
+    key/value heads of 128, 8192 positions, an int8 selection a row."""
+    b, s, h, h_kv, d = 2, 8192, 32, 4, 128
+
+    def shape(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def loss_and_grads(q, k, v, sel):
+        return jax.value_and_grad(
+            lambda q, k, v: fa.flash_attention(
+                q, k, v, causal=True, selection=sel
+            ).astype(jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
+
+    text = compiled_text(loss_and_grads, shape(b, s, h, d),
+                         shape(b, s, h_kv, d), shape(b, s, h_kv, d),
+                         shape(b, s, s, dtype=jnp.int8))
+    assert text.count("tpu_custom_call") == 3
+    for name in ("flash_sel_fwd", "flash_sel_bwd_dq", "flash_sel_bwd_dkv"):
+        assert name in text, name
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_indexer_compiles_at_keye_widths(one_chip, dtype):
+    """The index-score kernel (16 heads of 64 over 8192 positions) and
+    the exact top-2048 over one block of its rows."""
+    from paddle_tpu.ops import index_select as ix
+
+    def shape(*dims, dtype=dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    text = compiled_text(ix.index_scores, shape(2, 8192, 16, 64),
+                         shape(2, 8192, 64),
+                         shape(2, 8192, 16, dtype=jnp.float32))
+    assert "%index_scores" in text
+    compiled_text(lambda x: ix.select_topk(x, 2048, 7168),
+                  shape(2, 1024, 8192, dtype=jnp.float32))
+
+
 def test_dispatch_shards_kernel_over_four_chips(topo, monkeypatch):
     """scaled_dot_product_attention under a dp2 x mp2 mesh at the
     GPT-345M shape: the kernel must arrive wrapped in shard_map, or the

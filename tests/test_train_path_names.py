@@ -185,6 +185,12 @@ def metric_files() -> dict:
     return out
 
 
+# what an `op_ms` pattern may name: the program's kernels, and the one
+# instruction the compiler names for it (`jax.lax.ragged_dot`)
+NAMED = prof.KERNELS + prof.SEL_KERNELS + (prof.INDEX_SCORES,
+                                           prof.RAGGED_DOT)
+
+
 def test_metric_patterns_name_the_programs_kernels():
     """A pattern of an `op_ms` metric matches an instruction by the name
     the program gave it, "%<kernel>.<n> = ...": every such name is one
@@ -198,8 +204,8 @@ def test_metric_patterns_name_the_programs_kernels():
             if key not in spec["params"]:
                 continue
             pattern = spec["params"][key]
-            named = re.findall(r"%(\w+)", pattern)
-            assert named and set(named) <= set(prof.KERNELS), (name, key)
+            named = re.findall(r"%([\w-]+)", pattern)
+            assert named and set(named) <= set(NAMED), (name, key)
             seen.update(named)
             for kernel in named:
                 assert re.search(pattern, f"%{kernel}.16 = (bf16[128,1024,64]"
@@ -210,7 +216,7 @@ def test_metric_patterns_name_the_programs_kernels():
             for stem in ("checkpoint", "closed_call", "rematted_computation",
                          "bf16[", "f32["):
                 assert stem not in pattern, (name, stem)
-    assert seen == set(prof.KERNELS)
+    assert seen == set(NAMED)
 
 
 def program_span_names() -> set:
