@@ -119,11 +119,17 @@ def get_mesh() -> Mesh:
     return _GLOBAL_MESH
 
 
+def scoped_mesh_or_none() -> Optional[Mesh]:
+    """The mesh of the step being traced (`mesh_scope`); None outside."""
+    stack = getattr(_SCOPED, "stack", None)
+    return stack[-1] if stack else None
+
+
 def get_mesh_or_none() -> Optional[Mesh]:
     """The mesh model code shards for: the one a step is being traced
     for (`mesh_scope`), else the process-global one."""
-    stack = getattr(_SCOPED, "stack", None)
-    return stack[-1] if stack else _GLOBAL_MESH
+    scoped = scoped_mesh_or_none()
+    return _GLOBAL_MESH if scoped is None else scoped
 
 
 @contextlib.contextmanager

@@ -106,9 +106,10 @@ class BertEncoderLayer(Layer):
 
     def forward(self, x, attn_mask=None):
         b, s, d = x.shape
-        h, hd = self.num_heads, self.head_dim
         with jax.named_scope(ATTN):
-            qkv = jnp.reshape(self.qkv(x), (b, s, 3, h, hd))
+            # sharded by heads from the start (a column shard of the
+            # fused [3, heads, head_dim] weight is not a head group)
+            qkv = self.qkv.project_heads(x, 3, self.num_heads)
             q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
             attn = F.scaled_dot_product_attention(
                 q, k, v, attn_mask=attn_mask, training=self.training)

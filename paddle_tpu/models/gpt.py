@@ -28,13 +28,13 @@ from ..nn.layer import (Layer, functional_call, load_state, trainable_state)
 from ..nn.layer_common import Dropout, Embedding, LayerList
 from ..nn.layer_conv_norm import LayerNorm
 from ..distributed.meta_parallel.mp_layers import (
-    ColumnParallelLinear, ParallelCrossEntropy, RowParallelLinear,
+    TP_SUM, ColumnParallelLinear, ParallelCrossEntropy, RowParallelLinear,
     VocabParallelEmbedding, _constrain)
 from ..distributed.meta_parallel.stacked_pipeline import (
     one_f_one_b, pipelined_apply, stack_stage_params)
 from ..distributed.topology import mesh_scope
 from ..profiler import (ATTN, CLIP, DECODER, EMBED, GPT_TRAIN_STEP, LM_LOSS,
-                        MLP, OPTIMIZER, RecordEvent)
+                        MLP, OPTIMIZER, RecordEvent, stats)
 
 
 @dataclasses.dataclass
@@ -140,10 +140,11 @@ class GPTDecoderLayer(Layer):
 
     def _attn(self, x):
         b, s, d = x.shape
-        h, hd = self.num_heads, self.head_dim
-        qkv = self.qkv(self.ln1(x))   # LN in fp32, matmul in compute dtype
-        qkv = jnp.reshape(qkv, (b, s, 3, h, hd))
-        # heads sharded over 'model' (column shards = contiguous head groups)
+        # LN in fp32, matmul in compute dtype. The fused weight's columns
+        # are [3, heads, head_dim]: a contiguous column shard is NOT a head
+        # group, so the product comes sharded by heads from
+        # `project_heads`, never reshaped from a column-sharded [b, s, 3d]
+        qkv = self.qkv.project_heads(self.ln1(x), 3, self.num_heads)
         q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
         sp_attn = self._sp_attention
         if sp_attn is not None:
@@ -343,6 +344,12 @@ def build_train_step(model: Layer, optimizer, mesh,
     dim into microbatches, orthogonal to the sequence shard). This
     replaces the reference's whole meta-optimizer chain
     (`fleet_base.py:1288` → StrategyCompiler → program rewriting).
+    Under a 'model' axis (and no 'pipe' axis, whose microbatches already
+    are such streams) the layer scan applies each block to the two
+    halves of a chip's rows as two streams of one body, so that one
+    half's row-parallel sums cross the link while the other half
+    computes: one backward, one update, the same batch (`tp_streams`;
+    the count is the static counter `tp.streams`).
 
     Returns (step_fn, state) where state = (outer, stacked_blocks,
     opt_state) and step_fn(state, batch) -> (state, loss);
@@ -424,18 +431,23 @@ def build_train_step(model: Layer, optimizer, mesh,
             template._sp_attention = None
         return out
 
+    # selective remat: keep the weight-matmul outputs (no batch dims in
+    # the dot), recompute elementwise + attention (whose einsums carry
+    # batch dims) — the VERDICT r2 lever: full per-block checkpoint
+    # alone cost ~25% of achievable MFU. A row-parallel product summed by
+    # an explicit exchange (`mp_layers._row_product`) is a weight matmul's
+    # output too, but sits where the policy cannot see a dot: saved by its
+    # name, else the backward would run product and exchange again
+    dots = jax.checkpoint_policies.save_from_both_policies(
+        jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
+        jax.checkpoint_policies.save_only_these_names(TP_SUM))
     if remat_policy == "full":
         ckpt_policy = None            # rematerialize everything
     elif remat_policy == "dots":
-        # selective remat: keep the weight-matmul outputs (no batch dims in
-        # the dot), recompute elementwise + attention (whose einsums carry
-        # batch dims) — the VERDICT r2 lever: full per-block checkpoint
-        # alone cost ~25% of achievable MFU
-        ckpt_policy = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
+        ckpt_policy = dots
     elif remat_policy in _SAVED_BESIDE_DOTS:
         ckpt_policy = jax.checkpoint_policies.save_from_both_policies(
-            jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
-            jax.checkpoint_policies.save_only_these_names(
+            dots, jax.checkpoint_policies.save_only_these_names(
                 _SAVED_BESIDE_DOTS[remat_policy]))
     else:
         raise ValueError(f"unknown remat_policy {remat_policy!r}")
@@ -458,13 +470,17 @@ def build_train_step(model: Layer, optimizer, mesh,
         """One pipeline stage = scan over its L/pp blocks (shared by the
         gpipe and 1f1b schedules). `key` (when dropout > 0) is split into
         one sub-key per block so masks decorrelate across layers — a
-        closure draw would bake a single mask into the scanned body."""
+        closure draw would bake a single mask into the scanned body.
+        `h` is the batch, or a tuple of streams of it (`tp_streams`):
+        the body then applies the block to each, one after the other in
+        the program and independent in its data, so that one stream's
+        row-parallel sum is on the link while the other computes."""
         if key is None:
             fn = (jax.checkpoint(block_apply, policy=ckpt_policy)
                   if remat else block_apply)
 
             def body(carry, bp):
-                return fn(bp, carry), None
+                return jax.tree.map(lambda c: fn(bp, c), carry), None
             out, _ = jax.lax.scan(body, h, stage_p)
         else:
             fnk = (jax.checkpoint(block_apply_key, policy=ckpt_policy)
@@ -474,9 +490,42 @@ def build_train_step(model: Layer, optimizer, mesh,
 
             def body(carry, xs):
                 bp, k = xs
+                if isinstance(carry, tuple):   # a sub-key a stream
+                    ks = jax.random.split(k, len(carry))
+                    return tuple(fnk(bp, c, ki)
+                                 for c, ki in zip(carry, ks)), None
                 return fnk(bp, carry, k), None
             out, _ = jax.lax.scan(body, h, (stage_p, keys))
         return out
+
+    row_groups = axis.get("data", 1) * axis.get("sharding", 1)
+
+    def tp_streams(x):
+        """x [B, ...] as the streams the blocks are applied to: the two
+        halves of each chip's rows (split WITHIN a data x sharding group,
+        so no row changes chip) where the mesh has a 'model' axis, whose
+        sums a stream's partner can hide, and a chip holds an even number
+        of rows; else x itself."""
+        rows = x.shape[0] // row_groups
+        n = 2 if (axis.get("model", 1) > 1
+                  and x.shape[0] % row_groups == 0 and rows % 2 == 0) else 1
+        stats.static("tp.streams", n)
+        if n == 1:
+            return x
+        parts = x.reshape((row_groups, n, rows // n) + x.shape[1:])
+        return tuple(
+            _constrain(parts[:, i].reshape((-1,) + x.shape[1:]),
+                       ("data", "sharding"), seq_axis, None)
+            for i in range(n))
+
+    def tp_join(h):
+        """The batch back in its order from `tp_streams`' streams."""
+        if not isinstance(h, tuple):
+            return h
+        parts = jnp.stack([c.reshape((row_groups, -1) + c.shape[1:])
+                           for c in h], axis=1)
+        return _constrain(parts.reshape((-1,) + h[0].shape[1:]),
+                          ("data", "sharding"), seq_axis, None)
 
     def to_staged(stacked_p):
         """Leaves [L, ...] -> [pp, L/pp, ...]."""
@@ -525,7 +574,7 @@ def build_train_step(model: Layer, optimizer, mesh,
         """Apply all L blocks: scan over layers (and pipeline over stages
         when pp > 1)."""
         if pp == 1:
-            return stage_blocks(stacked_p, x, key)
+            return tp_join(stage_blocks(stacked_p, tp_streams(x), key))
         return pipelined_apply(stage_blocks, to_staged(stacked_p), x,
                                num_stages=pp,
                                num_microbatches=max(num_microbatches, pp),

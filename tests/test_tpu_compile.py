@@ -254,3 +254,57 @@ def test_dispatch_keeps_one_chip_under_a_stale_mesh(topo, one_chip,
     assert "shard_map" not in str(jax.make_jaxpr(loss_and_grads)(x, x, x))
     text = compiled_text(loss_and_grads, x, x, x)
     assert text.count("tpu_custom_call") == 3
+
+
+def test_tensor_parallel_sums_compile_to_exchanges(topo, monkeypatch):
+    """A fused head projection into a row-parallel one on the 2 x 2 mesh
+    (ZeRO x TP), forward and backward: with two chips on 'model' the row
+    product's sum and the column product's backward sum each arrive as
+    ONE asynchronous collective-permute of the activation, nothing of
+    activation size is all-reduced or gathered, and the partly-manual
+    `shard_map` they sit in compiles beside GSPMD's own partitioning."""
+    import re
+    from paddle_tpu.distributed import build_mesh, topology
+    from paddle_tpu.distributed.meta_parallel import (ColumnParallelLinear,
+                                                      RowParallelLinear)
+    from paddle_tpu.nn.layer import Layer, functional_call, trainable_state
+    monkeypatch.setattr(topology, "_GLOBAL_MESH", None)
+    mesh = build_mesh(sharding=2, mp=2, devices=list(topo.devices))
+    rows, seq, width, heads = 16, 1024, 1024, 16
+
+    class Attention(Layer):
+        def __init__(self):
+            super().__init__()
+            self.qkv = ColumnParallelLinear(width, 3 * width,
+                                            gather_output=False,
+                                            compute_dtype=jnp.bfloat16)
+            self.out = RowParallelLinear(width, width,
+                                         input_is_parallel=True,
+                                         compute_dtype=jnp.bfloat16)
+
+        def forward(self, x):
+            q = self.qkv.project_heads(x, 3, heads)[:, :, 0]
+            return self.out(q.reshape(x.shape))
+
+    layer = Attention()
+    params = {
+        n: jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=NamedSharding(
+            mesh, dict(layer.named_parameters())[n].sharding_spec or P()))
+        for n, v in trainable_state(layer).items()}
+    x = jax.ShapeDtypeStruct(
+        (rows, seq, width), jnp.bfloat16,
+        sharding=NamedSharding(mesh, P(("data", "sharding"), None, None)))
+
+    def loss_and_grads(p, x):
+        def loss(p, x):
+            with topology.mesh_scope(mesh):
+                return functional_call(layer, p, x)[0].astype(
+                    jnp.float32).sum()
+        return jax.value_and_grad(loss, argnums=(0, 1))(p, x)
+
+    text = compiled_text(loss_and_grads, params, x)
+    half = f"bf16[{rows // 2},{seq},{width}]"
+    moved = re.findall(r"= \(?(\S+?)[,)] .*?(all-reduce|all-gather|all-to-all"
+                       r"|collective-permute-start)\(", text)
+    assert [op for shape, op in moved if shape.startswith(half)] == \
+        ["collective-permute-start"] * 2, moved
