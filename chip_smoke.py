@@ -133,9 +133,10 @@ def phase_device(chips: int) -> dict:
 
 def _build_gpt_step(size: TrainSize, seed: int, mesh, **step_kw):
     """The README's path: model, AdamW with global-norm clipping, the
-    one compiled step (the settings of bench.py's GPT config)."""
+    one compiled step (the settings of the benchmark's GPT cells)."""
     import paddle_tpu as pt
-    from paddle_tpu.models import GPTForPretraining, build_train_step
+    from paddle_tpu.models import GPTForPretraining
+    from paddle_tpu.trainer import build_train_step
     pt.seed(seed)
     model = GPTForPretraining(size.cfg)
     opt = pt.optimizer.AdamW(learning_rate=1e-4, weight_decay=0.01,
@@ -234,7 +235,7 @@ def phase_train(size: TrainSize, seed: int, compiles: CompileLog,
 
 
 def _ragged_mask(rs, batch: int, seq: int):
-    """bench.py's BERT batch: lengths in [0.7 seq, seq]."""
+    """A BERT batch of ragged rows: lengths in [0.7 seq, seq]."""
     lengths = rs.randint(int(seq * 0.7), seq + 1, (batch,))
     return np.arange(seq)[None, :] < lengths[:, None]
 

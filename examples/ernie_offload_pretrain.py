@@ -25,8 +25,8 @@ def main(steps=8, o2=True):
     import jax.numpy as jnp
     import paddle_tpu as paddle
     from paddle_tpu.distributed import build_mesh
-    from paddle_tpu.models import (GPTConfig, GPTForPretraining,
-                                   build_train_step)
+    from paddle_tpu.models import GPTConfig, GPTForPretraining
+    from paddle_tpu.trainer import build_train_step
 
     paddle.seed(0)
     # toy stand-in for ernie_10b()/gpt_2p6b(); the flags are the point

@@ -15,8 +15,8 @@ def main(steps=10):
     import jax.numpy as jnp
     import paddle_tpu as paddle
     from paddle_tpu.distributed import build_mesh
-    from paddle_tpu.models import (GPTConfig, GPTForPretraining,
-                                   build_train_step)
+    from paddle_tpu.models import GPTConfig, GPTForPretraining
+    from paddle_tpu.trainer import build_train_step
 
     paddle.seed(0)
     cfg = GPTConfig(vocab_size=512, hidden_size=64, num_layers=2,

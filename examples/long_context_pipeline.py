@@ -35,8 +35,8 @@ import numpy as np                                           # noqa: E402
 
 import paddle_tpu as pt                                      # noqa: E402
 from paddle_tpu.distributed import build_mesh                # noqa: E402
-from paddle_tpu.models import (GPTConfig, GPTForPretraining,  # noqa: E402
-                               build_train_step)
+from paddle_tpu.models import GPTConfig, GPTForPretraining   # noqa: E402
+from paddle_tpu.trainer import build_train_step              # noqa: E402
 
 
 def main():

@@ -4,8 +4,7 @@ Every cold process recompiles the whole train step (GPT-345M on a v5e:
 11-13 s cold against 2 s from this cache, `chip_smoke.py` runs of PR 23;
 larger models and the tools' sweeps cost more), so the entry points that
 run on the chip
-(`chip_smoke.py`, `bench.py`, `tools/perf_sweep.py`,
-`tools/conv_profile.py`, `paddle_tpu.tools.op_bench`) call `enable()`
+(`chip_smoke.py`, `benchmarks/run.py`, `tools/conv_profile.py`, `paddle_tpu.tools.op_bench`) call `enable()`
 before their first compile. The rule:
 
 * `JAX_COMPILATION_CACHE_DIR` set in the environment: JAX reads it by

@@ -75,8 +75,9 @@ def _exchange_mesh():
 
 
 # the name a row-parallel sum is saved under by a remat policy that saves
-# the weight matmuls (`build_train_step`: it IS a weight matmul's output,
-# but the product sits in a shard_map where a policy cannot see it)
+# the weight matmuls (`trainer/trunk.py checkpoint_policy`: it IS a weight
+# matmul's output, but the product sits in a shard_map where a policy
+# cannot see it)
 TP_SUM = "tp_sum"
 
 

@@ -9,7 +9,7 @@ optimizer once. This wrapper is the API-parity path for arbitrary
 heterogeneous PipelineLayers; the *performance* pipeline — stage weights
 sharded over the 'pipe' mesh axis with the CollectivePermute microbatch
 schedule — is the stacked-stage engine (stacked_pipeline.py), used by
-`models.gpt.build_train_step` for uniform-trunk models.
+`trainer.build_train_step` for uniform-trunk models.
 """
 from __future__ import annotations
 

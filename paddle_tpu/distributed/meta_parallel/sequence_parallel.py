@@ -160,7 +160,7 @@ def make_sp_attention(mesh, mode: str = "ring", causal: bool = False,
     (`zigzag_permutation` applied along the sequence dim); positions are
     threaded through the ring so the causal mask is exact. `jit=False`
     returns the raw shard_map for embedding inside an outer jit trace
-    (e.g. models.gpt.build_train_step)."""
+    (`trainer.build_train_step`)."""
     if mode not in ("ring", "ulysses"):
         raise ValueError(f"mode must be 'ring' or 'ulysses', got {mode!r}")
     if zigzag and mode != "ring":

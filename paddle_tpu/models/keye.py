@@ -10,7 +10,7 @@ vocabulary-parallel deployment: `experts_held` experts from
 would add (their experts' outputs, their vocabulary's logits) is theirs:
 no exchange is built here. The vision tower is not part of this file.
 
-Training goes through `models.build_train_step`, like GPT: the layers are
+Training goes through `trainer.build_train_step`, like GPT: the layers are
 uniform, so the builder stacks their leaves and scans one template.
 """
 from __future__ import annotations
@@ -198,7 +198,7 @@ class KeyeForCausalLM(Layer):
     def config(self):
         return self.model.config
 
-    # what `build_train_step` asks of a model
+    # what a step builder asks of a model (`trainer/contract.py`)
     def block_template(self):
         return self.model.layers[0]
 

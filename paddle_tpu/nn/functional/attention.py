@@ -51,7 +51,7 @@ def _mesh_shards(query):
     over several devices, else None.
 
     The mesh is the one the step is being traced for
-    (`topology.mesh_scope`, entered by `build_train_step`), else the
+    (`topology.mesh_scope`, entered by `trainer.build_train_step`), else the
     process-global mesh — the same rule the models' sharding hints follow
     (`mp_layers._constrain`). A traced operand does not show where it
     will live, so under a bare `jax.jit` outside any scope the global
@@ -60,8 +60,8 @@ def _mesh_shards(query):
 
     Attention is independent per (batch row, head), so those are the two
     dims the kernel may be split on: batch over data x sharding, heads
-    over 'model' (the TP layout of the fused QKV projection,
-    models/gpt.py)."""
+    over 'model' (the TP layout of a fused QKV projection:
+    `mp_layers.ColumnParallelLinear.project_heads`)."""
     from ...distributed.topology import get_mesh_or_none
     mesh = get_mesh_or_none()
     if mesh is None or mesh.size == 1:

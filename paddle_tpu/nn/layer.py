@@ -378,14 +378,38 @@ def _slots(layer: Layer):
     return slots
 
 
+def _assign(slots, values: Optional[Dict[str, Any]]):
+    for name, v in (values or {}).items():
+        if name in slots:
+            slots[name].value = v
+
+
+@contextlib.contextmanager
+def swap_state(layer: Layer, params: Dict[str, Any],
+               buffers: Optional[Dict[str, Any]] = None):
+    """Inside the block the layer's Parameter (and buffer) slots hold the
+    given values, which may be tracers of a `jax.jit` / `jax.grad` trace;
+    on the way out, by an exception too, every slot holds again what it
+    held before. Names the layer does not have are passed over."""
+    slots = _slots(layer)
+    saved = {name: s.value for name, s in slots.items()}
+    try:
+        _assign(slots, params)
+        _assign(slots, buffers)
+        yield
+    finally:
+        for name, s in slots.items():
+            s.value = saved[name]
+
+
 def functional_call(layer: Layer, params: Dict[str, Any], *args,
                     buffers: Optional[Dict[str, Any]] = None,
                     **kwargs):
     """Run `layer` as a pure function of (params, buffers, inputs).
 
-    Swaps the given values into the layer's Parameter slots, runs forward,
-    captures (possibly updated) buffer values, then restores the originals.
-    Safe under `jax.jit`/`jax.grad` tracing: swapped values may be tracers.
+    Swaps the given values into the layer's Parameter slots
+    (`swap_state`), runs forward, captures (possibly updated) buffer
+    values, then restores the originals.
 
     Returns `(outputs, new_buffers)`.
 
@@ -393,22 +417,10 @@ def functional_call(layer: Layer, params: Dict[str, Any], *args,
     per-op C++ `Tracer` (`imperative/tracer.cc:144`) becomes a jax trace of
     the whole forward.
     """
-    slots = _slots(layer)
-    saved = {name: s.value for name, s in slots.items()}
-    try:
-        for name, v in params.items():
-            if name in slots:
-                slots[name].value = v
-        if buffers:
-            for name, v in buffers.items():
-                if name in slots:
-                    slots[name].value = v
+    with swap_state(layer, params, buffers):
         out = layer(*args, **kwargs)
         new_buffers = {name: b.value for name, b in layer.named_buffers()}
-        return out, new_buffers
-    finally:
-        for name, s in slots.items():
-            s.value = saved[name]
+    return out, new_buffers
 
 
 def trainable_state(layer: Layer) -> Dict[str, Any]:
@@ -432,13 +444,8 @@ def load_state(layer: Layer, params: Dict[str, Any],
                buffers: Optional[Dict[str, Any]] = None):
     """Write arrays back into the layer (post-step sync in training loops)."""
     slots = _slots(layer)
-    for name, v in params.items():
-        if name in slots:
-            slots[name].value = v
-    if buffers:
-        for name, v in buffers.items():
-            if name in slots:
-                slots[name].value = v
+    _assign(slots, params)
+    _assign(slots, buffers)
 
 
 @contextlib.contextmanager

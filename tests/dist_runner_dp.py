@@ -25,8 +25,8 @@ from jax.experimental import multihost_utils  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
 
 from paddle_tpu.distributed import build_mesh  # noqa: E402
-from paddle_tpu.models import (GPTConfig, GPTForPretraining,  # noqa: E402
-                               build_train_step)
+from paddle_tpu.models import GPTConfig, GPTForPretraining  # noqa: E402
+from paddle_tpu.trainer import build_train_step  # noqa: E402
 
 
 def main():

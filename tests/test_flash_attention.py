@@ -360,8 +360,8 @@ def test_train_step_traces_for_its_own_mesh():
     must not leak into a step that was built for one device."""
     import paddle_tpu as pt
     from paddle_tpu.distributed import build_mesh
-    from paddle_tpu.models.gpt import (GPTForPretraining, build_train_step,
-                                       gpt_tiny)
+    from paddle_tpu.models.gpt import GPTForPretraining, gpt_tiny
+    from paddle_tpu.trainer import build_train_step
     pt.seed(0)
     cfg = gpt_tiny(dropout=0.0)
     model = GPTForPretraining(cfg)

@@ -21,9 +21,10 @@ from benchmarks.families import keye_reference as ref  # noqa: E402
 from benchmarks.harness import cells, reference_train  # noqa: E402
 from benchmarks.harness import weights as wt  # noqa: E402
 from paddle_tpu.distributed import build_mesh  # noqa: E402
-from paddle_tpu.models import (GPTForPretraining, KeyeForCausalLM,  # noqa
-                               build_train_step)
-from paddle_tpu.models.gpt import gpt_tiny, sync_params_to_model  # noqa
+from paddle_tpu.models import GPTForPretraining, KeyeForCausalLM  # noqa
+from paddle_tpu.models.gpt import gpt_tiny  # noqa: E402
+from paddle_tpu.trainer import (build_train_step,  # noqa: E402
+                                sync_params_to_model)
 from paddle_tpu.nn import functional as F  # noqa: E402
 from paddle_tpu.nn.layer import functional_call, trainable_state  # noqa
 from paddle_tpu.ops import flash_attention as fa  # noqa: E402
@@ -284,11 +285,18 @@ def test_the_builder_gives_up_the_eager_copy_and_sync_brings_it_back():
 
 def test_builder_names_no_member_of_one_model():
     """One builder for every model that gives it its pieces."""
-    import inspect
-    from paddle_tpu.models import gpt
-    src = inspect.getsource(gpt.build_train_step)
-    code = src[src.index('"""', src.index('"""') + 3):]   # past the docstring
-    assert "model.gpt" not in code and "GPTForPretraining" not in code
+    import glob
+    import paddle_tpu.trainer as trainer
+    sources = glob.glob(os.path.join(os.path.dirname(trainer.__file__),
+                                     "*.py"))
+    assert len(sources) >= 5
+    for path in sources:
+        with open(path) as f:
+            src = f.read()
+        code = src[src.index('"""', src.index('"""') + 3):]   # past its docstring
+        for member in ("model.gpt", "model.model", "lm_head",
+                       "GPTForPretraining(", "KeyeForCausalLM("):
+            assert member not in code, (path, member)
     for cls in (GPTForPretraining, KeyeForCausalLM):
         for piece in ("block_template", "embed", "final_norm", "logits"):
             assert callable(getattr(cls, piece)), (cls, piece)

@@ -131,8 +131,8 @@ class TestZeRO:
     unsharded run. Reference bar: `sharding_optimizer.py:87-1385`."""
 
     def _run(self, mesh_dims, steps=3):
-        from paddle_tpu.models import (GPTConfig, GPTForPretraining,
-                                       build_train_step)
+        from paddle_tpu.models import GPTConfig, GPTForPretraining
+        from paddle_tpu.trainer import build_train_step
         pt.seed(0)
         cfg = GPTConfig(vocab_size=128, hidden_size=32, num_layers=2,
                         num_heads=4, max_position_embeddings=64,
@@ -180,8 +180,8 @@ class TestOneFOneB:
     with GPipe/sequential + activation residency bounded by S, not M."""
 
     def _run(self, schedule, mesh_dims, M=4, steps=2):
-        from paddle_tpu.models import (GPTConfig, GPTForPretraining,
-                                       build_train_step)
+        from paddle_tpu.models import GPTConfig, GPTForPretraining
+        from paddle_tpu.trainer import build_train_step
         pt.seed(0)
         cfg = GPTConfig(vocab_size=128, hidden_size=32, num_layers=4,
                         num_heads=4, max_position_embeddings=64,
@@ -217,8 +217,8 @@ class TestOneFOneB:
         """GPipe holds all M microbatch stashes live across the backward;
         1F1B's stash ring is depth 2S-1 — compiled temp memory must grow
         with M for GPipe but stay ~flat for 1F1B."""
-        from paddle_tpu.models import (GPTConfig, GPTForPretraining,
-                                       build_train_step)
+        from paddle_tpu.models import GPTConfig, GPTForPretraining
+        from paddle_tpu.trainer import build_train_step
 
         def temp_bytes(schedule, M):
             pt.seed(0)
@@ -249,8 +249,8 @@ class TestOneFOneB:
         """Train with dropout=0.1 under the given schedule; per-(microbatch,
         stage) dropout keys derive identically in both schedules
         (stacked_pipeline._mb_key) so losses must match exactly."""
-        from paddle_tpu.models import (GPTConfig, GPTForPretraining,
-                                       build_train_step)
+        from paddle_tpu.models import GPTConfig, GPTForPretraining
+        from paddle_tpu.trainer import build_train_step
         pt.seed(0)
         cfg = GPTConfig(vocab_size=128, hidden_size=32, num_layers=4,
                         num_heads=4, max_position_embeddings=64,
@@ -279,8 +279,8 @@ class TestOneFOneB:
     def test_dropout_masks_differ_across_steps(self):
         """Two different step keys must give different losses (the mask is
         not baked into the compiled program as a constant)."""
-        from paddle_tpu.models import (GPTConfig, GPTForPretraining,
-                                       build_train_step)
+        from paddle_tpu.models import GPTConfig, GPTForPretraining
+        from paddle_tpu.trainer import build_train_step
         pt.seed(0)
         cfg = GPTConfig(vocab_size=128, hidden_size=32, num_layers=2,
                         num_heads=4, max_position_embeddings=64,
@@ -301,8 +301,8 @@ class TestOneFOneB:
 
 class TestTrainStep:
     def test_hybrid_train_step_decreases_loss(self):
-        from paddle_tpu.models import (GPTForPretraining, build_train_step,
-                                       gpt_tiny)
+        from paddle_tpu.models import GPTForPretraining, gpt_tiny
+        from paddle_tpu.trainer import build_train_step
         pt.seed(0)
         mesh = build_mesh(dp=2, pp=2, mp=2)
         model = GPTForPretraining(gpt_tiny())
@@ -320,8 +320,8 @@ class TestTrainStep:
         """Same model/config trained on the hybrid mesh vs plain jit must
         produce the same loss trajectory (the reference's dist-vs-single
         loss-equivalence assertion, test_dist_base.py:743)."""
-        from paddle_tpu.models import (GPTForPretraining, build_train_step,
-                                       gpt_tiny)
+        from paddle_tpu.models import GPTForPretraining, gpt_tiny
+        from paddle_tpu.trainer import build_train_step
         import dataclasses
         cfg = dataclasses.replace(gpt_tiny(), dtype=jnp.float32)
         rs = np.random.RandomState(0)
@@ -474,8 +474,8 @@ class TestZero3:
     (`sharding_optimizer.py:87-1385`)."""
 
     def _run(self, mesh_dims, zero_stage, steps=3):
-        from paddle_tpu.models import (GPTConfig, GPTForPretraining,
-                                       build_train_step)
+        from paddle_tpu.models import GPTConfig, GPTForPretraining
+        from paddle_tpu.trainer import build_train_step
         pt.seed(0)
         cfg = GPTConfig(vocab_size=128, hidden_size=64, num_layers=4,
                         num_heads=4, max_position_embeddings=64,

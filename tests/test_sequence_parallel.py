@@ -91,8 +91,8 @@ class TestSPTrainStep:
 
     def _loss(self, mesh_fn, **kw):
         import paddle_tpu as pt
-        from paddle_tpu.models import GPTForPretraining, build_train_step, \
-            gpt_tiny
+        from paddle_tpu.models import GPTForPretraining, gpt_tiny
+        from paddle_tpu.trainer import build_train_step
 
         mesh = mesh_fn()   # build right before use: _constrain reads the
         pt.seed(0)         # global mesh set by build_mesh
@@ -156,8 +156,8 @@ class TestSPTrainStep:
         full +-lr update flips (m/sqrt(v) ~ +-1 regardless of grad
         size), which tests optimizer sensitivity, not the schedule."""
         import paddle_tpu as pt
-        from paddle_tpu.models import GPTForPretraining, \
-            build_train_step, gpt_tiny
+        from paddle_tpu.models import GPTForPretraining, gpt_tiny
+        from paddle_tpu.trainer import build_train_step
 
         def one_step(mesh_fn, **kw):
             mesh = mesh_fn()
@@ -199,14 +199,14 @@ class TestOffload:
 
     def test_chunked_offload_step_matches_reference_step(self):
         """offload=True runs a CHUNKED update (grad jit + per-chunk slot
-        streaming, `gpt.py _build_offload_chunked_step`) so peak HBM is
+        streaming, `trainer/offload.py build_offload_step`) so peak HBM is
         params+grads+ONE chunk of slots — the single-jit design OOMed
         at compile exactly as if there were no offload (r4 bench,
         ERNIE-1.3B: 18.4G of 15.75G). The streamed step must be
         numerically IDENTICAL to the resident step."""
         import paddle_tpu as pt
-        from paddle_tpu.models import GPTForPretraining, \
-            build_train_step, gpt_tiny
+        from paddle_tpu.models import GPTForPretraining, gpt_tiny
+        from paddle_tpu.trainer import build_train_step
 
         cfg = gpt_tiny()
         rs = np.random.RandomState(0)
@@ -233,7 +233,7 @@ class TestOffload:
         # slot-tuple indexing, and cross-chunk dynamic_update_slice
         # accumulation are all exercised (gpt_tiny's slots would
         # otherwise fit one chunk)
-        from paddle_tpu.models import gpt as gpt_mod
+        from paddle_tpu.trainer import offload as gpt_mod
         saved = gpt_mod._OFFLOAD_CHUNK_BYTES
         gpt_mod._OFFLOAD_CHUNK_BYTES = 1
         try:
@@ -252,8 +252,8 @@ class TestOffload:
         """Adagrad's initial_accumulator_value must survive the
         host-resident slot construction (it is NOT zeros)."""
         import paddle_tpu as pt
-        from paddle_tpu.models import GPTForPretraining, \
-            build_train_step, gpt_tiny
+        from paddle_tpu.models import GPTForPretraining, gpt_tiny
+        from paddle_tpu.trainer import build_train_step
 
         cfg = gpt_tiny()
         rs = np.random.RandomState(0)
@@ -287,8 +287,8 @@ class TestOffload:
         training still converges. Reference: pure-fp16 decorator +
         adam multi-precision."""
         import paddle_tpu as pt
-        from paddle_tpu.models import GPTForPretraining, \
-            build_train_step, gpt_tiny
+        from paddle_tpu.models import GPTForPretraining, gpt_tiny
+        from paddle_tpu.trainer import build_train_step
 
         pt.seed(0)
         cfg = gpt_tiny()
@@ -320,8 +320,8 @@ class TestOffload:
         """cfg.dropout > 0 routes the per-step key through the chunked
         grad jit; a missing key must raise, fresh keys must train."""
         import paddle_tpu as pt
-        from paddle_tpu.models import GPTForPretraining, \
-            build_train_step, gpt_tiny
+        from paddle_tpu.models import GPTForPretraining, gpt_tiny
+        from paddle_tpu.trainer import build_train_step
 
         pt.seed(0)
         cfg = gpt_tiny(dropout=0.1)
@@ -350,8 +350,8 @@ class TestOffload:
         fleet.save_persistables over offloaded sharding state)."""
         import os as _os
         import paddle_tpu as pt
-        from paddle_tpu.models import GPTForPretraining, \
-            build_train_step, gpt_tiny
+        from paddle_tpu.models import GPTForPretraining, gpt_tiny
+        from paddle_tpu.trainer import build_train_step
 
         pt.seed(0)
         cfg = gpt_tiny()
@@ -380,8 +380,8 @@ class TestOffload:
 
     def test_offload_rejects_norm_based_optimizers(self):
         import paddle_tpu as pt
-        from paddle_tpu.models import GPTForPretraining, \
-            build_train_step, gpt_tiny
+        from paddle_tpu.models import GPTForPretraining, gpt_tiny
+        from paddle_tpu.trainer import build_train_step
 
         pt.seed(0)
         mesh = build_mesh(dp=2)
@@ -393,8 +393,8 @@ class TestOffload:
     def test_slots_rest_in_host_memory(self):
         import jax
         import paddle_tpu as pt
-        from paddle_tpu.models import GPTForPretraining, \
-            build_train_step, gpt_tiny
+        from paddle_tpu.models import GPTForPretraining, gpt_tiny
+        from paddle_tpu.trainer import build_train_step
 
         mesh = build_mesh(dp=2, sharding=2, mp=2)
         model = GPTForPretraining(gpt_tiny())

@@ -17,7 +17,8 @@ import pytest
 
 import paddle_tpu as pt
 from paddle_tpu.distributed import build_mesh
-from paddle_tpu.models import GPTConfig, GPTForPretraining, build_train_step
+from paddle_tpu.models import GPTConfig, GPTForPretraining
+from paddle_tpu.trainer import build_train_step
 from paddle_tpu.profiler import stats
 
 SEQ, WIDTH, HEADS, LAYERS, VOCAB = 48, 64, 4, 3, 128

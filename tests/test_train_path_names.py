@@ -22,7 +22,8 @@ import paddle_tpu as pt
 import paddle_tpu.profiler as prof
 from paddle_tpu.core import compile_cache, native
 from paddle_tpu.distributed import build_mesh
-from paddle_tpu.models import GPTForPretraining, build_train_step
+from paddle_tpu.models import GPTForPretraining
+from paddle_tpu.trainer import build_train_step
 from paddle_tpu.models.bert import BertForPretraining, bert_tiny
 from paddle_tpu.models.gpt import gpt_tiny
 from paddle_tpu.nn.functional import attention
@@ -124,9 +125,12 @@ def test_offloaded_step_names_its_three_programs():
     for program in (prof.GPT_OFFLOAD_GRAD, prof.GPT_OFFLOAD_CHUNK,
                     prof.GPT_OFFLOAD_OUTER):
         assert f"jit({program})" in compiled
-    with open(os.path.join(REPO, "paddle_tpu", "models", "gpt.py")) as f:
-        source = f.read()
-    assert "PTPU_OFFLOAD_SYNC" not in source and "_trace(" not in source
+    sources = glob.glob(os.path.join(REPO, "paddle_tpu", "trainer", "*.py"))
+    assert len(sources) >= 5
+    for path in sources:
+        with open(path) as f:
+            source = f.read()
+        assert "PTPU_OFFLOAD_SYNC" not in source and "_trace(" not in source
 
 
 def test_pallas_calls_carry_their_names(kernels_on_cpu):

@@ -3,7 +3,7 @@
 Breaks the train step into parts and times each directly on the chip
 (a host-clock decomposition, not a profiler trace):
 
-  1. full train step (matches bench.py config 1)
+  1. full train step (ResNet-50, BASELINE config 1)
   2. forward-only, loss-only
   3. per-stage forward (stem, layer1..4, head)
   4. conv microbench: every distinct (shape, stride) conv2d in ResNet-50

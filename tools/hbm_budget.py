@@ -7,7 +7,7 @@ offload counting the whole optimizer state against peak HBM) is
 invisible at hidden=64. This tool closes that hole WITHOUT hardware:
 
   1. `estimate(cfg, ...)` — closed-form per-chip peak-HBM for
-     `models.gpt.build_train_step` (params/grads/slots by zero stage,
+     `trainer.build_train_step` (params/grads/slots by zero stage,
      param dtype, offload chunk window; activation residency by remat
      policy; chunked-CE logits).
   2. `validate_scaled()` — compiles the REAL step at a scaled config on
@@ -49,7 +49,8 @@ def estimate(cfg, *, batch: int, seq: int, tp: int = 1, shard: int = 1,
              remat: str = "full", loss_chunks: int = 8) -> dict:
     """Per-chip peak-HBM breakdown in bytes for one train step.
 
-    Mirrors build_train_step's residency rules (models/gpt.py):
+    Mirrors build_train_step's residency rules (trainer/state.py,
+    trainer/offload.py):
       params rest sharded over tp x (shard if zero3);
       grads mirror params;
       AdamW slots (m, v fp32) + optional fp32 masters shard over
@@ -61,7 +62,7 @@ def estimate(cfg, *, batch: int, seq: int, tp: int = 1, shard: int = 1,
       weight-matmul outputs (~4 more [b,s,d]-class tensors per layer);
       chunked CE materializes [b_local, s/chunks, V] fp32 logits once.
     """
-    from paddle_tpu.models.gpt import _OFFLOAD_CHUNK_BYTES
+    from paddle_tpu.trainer.offload import _OFFLOAD_CHUNK_BYTES
 
     P = param_count(cfg)
     d, L, V = cfg.hidden_size, cfg.num_layers, cfg.vocab_size
@@ -100,8 +101,8 @@ def _compile_peak(num_layers: int) -> float:
     import jax.numpy as jnp
     import paddle_tpu as pt
     from paddle_tpu.distributed import build_mesh
-    from paddle_tpu.models import (GPTConfig, GPTForPretraining,
-                                   build_train_step)
+    from paddle_tpu.models import GPTConfig, GPTForPretraining
+    from paddle_tpu.trainer import build_train_step
 
     cfg = GPTConfig(vocab_size=4096, hidden_size=256,
                     num_layers=num_layers, num_heads=8,
