@@ -49,6 +49,7 @@ FLASH_SEL_FWD = "flash_sel_fwd"
 FLASH_SEL_BWD_DQ = "flash_sel_bwd_dq"
 FLASH_SEL_BWD_DKV = "flash_sel_bwd_dkv"
 INDEX_SCORES = "index_scores"
+INDEX_TOPK = "index_topk"
 KERNELS = (FLASH_FWD, FLASH_BWD_DQ, FLASH_BWD_DKV)
 SEL_KERNELS = (FLASH_SEL_FWD, FLASH_SEL_BWD_DQ, FLASH_SEL_BWD_DKV)
 # not ours to choose: the instruction the TPU compiler makes of

@@ -3,6 +3,7 @@ benchmark's plain reference on seeded weights at a tiny size (hidden 64,
 2 layers, 8 experts / 4 held / top 2, top 16 keys at 64 positions, so
 the selection bites), the indexer's exact top-k against a sort, and the
 model through `build_train_step`, the builder GPT goes through."""
+import functools
 import os
 import sys
 
@@ -149,21 +150,48 @@ def test_shares_of_one_layer_add_up_to_the_uncut_layer():
                                rtol=2e-4, atol=2e-4)
 
 
-@pytest.mark.parametrize("rows,s,topk,t0,kind", [
+TOPK_CASES = [
     (64, 64, 16, 0, "random"), (64, 64, 16, 0, "ties"),
     (64, 128, 16, 64, "ties"), (128, 256, 100, 128, "random"),
     (64, 64, 64, 0, "random"), (64, 64, 70, 0, "random"),
-    (8, 8192, 2048, 8184, "ties")])
-def test_exact_topk_against_a_sort(rows, s, topk, t0, kind):
+    (8, 8192, 2048, 8184, "ties"),
+    # at shapes the kernel takes: t < topk in a block that also searches,
+    # topk at and above the keys, an offset, three row blocks of which the
+    # first keeps every key, the 8192-wide ties, and ties that crowd the
+    # threshold in the second row block alone
+    (128, 128, 16, 0, "random"), (128, 128, 16, 0, "ties"),
+    (128, 128, 128, 0, "random"), (64, 128, 130, 64, "random"),
+    (384, 384, 128, 0, "random"), (32, 8192, 2048, 8160, "ties"),
+    (512, 512, 100, 0, "ties below"),
+]
+
+
+def kernel_takes(rows, s, t0):
+    return rows % 32 == 0 and s % 128 == 0 and t0 + rows <= s
+
+
+@pytest.mark.parametrize("rows,s,topk,t0,kind,how", [
+    case + (how,) for case in TOPK_CASES for how in ("xla", "kernel")
+    if how == "xla" or kernel_takes(case[0], case[1], case[3])])
+def test_exact_topk_against_a_sort(monkeypatch, rows, s, topk, t0, kind,
+                                   how):
     """Ties to the smaller key, every key where t < topk, signed zeros
-    one value: the digit search against the reference's sort."""
+    one value: XLA's digit search and the kernel (interpreted), each
+    against the reference's sort."""
     rs = np.random.RandomState(rows + topk)
     x = rs.randn(2, rows, s).astype(np.float32)
     if kind == "ties":
         x = np.round(x * 1.5)
+    elif kind == "ties below":      # the kernel's second block of 256 rows
+        x[:, 256:] = np.round(x[:, 256:] * 1.5)
     x[0, 3, ::2] = -0.0
     x[0, 3, 1::2] = 0.0
-    got = np.asarray(ix.select_topk(jnp.asarray(x), topk, t0))
+    if how == "kernel":
+        monkeypatch.setattr(fa, "_interpret", lambda: True)
+        got = np.asarray(jax.jit(
+            lambda x: ix.index_topk(x, topk, t0))(jnp.asarray(x)))
+    else:
+        got = np.asarray(ix.select_topk(jnp.asarray(x), topk, t0))
     want = np.stack([np.asarray(ref.select(jnp.asarray(r), t0, topk))
                      for r in x])
     assert got.dtype == np.int8
@@ -184,10 +212,18 @@ def test_index_scores_kernel_and_the_whole_selection(monkeypatch):
                                rtol=1e-5, atol=1e-4)
     # tiles (256 x 256 at float32) above the diagonal are not computed
     assert float(jnp.abs(got[:, :256, 256:]).max()) == 0.0
-    sel = ix.topk_selection(q, k, w, 100, block=256)
+    # the whole selection is one call of the top-k kernel: two row blocks
+    # of 256, `topk` inside the first
+    select = functools.partial(ix.topk_selection, topk=100)
+    text = str(jax.make_jaxpr(select)(q, k, w))
+    assert text.count("name=index_topk") == 1
+    sel = select(q, k, w)
     ref_sel = np.stack([np.asarray(ref.select(want[i], 0, 100))
                         for i in range(2)])
     np.testing.assert_array_equal(np.asarray(sel).astype(bool), ref_sel)
+    # and off the TPU XLA's blocks of rows give the same
+    np.testing.assert_array_equal(
+        np.asarray(sel), np.asarray(ix._select_blocks(got, 100, block=256)))
 
 
 def test_rotary_and_selected_attention_against_the_reference():

@@ -192,7 +192,7 @@ def test_selected_grouped_kernels_compile_at_keye_widths(one_chip):
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
 def test_indexer_compiles_at_keye_widths(one_chip, dtype):
     """The index-score kernel (16 heads of 64 over 8192 positions) and
-    the exact top-2048 over one block of its rows."""
+    the exact top-2048 of all its rows, one kernel too."""
     from paddle_tpu.ops import index_select as ix
 
     def shape(*dims, dtype=dtype):
@@ -202,8 +202,9 @@ def test_indexer_compiles_at_keye_widths(one_chip, dtype):
                          shape(2, 8192, 64),
                          shape(2, 8192, 16, dtype=jnp.float32))
     assert "%index_scores" in text
-    compiled_text(lambda x: ix.select_topk(x, 2048, 7168),
-                  shape(2, 1024, 8192, dtype=jnp.float32))
+    text = compiled_text(lambda x: ix.index_topk(x, 2048),
+                         shape(2, 8192, 8192, dtype=jnp.float32))
+    assert "%index_topk" in text and text.count("tpu_custom_call") == 1
 
 
 def test_dispatch_shards_kernel_over_four_chips(topo, monkeypatch):

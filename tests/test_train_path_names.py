@@ -192,7 +192,7 @@ def metric_files() -> dict:
 # what an `op_ms` pattern may name: the program's kernels, and the one
 # instruction the compiler names for it (`jax.lax.ragged_dot`)
 NAMED = prof.KERNELS + prof.SEL_KERNELS + (prof.INDEX_SCORES,
-                                           prof.RAGGED_DOT)
+                                           prof.INDEX_TOPK, prof.RAGGED_DOT)
 
 
 def test_metric_patterns_name_the_programs_kernels():
@@ -221,6 +221,24 @@ def test_metric_patterns_name_the_programs_kernels():
                          "bf16[", "f32["):
                 assert stem not in pattern, (name, stem)
     assert seen == set(NAMED)
+
+
+def test_index_topk_ms_is_the_keye_cells_alone():
+    """The top-k kernel's metric (ISSUE 32) is reported where an indexer
+    runs and nowhere else."""
+    if REPO not in sys.path:
+        sys.path.insert(0, REPO)
+    from benchmarks.harness import cells
+    for cell in cells.benchmark()["workloads"]:
+        names = {m["name"] for m in cells.resolve(cell["name"])["per_layer"]}
+        assert ("index_topk_ms" in names) == cell["name"].startswith("keye")
+        assert ("index_topk_ms" in names) == ("index_select_ms" in names)
+    read, params = cells.reader("index_topk_ms")
+    assert read.__module__.endswith("readers.op_ms")
+    assert re.search(params["pattern"], f"%{prof.INDEX_TOPK}.3 = (s8[2,8192,"
+                     "8192]{2,1,0}) custom-call(%fusion.1)")
+    # nothing to read (the parent's program, or no trace): nothing returned
+    assert read({}, params) is None
 
 
 def program_span_names() -> set:
