@@ -10,6 +10,15 @@ and the chip computes the assignments that fall on the experts it holds
 experts would add is their chips' to add: the exchange across chips is
 not here (ROADMAP "Reach"), and nothing stands in for it.
 
+Two ways of scoring (`MoEMLP(scoring=...)`, a model's published key):
+a softmax over all experts with the chosen weights renormalised
+(`topk_gating`), and DeepSeek-V3's sigmoid scores, where the CHOICE is by
+score + a per-expert bias (a leaf that no gradient reaches: the source
+moves it by the load, outside the loss) and the WEIGHT the unbiased
+score, renormalised and scaled (`sigmoid_gating`). A shared expert
+(`shared_width`) is one gated MLP that every token takes beside its
+routed ones; it is whole on every chip.
+
 Routing is sort-based, not mask-based: the assignments are ordered by held
 expert (absent ones last), their tokens' rows are gathered into a row
 buffer, and the three expert matrices are applied as grouped products over
@@ -36,7 +45,8 @@ import jax.numpy as jnp
 from ...nn import functional as F
 from ...nn import initializer as I
 from ...nn.layer import Layer
-from ...profiler import MOE_EXPERTS, MOE_ROUTE, stats
+from ...profiler import MOE_EXPERTS, MOE_ROUTE, MOE_SHARED, stats
+from .mp_layers import ColumnParallelLinear, RowParallelLinear
 
 
 def topk_gating(logits, top_k: int, norm_topk_prob: bool = True):
@@ -49,6 +59,25 @@ def topk_gating(logits, top_k: int, norm_topk_prob: bool = True):
     if norm_topk_prob:
         weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
     return experts, weights, probs
+
+
+def sigmoid_gating(logits, top_k: int, bias=None,
+                   norm_topk_prob: bool = True, scaling: float = 1.0):
+    """Router logits [t, e] -> (experts, weights, scores) as
+    `topk_gating` gives them, DeepSeek-V3's way: scores are sigmoids in
+    float32, the `top_k` are chosen by score + `bias` [e] (which takes no
+    gradient), the weights are the chosen experts' scores WITHOUT the
+    bias, over their sum + 1e-20 where `norm_topk_prob`, times
+    `scaling`."""
+    scores = jax.nn.sigmoid(logits.astype(jnp.float32))
+    choice = scores if bias is None else \
+        scores + jax.lax.stop_gradient(bias.astype(jnp.float32))
+    _, experts = jax.lax.top_k(choice, top_k)
+    weights = jnp.take_along_axis(scores, experts, axis=-1)
+    if norm_topk_prob:
+        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True)
+                             + 1e-20)
+    return experts, weights * scaling, scores
 
 
 def balance_loss(experts, probs):
@@ -202,20 +231,55 @@ def grouped_experts(x, plan: Dispatch, weights, w_gate, w_up, w_down,
     return _rounds(*args, plan, rows)
 
 
+class GatedMLP(Layer):
+    """down(silu(gate(x)) * up(x)), no biases: a dense layer's MLP, or
+    the shared expert of an expert layer."""
+
+    def __init__(self, d_model: int, d_ff: int, compute_dtype=None,
+                 initializer_range: float = 0.02):
+        super().__init__()
+        init = I.Normal(0.0, initializer_range)
+
+        def column():
+            return ColumnParallelLinear(
+                d_model, d_ff, weight_attr=init, has_bias=False,
+                gather_output=False, compute_dtype=compute_dtype)
+        self.gate_proj, self.up_proj = column(), column()
+        self.down_proj = RowParallelLinear(
+            d_ff, d_model, weight_attr=init, has_bias=False,
+            input_is_parallel=True, compute_dtype=compute_dtype)
+
+    def forward(self, x):
+        return self.down_proj(F.swiglu(self.gate_proj(x), self.up_proj(x)))
+
+
 class MoEMLP(Layer):
     """Top-k routed, SiLU-gated experts; one chip's share of the layer.
 
     `experts_held` of the `num_experts` live here, from `expert_offset`
     (default: all of them). The router is `num_experts` wide whatever is
-    held. `aux_loss=True` keeps the load-balancing loss of the last call
-    in a buffer (it survives `functional_call` / jit as a new buffer)."""
+    held. `scoring` "softmax" or "sigmoid" (module docstring); with
+    "sigmoid", `choice_bias=True` adds the leaf `choice_bias`
+    [num_experts] to the scores for the choice alone, and
+    `routed_scaling_factor` scales the weights. `shared_width`: a gated
+    MLP of that width (`shared`) that every token takes beside its routed
+    experts. `aux_loss=True` keeps the load-balancing loss of the last
+    call in a buffer (it survives `functional_call` / jit as a new
+    buffer)."""
 
     def __init__(self, d_model: int, d_ff: int, num_experts: int,
                  top_k: int = 2, experts_held: Optional[int] = None,
                  expert_offset: int = 0, norm_topk_prob: bool = True,
                  compute_dtype=None, initializer_range: float = 0.02,
-                 aux_loss: bool = False, rows_buffer: Optional[int] = None):
+                 aux_loss: bool = False, rows_buffer: Optional[int] = None,
+                 scoring: str = "softmax", choice_bias: bool = False,
+                 routed_scaling_factor: float = 1.0,
+                 shared_width: Optional[int] = None):
         super().__init__()
+        if scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"unknown scoring {scoring!r}")
+        if choice_bias and scoring != "sigmoid":
+            raise ValueError("a choice bias goes with sigmoid scoring")
         held = num_experts if experts_held is None else experts_held
         if not 0 <= expert_offset <= num_experts - held:
             raise ValueError(f"experts {expert_offset}..{expert_offset + held}"
@@ -232,6 +296,13 @@ class MoEMLP(Layer):
                                           default_initializer=init)
         self.w_down = self.create_parameter((held, d_ff, d_model),
                                             default_initializer=init)
+        self.scoring = scoring
+        self.routed_scaling_factor = routed_scaling_factor
+        self.choice_bias = self.create_parameter(
+            (num_experts,), default_initializer=I.Constant(0.0)) \
+            if choice_bias else None
+        self.shared = GatedMLP(d_model, shared_width, compute_dtype,
+                               initializer_range) if shared_width else None
         self._cdt = compute_dtype
         self._rows = rows_buffer
         if aux_loss:
@@ -260,8 +331,15 @@ class MoEMLP(Layer):
                 xt.astype(jnp.float32),
                 jnp.asarray(self.gate_weight).astype(jnp.float32),
                 precision=jax.lax.Precision.HIGHEST)
-            experts, weights, probs = topk_gating(logits, self.top_k,
-                                                  self.norm_topk_prob)
+            if self.scoring == "sigmoid":
+                bias = None if self.choice_bias is None else \
+                    jnp.asarray(self.choice_bias)
+                experts, weights, probs = sigmoid_gating(
+                    logits, self.top_k, bias, self.norm_topk_prob,
+                    self.routed_scaling_factor)
+            else:
+                experts, weights, probs = topk_gating(logits, self.top_k,
+                                                      self.norm_topk_prob)
             if self._aux:
                 self.aux_loss.value = balance_loss(experts, probs)
             rows, rounds = self.rows_buffer(b * s)
@@ -269,8 +347,14 @@ class MoEMLP(Layer):
                                  self.experts_held, rows * rounds)
         stats.static("moe.experts_held", self.experts_held)
         stats.static("moe.rows_buffer", rows)
+        stats.static("moe.top_k", self.top_k)
         with jax.named_scope(MOE_EXPERTS):
             y = grouped_experts(xt, plan, weights, jnp.asarray(self.w_gate),
                                 jnp.asarray(self.w_up),
                                 jnp.asarray(self.w_down), self._cdt, rows)
+        if self.shared is not None:
+            stats.static("moe.shared_width",
+                         self.shared.gate_proj.out_features)
+            with jax.named_scope(MOE_SHARED):
+                y = y + self.shared(xt)
         return y.reshape(b, s, d).astype(x.dtype)

@@ -31,6 +31,12 @@ from .keye import (  # noqa: F401
     KeyeModel,
     keye_tiny,
 )
+from .deepseek_v3 import (  # noqa: F401
+    DeepseekV3Config,
+    DeepseekV3ForCausalLM,
+    DeepseekV3Model,
+    deepseek_v3_tiny,
+)
 from .bert import (  # noqa: F401
     BertConfig,
     BertForPretraining,

@@ -228,10 +228,10 @@ class GPTForPretraining(Layer):
         return self.gpt.config
 
     # the pieces a step builder asks for beside `config`, `logits` and
-    # `criterion` (`paddle_tpu/trainer/contract.py`): one block to scan
-    # over stacked leaves, the embedding and the last norm
-    def block_template(self):
-        return self.gpt.layers[0]
+    # `criterion` (`paddle_tpu/trainer/contract.py`): one group of alike
+    # blocks to scan over stacked leaves, the embedding and the last norm
+    def block_groups(self):
+        return [(self.gpt.layers[0], self.config.num_layers)]
 
     def embed(self, input_ids, position_ids=None):
         return self.gpt.embeddings(input_ids, position_ids)

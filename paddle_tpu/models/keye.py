@@ -199,8 +199,8 @@ class KeyeForCausalLM(Layer):
         return self.model.config
 
     # what a step builder asks of a model (`trainer/contract.py`)
-    def block_template(self):
-        return self.model.layers[0]
+    def block_groups(self):
+        return [(self.model.layers[0], self.config.num_layers)]
 
     def embed(self, input_ids, position_ids=None):
         # positions are rotary, inside the layers; text only: 0 .. s-1

@@ -9,6 +9,7 @@ keep the S×S score matrix out of HBM for long sequences.
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import Optional
 
@@ -16,7 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from ...core.flags import flag
-from ...ops.flash_attention import flash_attention
+from ...ops.flash_attention import flash_attention, flash_attention_latent
 from ...profiler import ATTENTION
 
 
@@ -184,12 +185,20 @@ def _pallas_ok(q, k, causal: bool) -> bool:
     return same and s % 128 == 0 and s >= floor and d <= 256
 
 
-def rotary_embedding(x, theta: float = 10000.0, positions=None):
+def rotary_embedding(x, theta: float = 10000.0, positions=None,
+                     interleaved: bool = False):
     """Rotary positions on [batch, seq, heads, n]: the pairs (i, i + n/2)
     (the half-split convention of the Llama / Qwen checkpoints) are turned
     by position x theta^(-2i/n). `positions` [batch, seq] defaults to
-    0 .. seq-1. Computed in float32, returned in x's dtype."""
+    0 .. seq-1. Computed in float32, returned in x's dtype.
+    `interleaved` (DeepSeek's `rope_interleave`): the pairs are (2i,
+    2i + 1); as the source does, they are de-interleaved first ([x0, x2,
+    .., x1, x3, ..]) and turned by halves, and the result STAYS in that
+    order, which moves no score where queries and keys are both so."""
     b, s, _, n = x.shape
+    if interleaved:
+        x = jnp.swapaxes(x.reshape(x.shape[:-1] + (n // 2, 2)), -1,
+                         -2).reshape(x.shape)
     inv = theta ** (-jnp.arange(0, n, 2, dtype=jnp.float32) / n)
     pos = (jnp.arange(s, dtype=jnp.float32)[None] if positions is None
            else positions.astype(jnp.float32))
@@ -223,6 +232,44 @@ def selected_attention(query, key, value, selection, scale=None):
     probs = jax.nn.softmax(scores, axis=-1).astype(query.dtype)
     out = jnp.einsum("bcgqk,bkcd->bqcgd", probs, value)
     return out.reshape(b, s, h, d)
+
+
+@jax.named_scope(ATTENTION)
+def latent_attention(query, key_nope, key_rope, value, scale=None):
+    """Causal latent attention (DeepSeek's MLA as it trains): query [b, s,
+    h, dn + dr] scores against key_nope [b, s, h, dn] on its first dn
+    lanes and against key_rope [b, s, 1, dr], ONE rotary head that all
+    query heads read, on the rest; value [b, s, h, dv]. The softmax runs
+    in float32 over (q_nope . k_nope + q_rope . k_rope) x scale (default
+    1 / sqrt(dn + dr)). Returns [b, s, h, dv]."""
+    if flag("enable_pallas_kernels") and _pallas_ok(query, query, True):
+        shards = _mesh_shards(query)
+        if shards is None:
+            return flash_attention_latent(query, key_nope, key_rope, value,
+                                          scale=scale)
+        # per shard, as `_flash`: rows over data x sharding, heads over
+        # 'model'; the one rotary head whole on every chip, its gradient
+        # summed over 'model' by shard_map's transpose
+        from jax.sharding import PartitionSpec as P
+        mesh, batch, head = shards
+        per_head = P(batch, None, head, None)
+        return jax.shard_map(
+            functools.partial(flash_attention_latent, scale=scale),
+            mesh=mesh, in_specs=(per_head, per_head,
+                                 P(batch, None, None, None), per_head),
+            out_specs=per_head, check_vma=False)(
+                query, key_nope, key_rope, value)
+    s, dn = query.shape[1], key_nope.shape[-1]
+    scale = scale if scale is not None else 1.0 / math.sqrt(query.shape[-1])
+    scores = (jnp.einsum("bqhd,bkhd->bhqk", query[..., :dn], key_nope,
+                         preferred_element_type=jnp.float32)
+              + jnp.einsum("bqhd,bkd->bhqk", query[..., dn:],
+                           key_rope[:, :, 0],
+                           preferred_element_type=jnp.float32)) * scale
+    scores = jnp.where(jnp.tril(jnp.ones((s, s), dtype=bool)), scores,
+                       -jnp.inf)
+    probs = jax.nn.softmax(scores, axis=-1).astype(query.dtype)
+    return jnp.einsum("bhqk,bkhd->bqhd", probs, value)
 
 
 def _xla_attention(query, key, value, attn_mask, dropout_p, is_causal,

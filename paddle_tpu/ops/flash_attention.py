@@ -51,6 +51,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..profiler import (FLASH_BWD_DKV, FLASH_BWD_DQ, FLASH_FWD,
+                        FLASH_MLA_BWD_DKV, FLASH_MLA_BWD_DQ, FLASH_MLA_FWD,
                         FLASH_SEL_BWD_DKV, FLASH_SEL_BWD_DQ, FLASH_SEL_FWD)
 
 # Row statistics (lse/delta) ride an 8-lane broadcast: TPU block layouts
@@ -667,3 +668,312 @@ def flash_attention(query, key, value, causal: bool = False,
     o3 = _flash3(to3(query), to3(key), to3(value), m3, sel, causal, scale,
                  h, h // h_kv)
     return jnp.swapaxes(o3.reshape(b, h, s, d), 1, 2)
+
+
+# ----------------------------------------------------- latent attention
+#
+# DeepSeek's latent attention scores over a wider head than its values
+# have (192 = 128 without position + 64 rotary, against 128), and its
+# rotary key is ONE head that every query head reads. The three kernels
+# below are the three above on the same `_walk`, causal only, with the
+# key given in PARTS along the score width: each part is an operand of
+# its own, `[b h, s, w]` or, shared by a row's heads, `[b, s, w]` read
+# through the index map `b // heads` (the grouped-head route, no copy).
+# The score is the sum of the parts' products with q's lanes of the same
+# place, dq and dk are written part by part, and a shared part's dk comes
+# out one partial sum a query head in float32, added up outside. One part
+# of the full width is the key concatenated in HBM beforehand.
+
+def _part_lanes(ks):
+    """[(first lane, width)] of the key parts along the score width."""
+    out, lo = [], 0
+    for k in ks:
+        out.append((lo, k.shape[-1]))
+        lo += k.shape[-1]
+    return out
+
+
+def _mla_scores(q_parts, k_refs, row0, rows, scale):
+    """sum over the parts of q_part k_partT: [q rows, rows] fp32."""
+    s = None
+    for qp, k_ref in zip(q_parts, k_refs):
+        t = _dot(qp, _rows(k_ref, row0, rows), _NT)
+        s = t if s is None else s + t
+    return s * scale
+
+
+def _mla_fwd_kernel(q_ref, *refs, lanes, scale, plan, n):
+    parts = len(lanes)
+    k_refs, (v_ref, o_ref, lse_ref) = refs[:parts], refs[parts:parts + 3]
+    _, c, sub = plan
+
+    def prep(r):
+        return tuple(q_ref[0, pl.ds(r, c), pl.ds(lo, w)] for lo, w in lanes)
+
+    def piece(ctx, j, g, lo, hi, tri, carry):
+        m, l, acc = carry
+        s = _mla_scores([q[g:g + sub] for q in ctx], k_refs, j * c, hi,
+                        scale)
+        if tri:
+            s = jnp.where(_keep_tri(sub, hi, g, True), s, NEG_INF)
+        v = _rows(v_ref, j * c, hi)
+        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m - m_new)
+        l = l * corr + jnp.sum(p, axis=1, keepdims=True)
+        return m_new, l, acc * corr + _dot(p.astype(v.dtype), v, _NN)
+
+    def finalize(ctx, r, carry):
+        m, l, acc = carry
+        l_safe = jnp.maximum(l, 1e-30)
+        o_ref[0, pl.ds(r, c), :] = (acc / l_safe).astype(o_ref.dtype)
+        lse_ref[0, pl.ds(r, c), :] = jnp.broadcast_to(
+            m + jnp.log(l_safe), (c, LANE))
+
+    _walk(plan, n, True, True, pl.program_id(1), pl.program_id(2),
+          ((NEG_INF, 1), (0.0, 1), (0.0, v_ref.shape[-1])),
+          refs[parts + 3:], prep, piece, finalize)
+
+
+def _mla_dq_kernel(q_ref, *refs, lanes, scale, plan, n):
+    parts = len(lanes)
+    k_refs = refs[:parts]
+    v_ref, do_ref, lse_ref, delta_ref, dq_ref = refs[parts:parts + 5]
+    _, c, sub = plan
+
+    def prep(r):
+        return (tuple(q_ref[0, pl.ds(r, c), pl.ds(lo, w)]
+                      for lo, w in lanes),
+                _rows(do_ref, r, c), _rows(lse_ref, r, c)[:, 0:1],
+                _rows(delta_ref, r, c)[:, 0:1])
+
+    def piece(ctx, j, g, lo, hi, tri, carry):
+        qs, do, lse, delta = ctx
+        s = _mla_scores([q[g:g + sub] for q in qs], k_refs, j * c, hi,
+                        scale)
+        if tri:
+            s = jnp.where(_keep_tri(sub, hi, g, True), s, NEG_INF)
+        v = _rows(v_ref, j * c, hi)
+        ds = jnp.exp(s - lse[g:g + sub]) * (
+            _dot(do[g:g + sub], v, _NT) - delta[g:g + sub]) * scale
+        ds = ds.astype(v.dtype)
+        return tuple(acc + _dot(ds, _rows(k_ref, j * c, hi), _NN)
+                     for acc, k_ref in zip(carry, k_refs))
+
+    def finalize(ctx, r, carry):
+        for (lo, w), acc in zip(lanes, carry):
+            dq_ref[0, pl.ds(r, c), pl.ds(lo, w)] = acc.astype(dq_ref.dtype)
+
+    _walk(plan, n, True, True, pl.program_id(1), pl.program_id(2),
+          tuple((0.0, w) for _, w in lanes), refs[parts + 5:], prep, piece,
+          finalize)
+
+
+def _mla_dkv_kernel(q_ref, *refs, lanes, scale, plan, n):
+    """dk of every key part and dv of the resident k block; the scores
+    transposed, as in `_dkv_kernel`."""
+    parts = len(lanes)
+    k_refs = refs[:parts]
+    v_ref, do_ref, lse_ref, delta_ref = refs[parts:parts + 4]
+    dk_refs = refs[parts + 4:2 * parts + 4]
+    dv_ref = refs[2 * parts + 4]
+    _, c, sub = plan
+
+    def prep(r):
+        return tuple(_rows(k, r, c) for k in k_refs), _rows(v_ref, r, c)
+
+    def stat_row(ref, start, size):
+        return jnp.concatenate(
+            [ref[0, 0, pl.ds((start + u) // sub, 1), :]
+             for u in range(0, size, sub)], axis=1)
+
+    def piece(ctx, i, g, lo, hi, tri, carry):
+        ks, v = ctx
+        *dks, dv = carry
+        q0, nq = i * c + lo, hi - lo
+        qs = [q_ref[0, pl.ds(q0, nq), pl.ds(l0, w)] for l0, w in lanes]
+        do = _rows(do_ref, q0, nq)
+        st = None
+        for k, q in zip(ks, qs):
+            t = _dot(k[g:g + sub], q, _NT)
+            st = t if st is None else st + t
+        st = st * scale                                   # [sub, nq] fp32
+        if tri:
+            st = jnp.where(_keep_tri(sub, nq, 0, False), st, NEG_INF)
+        pt = jnp.exp(st - stat_row(lse_ref, q0, nq))
+        dv = dv + _dot(pt.astype(do.dtype), do, _NN)
+        dst = (pt * (_dot(v[g:g + sub], do, _NT)
+                     - stat_row(delta_ref, q0, nq)) * scale).astype(do.dtype)
+        return (*(dk + _dot(dst, q, _NN) for dk, q in zip(dks, qs)), dv)
+
+    def finalize(ctx, r, carry):
+        for ref, x in zip((*dk_refs, dv_ref), carry):
+            ref[0, pl.ds(r, c), :] = x.astype(ref.dtype)
+
+    _walk(plan, n, True, False, pl.program_id(1), pl.program_id(2),
+          (*((0.0, w) for _, w in lanes), (0.0, v_ref.shape[-1])),
+          refs[2 * parts + 5:], prep, piece, finalize)
+
+
+def _mla_specs(plan, q3, ks, heads, out_is_q):
+    """Block specs of the latent kernels' operands on a grid (b h, out
+    block, reduce block), under the causal mask: q-side operands of the
+    width `w` (`q(w)`), k-side ones (`k(w)`), the key's parts (`ks`; a
+    shared one is read at b // heads), an output (`out(w)`), and the
+    statistics as `_specs` lays them."""
+    block = plan.block
+    stats = _specs(plan, q3.shape[-1], True, heads, out_is_q)
+
+    def red(i, j):
+        return jnp.minimum(j, i) if out_is_q else jnp.maximum(j, i)
+
+    def spec(w, index):
+        return pl.BlockSpec((1, block, w), index, memory_space=pltpu.VMEM)
+
+    def out_side(w, shared=False):
+        return spec(w, lambda b, i, j: (b // heads if shared else b, i, 0))
+
+    def red_side(w, shared=False):
+        return spec(w, lambda b, i, j: (b // heads if shared else b,
+                                        red(i, j), 0))
+    q_side, k_side = (out_side, red_side) if out_is_q else \
+        (red_side, out_side)
+    shared = [k.shape[0] != q3.shape[0] for k in ks]
+    return dict(q=q_side, k=k_side, out=out_side, shared=shared,
+                ks=[k_side(k.shape[-1], sh) for k, sh in zip(ks, shared)],
+                stat_out=stats["stat_out"], stat_rows=stats["stat_rows"])
+
+
+def _mla_plan(s: int, dv: int, dtype) -> Plan:
+    """`_plan` by the VALUES' width: of the latent kernels' operands only
+    q and dq are wider (and 64 of their 256 lanes are padding), and what
+    dq holds at that block, q and dq at 256 lanes and the key's parts, v
+    and dO at 128, is 8 operand blocks of `_RESIDENT_BYTES` / 2 where the
+    budget allows 5 of `_RESIDENT_BYTES`. Measured (PERF.md, PR 33): 1024
+    resident rows at 8192 tokens take 58.4 ms a layer forward and
+    backward where the 512 that the score width would give take 68.2."""
+    return _plan(s, dv, dtype, True)
+
+
+def _mla_fwd(q3, ks, v3, scale, heads):
+    bh, s, d = q3.shape
+    dv = v3.shape[-1]
+    plan = _mla_plan(s, dv, q3.dtype)
+    n = s // plan.block
+    sp = _mla_specs(plan, q3, ks, heads, out_is_q=True)
+    carried = [pltpu.VMEM((plan.block, w), jnp.float32)
+               for w in (1, 1, dv)] if n > 1 else []
+    return pl.pallas_call(
+        functools.partial(_mla_fwd_kernel, lanes=_part_lanes(ks),
+                          scale=scale, plan=plan, n=n),
+        grid=(bh, n, n),
+        in_specs=[sp["q"](d), *sp["ks"], sp["k"](dv)],
+        out_specs=[sp["out"](dv), sp["stat_out"]],
+        out_shape=[jax.ShapeDtypeStruct((bh, s, dv), q3.dtype),
+                   jax.ShapeDtypeStruct((bh, s, LANE), jnp.float32)],
+        scratch_shapes=carried,
+        interpret=_interpret(),
+        compiler_params=_COMPILER_PARAMS,
+        name=FLASH_MLA_FWD,
+    )(q3, *ks, v3)
+
+
+def _mla_bwd(scale, heads, res, do3):
+    q3, ks, v3, o3, lse = res
+    bh, s, d = q3.shape
+    dv = v3.shape[-1]
+    plan = _mla_plan(s, dv, q3.dtype)
+    block, _, sub = plan
+    n = s // block
+    delta = jnp.sum(do3.astype(jnp.float32) * o3.astype(jnp.float32),
+                    axis=-1)
+    delta3 = jnp.broadcast_to(delta[..., None], (bh, s, LANE))
+    delta_rows = delta.reshape(bh, n, block // sub, sub)
+    lse_rows = lse[..., 0].reshape(bh, n, block // sub, sub)
+    lanes = _part_lanes(ks)
+
+    def scratch(widths):
+        return [pltpu.VMEM((block, w), jnp.float32)
+                for w in widths] if n > 1 else []
+
+    sp = _mla_specs(plan, q3, ks, heads, out_is_q=True)
+    dq = pl.pallas_call(
+        functools.partial(_mla_dq_kernel, lanes=lanes, scale=scale,
+                          plan=plan, n=n),
+        grid=(bh, n, n),
+        in_specs=[sp["q"](d), *sp["ks"], sp["k"](dv), sp["q"](dv),
+                  sp["stat_out"], sp["stat_out"]],
+        out_specs=[sp["out"](d)],
+        out_shape=[jax.ShapeDtypeStruct((bh, s, d), q3.dtype)],
+        scratch_shapes=scratch(w for _, w in lanes),
+        interpret=_interpret(),
+        compiler_params=_COMPILER_PARAMS,
+        name=FLASH_MLA_BWD_DQ,
+    )(q3, *ks, v3, do3, lse, delta3)[0]
+
+    # a shared part's dk: one partial sum a query head in float32, added
+    # up over a row's heads outside (as grouped heads' dk, dv are)
+    sp = _mla_specs(plan, q3, ks, heads, out_is_q=False)
+    *dks, dv_ = pl.pallas_call(
+        functools.partial(_mla_dkv_kernel, lanes=lanes, scale=scale,
+                          plan=plan, n=n),
+        grid=(bh, n, n),
+        in_specs=[sp["q"](d), *sp["ks"], sp["k"](dv), sp["q"](dv),
+                  sp["stat_rows"], sp["stat_rows"]],
+        out_specs=[*(sp["out"](w) for _, w in lanes), sp["out"](dv)],
+        out_shape=[*(jax.ShapeDtypeStruct(
+                         (bh, s, w), jnp.float32 if sh else k.dtype)
+                     for k, sh, (_, w) in zip(ks, sp["shared"], lanes)),
+                   jax.ShapeDtypeStruct((bh, s, dv), v3.dtype)],
+        scratch_shapes=scratch([*(w for _, w in lanes), dv]),
+        interpret=_interpret(),
+        compiler_params=_COMPILER_PARAMS,
+        name=FLASH_MLA_BWD_DKV,
+    )(q3, *ks, v3, do3, lse_rows, delta_rows)
+    dks = tuple(
+        x.reshape(bh // heads, heads, s, -1).sum(1).astype(k.dtype)
+        if sh else x for x, k, sh in zip(dks, ks, sp["shared"]))
+    return dq, dks, dv_
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _mla3(q3, ks, v3, scale, heads):
+    """The differentiable wrapper of the three latent kernels. q3 [b h,
+    s, d]; `ks` the key's parts along d (module comment above); v3 [b h,
+    s, dv]; `heads` query heads a row."""
+    return _mla_fwd(q3, ks, v3, scale, heads)[0]
+
+
+def _mla3_fwd(q3, ks, v3, scale, heads):
+    o, lse = _mla_fwd(q3, ks, v3, scale, heads)
+    return o, (q3, ks, v3, o, lse)
+
+
+_mla3.defvjp(_mla3_fwd, _mla_bwd)
+
+
+def flash_attention_latent(query, key_nope, key_rope, value, scale=None):
+    """Causal latent attention. query [b, s, h, dn + dr]; key_nope [b, s,
+    h, dn]; key_rope [b, s, 1, dr], ONE head that every query head reads
+    (the rotary part); value [b, s, h, dv]. score = (q[:dn] . k_nope +
+    q[dn:] . k_rope) x scale (default 1 / sqrt(dn + dr)). Returns [b, s,
+    h, dv]. Requires s % 128 == 0. The rotary key is read through the
+    index map, not concatenated in HBM beforehand (step 0 on the chip:
+    tools/flash_mla_step0.py; PERF.md, PR 33)."""
+    b, s, h, d = query.shape
+    if s % 128 != 0:
+        raise ValueError(f"flash_attention_latent needs seq % 128 == 0, "
+                         f"got {s}")
+    if key_rope.shape[2] != 1 or \
+            key_nope.shape[-1] + key_rope.shape[-1] != d:
+        raise ValueError(
+            f"key parts {key_nope.shape} + {key_rope.shape} against "
+            f"queries {query.shape}")
+    scale = scale if scale is not None else 1.0 / math.sqrt(d)
+
+    def to3(x):
+        return jnp.swapaxes(x, 1, 2).reshape(-1, s, x.shape[-1])
+
+    o3 = _mla3(to3(query), (to3(key_nope), to3(key_rope)), to3(value),
+               scale, h)
+    return jnp.swapaxes(o3.reshape(b, h, s, -1), 1, 2)

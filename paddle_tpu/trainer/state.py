@@ -3,9 +3,13 @@
 `outer` holds the parameters outside the blocks by their names in the
 model, `stacked` one leaf `[L, ...]` for each parameter of a block, and
 `opt_state` is the optimizer's over ONE flat dict of both, the stacked
-leaves under `"blocks." + name` (`flatten` / `unflatten`). What is decided
-here and nowhere else: that naming, the residency of parameters and
-master weights, and the partition spec of every leaf by ZeRO stage.
+leaves under `"blocks." + name` (`flatten` / `unflatten`). A model of
+several groups of alike blocks (`contract.block_groups`) has one such
+leaf `[n, ...]` for each parameter of each group's block, under
+`"g<i>." + name` (`group_keys`, `of_group`); a one-group model's leaves
+carry no such prefix. What is decided here and nowhere else: that naming,
+the residency of parameters and master weights, and the partition spec of
+every leaf by ZeRO stage.
 """
 from __future__ import annotations
 
@@ -19,7 +23,7 @@ from ..distributed.meta_parallel.sharding_optimizer import shard_spec_for
 from ..distributed.meta_parallel.stacked_pipeline import stack_stage_params
 from ..nn.layer import Layer
 from ..profiler import RecordEvent
-from .contract import split_parameters
+from .contract import block_groups, group_keys, split_parameters
 
 _BLOCKS = "blocks."
 
@@ -46,6 +50,15 @@ def unflatten(flat: Tree) -> Tuple[Tree, Tree]:
     return outer, stacked
 
 
+def of_group(stacked: Tree, key: str) -> Tree:
+    """`{rel: leaf}` of the group whose leaves are called `key + rel`
+    (`contract.group_keys`; the whole dict of a one-group model)."""
+    if not key:
+        return stacked
+    return {n[len(key):]: v for n, v in stacked.items()
+            if n.startswith(key)}
+
+
 def stack_params(model: Layer, param_dtype) -> Tuple[Tree, Tree,
                                                      Optional[Tuple]]:
     """`(outer, stacked, masters)` from the model's trainable parameters.
@@ -60,8 +73,13 @@ def stack_params(model: Layer, param_dtype) -> Tuple[Tree, Tree,
     with RecordEvent("build_train_step.stack"):
         outer_ps, block_ps = split_parameters(model)
         outer = {n: p.value for n, p in outer_ps.items()}
-        stacked = stack_stage_params(
-            [{n: p.value for n, p in blk.items()} for blk in block_ps])
+        groups = block_groups(model)
+        stacked, first = {}, 0
+        for key, (_, count) in zip(group_keys(groups), groups):
+            stacked.update(stack_stage_params(
+                [{key + n: p.value for n, p in blk.items()}
+                 for blk in block_ps[first:first + count]]))
+            first += count
     masters = None
     if param_dtype is not None:
         masters = (outer, stacked)
@@ -109,11 +127,12 @@ class Layout:
         self.shard_axis = mesh.shape.get("sharding", 1)
         lead = "pipe" if mesh.shape.get("pipe", 1) > 1 else None
         named = dict(model.named_parameters())
+        groups = block_groups(model)
         self._own = flatten(
             {n: named[n].sharding_spec or P() for n in outer},
-            {n: P(lead, *(p.sharding_spec or P()))
-             for n, p in model.block_template().named_parameters()
-             if p.trainable})
+            {key + n: P(lead, *(p.sharding_spec or P()))
+             for key, (template, _) in zip(group_keys(groups), groups)
+             for n, p in template.named_parameters() if p.trainable})
         if zero_stage >= 3 and self.shard_axis > 1:
             specs = {n: self.slot_spec(n, v)
                      for n, v in flatten(outer, stacked).items()}

@@ -13,7 +13,7 @@ from ..distributed.topology import mesh_scope
 from ..framework.random import next_key, rng_guard
 from ..nn.layer import Layer, swap_state
 from ..profiler import EMBED, GPT_TRAIN_STEP, LM_LOSS, RecordEvent
-from .contract import check_model
+from .contract import block_groups, check_model
 from .offload import build_offload_step
 from .state import Layout, flatten, init_opt_state, stack_params, unflatten
 from .trunk import Trunk, keyed, require_key, sequence_parallel
@@ -151,8 +151,8 @@ def build_train_step(model: Layer, optimizer, mesh,
                      param_dtype=None):
     """Build the one compiled hybrid-parallel training step.
 
-    `model` is any decoder-only LM made of uniform blocks that gives the
-    builder its pieces (the contract: `trainer/contract.py`).
+    `model` is any decoder-only LM made of groups of alike blocks that
+    gives the builder its pieces (the contract: `trainer/contract.py`).
 
     The eager model's copy of the blocks' weights is given up once they
     are stacked into the state (the arrays are deleted: 1.3 GiB at 345M
@@ -187,7 +187,9 @@ def build_train_step(model: Layer, optimizer, mesh,
     cfg = model.config
     pp = mesh.shape.get("pipe", 1)
     sp = mesh.shape.get("sequence", 1)
-    assert cfg.num_layers % pp == 0, "num_layers must divide pipe axis"
+    groups = block_groups(model)
+    assert sum(n for _, n in groups) % pp == 0, \
+        "num_layers must divide pipe axis"
     if pipeline_schedule not in ("gpipe", "1f1b"):
         raise ValueError(f"unknown pipeline_schedule {pipeline_schedule!r}")
     if pp > 1 and num_microbatches < pp:
@@ -208,10 +210,11 @@ def build_train_step(model: Layer, optimizer, mesh,
             "param_dtype set without optimizer multi_precision=True: "
             "no fp32 master weights — low-precision updates will "
             "accumulate rounding error", stacklevel=3)
-    template = model.block_template()
-    if sp > 1 and not hasattr(type(template), "_sp_attention"):
-        raise NotImplementedError(
-            f"{type(template).__name__} has no sequence-parallel attention")
+    for template, _ in groups:
+        if sp > 1 and not hasattr(type(template), "_sp_attention"):
+            raise NotImplementedError(
+                f"{type(template).__name__} has no sequence-parallel "
+                f"attention")
 
     outer, stacked, masters = stack_params(model, param_dtype)
 
@@ -222,8 +225,7 @@ def build_train_step(model: Layer, optimizer, mesh,
     # dim inside the schedules
     sp_attention, sp_layout = sequence_parallel(mesh, sequence_mode,
                                                 sequence_zigzag)
-    trunk = Trunk(template, mesh, cfg.num_layers, remat=remat,
-                  remat_policy=remat_policy,
+    trunk = Trunk(groups, mesh, remat=remat, remat_policy=remat_policy,
                   num_microbatches=num_microbatches,
                   sp_attention=sp_attention)
     forward = _Forward(model, mesh, trunk, sp_layout, loss_chunks,
@@ -232,6 +234,10 @@ def build_train_step(model: Layer, optimizer, mesh,
     layout = Layout(model, mesh, outer, stacked, zero_stage)
     batch_sharding = layout.batch(trunk.seq_axis)
     if offload:
+        if len(groups) > 1:
+            raise NotImplementedError(
+                f"offload=True over {len(groups)} groups of blocks: the "
+                f"offloaded update streams chunks of ONE stack of blocks")
         return build_offload_step(
             optimizer=optimizer, outer=outer, stacked=stacked,
             masters=masters, layout=layout,

@@ -1,12 +1,14 @@
-"""All L blocks applied to the embedded batch: one template block under a
-scan over the stacked leaves, over two streams of the batch where a
-'model' axis has sums to hide, or under a pipeline schedule where the
+"""All L blocks applied to the embedded batch: each group of alike blocks
+as its template block under a scan over the group's stacked leaves, one
+group after the other, over two streams of the batch where a 'model' axis
+has sums to hide, or (one group only) under a pipeline schedule where the
 mesh has a 'pipe' axis; and the sequence-parallel layout of a batch.
 """
 from __future__ import annotations
 
 import contextlib
-from typing import Callable, Optional, Tuple
+import functools
+from typing import Callable, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -18,6 +20,8 @@ from ..distributed.meta_parallel.stacked_pipeline import pipelined_apply
 from ..framework.random import rng_guard
 from ..nn.layer import Layer, functional_call
 from ..profiler import DECODER, stats
+from .contract import group_keys
+from .state import of_group
 
 # remat policies that keep one named residual beside the dots:
 # "dots_attn" the attention output (+16 MB a layer at GPT-345M buys
@@ -99,49 +103,62 @@ def sequence_parallel(mesh, mode: str, zigzag: bool
 class Trunk:
     """`trunk(stacked_p, x, key=None)`: the blocks, by the mesh's axes.
 
+    `groups` is the model's `[(template, blocks), ...]`: every group is a
+    scan of its own over its own leaves, under the one remat policy, the
+    one `decoder` scope and the same streams. A 'pipe' axis takes a
+    one-group model only: stages would have to end where groups do, and
+    no schedule here cuts them so.
+
     Under a 'model' axis (and no 'pipe' axis, whose microbatches already
     are such streams) each block is applied to the two halves of a chip's
     rows as two streams of one scan body, so that one half's row-parallel
     sums cross the link while the other half computes."""
 
-    def __init__(self, template: Layer, mesh, num_layers: int, *,
+    def __init__(self, groups: List[Tuple[Layer, int]], mesh, *,
                  remat: bool, remat_policy: str, num_microbatches: int,
                  sp_attention: Optional[Callable]):
         axis = mesh.shape
-        self.template = template
+        self.groups = groups
         self.pp = axis.get("pipe", 1)
-        self.layers_per_stage = num_layers // self.pp
+        if self.pp > 1 and len(groups) > 1:
+            raise NotImplementedError(
+                f"a 'pipe' axis of {self.pp} over {len(groups)} groups of "
+                f"blocks ({'+'.join(str(n) for _, n in groups)}): the "
+                f"pipeline schedules stack one group's stages")
+        self.layers_per_stage = sum(n for _, n in groups) // self.pp
         self.microbatches = max(num_microbatches, self.pp)
         self.seq_axis = "sequence" if axis.get("sequence", 1) > 1 else None
         self._tp = axis.get("model", 1) > 1
         self._row_groups = axis.get("data", 1) * axis.get("sharding", 1)
         self._sp_attention = sp_attention
         policy = checkpoint_policy(remat_policy)
-        self._block = (jax.checkpoint(self.block_apply, policy=policy)
-                       if remat else self.block_apply)
+        self._blocks = [
+            (jax.checkpoint(apply, policy=policy) if remat else apply)
+            for apply in (functools.partial(self.block_apply, template)
+                          for template, _ in groups)]
 
-    def block_apply(self, bparams, x, key=None):
+    def block_apply(self, template, bparams, x, key=None):
         # _sp_attention is scoped to THIS trace (set/restore, not a
         # permanent template mutation): the model stays usable eagerly
         # and under other meshes after the step is built. The guard sits
         # INSIDE the checkpointed function: it pushes and pops the scoped
         # key within one trace, so no inner-trace key tracer survives in
         # the thread-local scope (leak otherwise)
-        self.template._sp_attention = self._sp_attention
+        template._sp_attention = self._sp_attention
         try:
             with keyed(key):
-                out, _ = functional_call(self.template, bparams, x)
+                out, _ = functional_call(template, bparams, x)
         finally:
-            self.template._sp_attention = None
+            template._sp_attention = None
         return out
 
     @jax.named_scope(DECODER)
-    def stage_blocks(self, stage_p, h, key=None):
-        """One pipeline stage = scan over its L/pp blocks (shared by the
-        gpipe and 1f1b schedules). `key` (when dropout > 0) is split into
-        one sub-key per block, and a block's into one per stream, so masks
-        decorrelate across layers — a closure draw would bake a single
-        mask into the scanned body. `h` is the batch, or a tuple of
+    def stage_blocks(self, stage_p, h, key=None, group: int = 0):
+        """One group's blocks, or one pipeline stage of them = scan over
+        its L/pp blocks (shared by the gpipe and 1f1b schedules). `key`
+        (when dropout > 0) is split into one sub-key per block, and a
+        block's into one per stream, so masks decorrelate across layers —
+        a closure draw would bake a single mask into the scanned body. `h` is the batch, or a tuple of
         streams of it (`streams`): the body then applies the block to
         each, one after the other in the program and independent in its
         data, so that one stream's row-parallel sum is on the link while
@@ -149,14 +166,15 @@ class Trunk:
         keys = None
         if key is not None:
             keys = jax.random.split(key, jax.tree.leaves(stage_p)[0].shape[0])
+        block = self._blocks[group]
 
         def body(carry, xs):
             bp, k = xs
             if not isinstance(carry, tuple):
-                return self._block(bp, carry, k), None
+                return block(bp, carry, k), None
             ks = (None,) * len(carry) if k is None else \
                 jax.random.split(k, len(carry))
-            return tuple(self._block(bp, c, ki)
+            return tuple(block(bp, c, ki)
                          for c, ki in zip(carry, ks)), None
         out, _ = jax.lax.scan(body, h, (stage_p, keys))
         return out
@@ -201,10 +219,24 @@ class Trunk:
             lambda a: a.reshape((self.pp * self.layers_per_stage,)
                                 + a.shape[2:]), staged)
 
+    def all_groups(self, stacked_p, h, key=None):
+        """Every group's blocks in order, each a scan over its own
+        leaves; with dropout a key a group."""
+        stats.static("trunk.groups", len(self.groups))
+        for g, (_, blocks) in enumerate(self.groups):
+            stats.static(f"trunk.groups.g{g}", blocks)
+        if len(self.groups) == 1:
+            return self.stage_blocks(stacked_p, h, key)
+        keys = [None] * len(self.groups) if key is None else \
+            jax.random.split(key, len(self.groups))
+        for g, name in enumerate(group_keys(self.groups)):
+            h = self.stage_blocks(of_group(stacked_p, name), h, keys[g], g)
+        return h
+
     def __call__(self, stacked_p, x, key=None):
         if self.pp == 1:
-            return self.join(self.stage_blocks(stacked_p, self.streams(x),
-                                               key))
+            return self.join(self.all_groups(stacked_p, self.streams(x),
+                                             key))
         return pipelined_apply(self.stage_blocks, self.to_staged(stacked_p),
                                x, num_stages=self.pp,
                                num_microbatches=self.microbatches,

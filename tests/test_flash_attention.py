@@ -293,6 +293,34 @@ class TestDispatchUnderMesh:
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        rtol=1e-4, atol=5e-5)
 
+    def test_latent_kernels_per_shard_match_xla(self, mesh, monkeypatch):
+        """`F.latent_attention` under dp2 x mp2: the `flash_mla_*` kernels
+        per shard, the one rotary head whole on every chip and its
+        gradient summed over 'model'."""
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        from paddle_tpu.nn.functional import attention as A
+        q, kn, kr, v, do = _latent_operands(1024, 4, 16, 8, 16, b=4)
+        sh = NamedSharding(mesh, P("data", None, "model", None))
+        q, kn, v, do = (jax.device_put(a, sh) for a in (q, kn, v, do))
+        kr = jax.device_put(kr, NamedSharding(mesh, P("data")))
+
+        def grads():
+            return jax.jit(jax.value_and_grad(
+                lambda *a: jnp.sum(A.latent_attention(*a) * do),
+                argnums=(0, 1, 2, 3)))
+        sharded = grads()
+        text = str(jax.make_jaxpr(sharded)(q, kn, kr, v))
+        assert "shard_map" in text and "flash_mla_bwd_dkv" in text
+        got, got_g = sharded(q, kn, kr, v)
+        monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+        ref, ref_g = grads()(q, kn, kr, v)
+        np.testing.assert_allclose(float(got), float(ref), rtol=1e-5)
+        for name, a, b in zip(("dq", "dk_nope", "dk_rope", "dv"), got_g,
+                              ref_g):
+            assert a.shape == b.shape, name
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-4, atol=5e-5, err_msg=name)
+
     @staticmethod
     def _ok_traced(q, k, causal=False):
         """_pallas_ok as a step sees it: on traced operands, which do not
@@ -454,3 +482,103 @@ def test_selection_with_a_kv_mask_is_refused():
                         selection=jnp.ones((1, 128, 128), jnp.int8))
     with pytest.raises(ValueError):
         flash_attention(jnp.zeros((1, 128, 3, 64)), x, x)
+
+
+# ------------------------------------------------------ latent attention
+
+def _latent_operands(s, h, dn, dr, dv, dtype=jnp.float32, b=2):
+    ks = jax.random.split(jax.random.key(1), 5)
+    shapes = [(b, s, h, dn + dr), (b, s, h, dn), (b, s, 1, dr),
+              (b, s, h, dv), (b, s, h, dv)]
+    return [jax.random.normal(k, sh, dtype) for k, sh in zip(ks, shapes)]
+
+
+def _latent(q, kn, kr, v, concat=False):
+    """`flash_attention_latent`, or the same kernels given the key as ONE
+    part of the full width, concatenated beforehand with the rotary head
+    broadcast (step 0's other variant, tools/flash_mla_step0.py)."""
+    if not concat:
+        return fa.flash_attention_latent(q, kn, kr, v)
+    b, s, h, d = q.shape
+
+    def to3(x):
+        return jnp.swapaxes(x, 1, 2).reshape(-1, s, x.shape[-1])
+    k = jnp.concatenate(
+        [kn, jnp.broadcast_to(kr, kn.shape[:-1] + kr.shape[-1:])], -1)
+    o3 = fa._mla3(to3(q), (to3(k),), to3(v), d ** -0.5, h)
+    return jnp.swapaxes(o3.reshape(b, h, s, -1), 1, 2)
+
+
+@pytest.mark.parametrize("concat", [False, True],
+                         ids=["rotary-key-by-index-map", "key-concatenated"])
+@pytest.mark.parametrize("s,h,dn,dr,dv,resident", [
+    (256, 2, 128, 64, 128, None),       # the published widths, one block
+    (1024, 3, 16, 8, 16, None),         # a whole-sequence block of one chunk
+    (512, 2, 128, 64, 128, 64 * 1024),  # four blocks on the grid
+], ids=["192-128-s256", "24-16-s1024", "192-128-s512-four-blocks"])
+def test_latent_kernels_match_the_xla_path(monkeypatch, s, h, dn, dr, dv,
+                                           resident, concat):
+    """Scores over dn + dr lanes with ONE rotary key head shared by every
+    query head, values dv wide: forward and all four gradients, `dk_rope`
+    (summed over the heads) among them, against `F.latent_attention`'s
+    XLA path."""
+    from paddle_tpu.nn import functional as F
+    if resident:
+        monkeypatch.setattr(fa, "_RESIDENT_BYTES", resident)
+        assert fa._mla_plan(s, dv, jnp.float32).block < s
+    q, kn, kr, v, do = _latent_operands(s, h, dn, dr, dv)
+
+    def xla(*a):
+        return jnp.sum(F.latent_attention(*a) * do)
+
+    def kernels(*a):
+        return jnp.sum(_latent(*a, concat=concat) * do)
+    np.testing.assert_allclose(
+        np.asarray(_latent(q, kn, kr, v, concat=concat)),
+        np.asarray(F.latent_attention(q, kn, kr, v)), atol=5e-6)
+    want = jax.grad(xla, argnums=(0, 1, 2, 3))(q, kn, kr, v)
+    got = jax.grad(kernels, argnums=(0, 1, 2, 3))(q, kn, kr, v)
+    for name, a, b in zip(("dq", "dk_nope", "dk_rope", "dv"), got, want):
+        assert a.shape == b.shape, name
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), rtol=1e-5,
+            atol=1e-5 * float(jnp.abs(b).max()), err_msg=name)
+
+
+def test_latent_kernels_in_bf16_and_by_their_own_names():
+    from paddle_tpu.nn import functional as F
+    q, kn, kr, v, _ = _latent_operands(256, 2, 128, 64, 128, jnp.bfloat16)
+    got = fa.flash_attention_latent(q, kn, kr, v)
+    want = F.latent_attention(*(x.astype(jnp.float32)
+                                for x in (q, kn, kr, v)))
+    assert got.dtype == jnp.bfloat16 and got.shape == (2, 256, 2, 128)
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want), atol=3e-2)
+    text = jax.jit(jax.grad(lambda *a: fa.flash_attention_latent(
+        *a).astype(jnp.float32).sum(), argnums=(0, 1, 2, 3))).lower(
+        q, kn, kr, v).as_text(debug_info=True)
+    for name in ("flash_mla_fwd", "flash_mla_bwd_dq", "flash_mla_bwd_dkv"):
+        assert name in text, name
+    assert "flash_fwd" not in text and "flash_sel" not in text
+    # 192 lanes pad to 256, so `_plan` by the scores' width would keep
+    # 512 rows resident at 8192 tokens; the latent kernels plan by the
+    # values' 128 and keep 1024 (PERF.md, PR 33: 58.4 ms a layer for 68.2)
+    assert fa._plan(8192, 192, jnp.bfloat16, True) == (512, 512, 256)
+    assert fa._mla_plan(8192, 128, jnp.bfloat16) == (1024, 1024, 256)
+
+
+def test_latent_attention_off_the_kernels_and_what_it_refuses():
+    """Off the TPU, and at a length no kernel takes, `F.latent_attention`
+    is the XLA path: the same numbers as the formula written out."""
+    from paddle_tpu.nn import functional as F
+    q, kn, kr, v, _ = _latent_operands(72, 2, 16, 8, 16)
+    got = F.latent_attention(q, kn, kr, v)
+    k = jnp.concatenate([kn, jnp.broadcast_to(kr, (2, 72, 2, 8))], -1)
+    want = _xla_attention(q, k, jnp.pad(v, ((0, 0),) * 3 + ((0, 8),)), None,
+                          0.0, True, False, None)[..., :16]
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-6)
+    with pytest.raises(ValueError, match="% 128"):
+        fa.flash_attention_latent(q, kn, kr, v)
+    q, kn, kr, v, _ = _latent_operands(128, 2, 16, 8, 16)
+    with pytest.raises(ValueError, match="key parts"):
+        fa.flash_attention_latent(q, kn, jnp.concatenate([kr, kr], 2), v)

@@ -137,7 +137,7 @@ def test_shares_of_one_layer_add_up_to_the_uncut_layer():
             model = KeyeForCausalLM(cfg)
             cut = {k: (v[off:off + 4] if k.startswith("experts.") else v)
                    for k, v in p.items()}
-            block = model.block_template()
+            (block, _), = model.block_groups()
             params = {n: cut[c] for c, n in adapter.BLOCK.items()}
             y, _ = functional_call(block, params, x)
             # a share's output is h + its experts' sum: take h out
@@ -334,7 +334,7 @@ def test_builder_names_no_member_of_one_model():
                        "GPTForPretraining(", "KeyeForCausalLM("):
             assert member not in code, (path, member)
     for cls in (GPTForPretraining, KeyeForCausalLM):
-        for piece in ("block_template", "embed", "final_norm", "logits"):
+        for piece in ("block_groups", "embed", "final_norm", "logits"):
             assert callable(getattr(cls, piece)), (cls, piece)
     model = GPTForPretraining(gpt_tiny(dtype=jnp.float32))
-    assert model.block_template() is model.gpt.layers[0]
+    assert model.block_groups() == [(model.gpt.layers[0], 4)]

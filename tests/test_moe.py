@@ -11,7 +11,7 @@ import pytest
 import paddle_tpu as pt
 from paddle_tpu.distributed.meta_parallel import MoEMLP, topk_gating
 from paddle_tpu.distributed.meta_parallel.moe import (
-    balance_loss, dispatch_plan, grouped_experts)
+    balance_loss, dispatch_plan, grouped_experts, sigmoid_gating)
 from paddle_tpu.nn.layer import buffer_state, functional_call, \
     trainable_state
 
@@ -281,3 +281,115 @@ class TestMoEMLP:
         assert big.rows_buffer(16384) == (24576, 6)
         with pytest.raises(ValueError):
             MoEMLP(16, 32, num_experts=8, experts_held=4, expert_offset=6)
+
+
+class TestSigmoidRouting:
+    """DeepSeek-V3's routing: the choice by score + bias, the weight by
+    the score alone."""
+
+    def logits(self, t=64, e=8, seed=0):
+        return jnp.asarray(np.random.RandomState(seed).randn(t, e),
+                           jnp.float32)
+
+    def test_choice_by_the_biased_score_weight_by_the_unbiased_one(self):
+        logits = self.logits()
+        bias = jnp.asarray([0.4, -0.4, 0.0, 0.3, -0.2, 0.0, 0.1, -0.1])
+        experts, weights, scores = sigmoid_gating(logits, 3, bias,
+                                                  scaling=2.5)
+        s = 1.0 / (1.0 + np.exp(-np.asarray(logits, np.float64)))
+        np.testing.assert_allclose(np.asarray(scores), s, rtol=1e-6)
+        chosen = np.argsort(-(s + np.asarray(bias)), axis=-1)[:, :3]
+        np.testing.assert_array_equal(np.sort(np.asarray(experts), -1),
+                                      np.sort(chosen, -1))
+        w = np.take_along_axis(s, np.asarray(experts), -1)
+        np.testing.assert_allclose(
+            np.asarray(weights), 2.5 * w / (w.sum(-1, keepdims=True) + 1e-20),
+            rtol=1e-6)
+        # a case where the two differ: the bias changes some token's
+        # experts, and weighting by the biased score would change weights
+        plain, plain_w, _ = sigmoid_gating(logits, 3, None, scaling=2.5)
+        assert (np.sort(np.asarray(plain), -1) != np.sort(chosen, -1)).any()
+        biased = np.take_along_axis(s + np.asarray(bias),
+                                    np.asarray(experts), -1)
+        biased = 2.5 * biased / biased.sum(-1, keepdims=True)
+        assert np.abs(biased - np.asarray(weights)).max() > 0.05
+
+    @pytest.mark.parametrize("norm, scaling", [(True, 1.0), (False, 1.0),
+                                               (True, 2.448)])
+    def test_renormalised_over_all_the_chosen_and_scaled(self, norm, scaling):
+        _, weights, scores = sigmoid_gating(self.logits(), 2, None, norm,
+                                            scaling)
+        top = np.sort(np.asarray(scores), -1)[:, -2:].sum(-1)
+        want = scaling if norm else scaling * top
+        np.testing.assert_allclose(np.asarray(weights.sum(-1)), want,
+                                   rtol=1e-6)
+
+    def test_the_bias_takes_no_gradient_and_the_router_does(self):
+        logits = self.logits()
+
+        def f(logits, bias):
+            _, weights, _ = sigmoid_gating(logits, 2, bias, False)
+            return jnp.sum(weights ** 2)
+        g_logits, g_bias = jax.grad(f, argnums=(0, 1))(
+            logits, 0.1 * jnp.arange(8.0))
+        assert float(jnp.abs(g_bias).max()) == 0.0
+        assert float(jnp.abs(g_logits).max()) > 0.0
+
+    def layer(self, **kw):
+        pt.seed(0)
+        return MoEMLP(16, 8, 8, top_k=2, scoring="sigmoid", choice_bias=True,
+                      routed_scaling_factor=2.0, shared_width=12, **kw)
+
+    def test_layer_is_routed_experts_plus_the_shared_expert(self):
+        layer = self.layer()
+        layer.choice_bias = 0.3 * jnp.asarray(
+            np.random.RandomState(1).randn(8), jnp.float32)
+        for p in (layer.gate_weight, layer.w_gate, layer.w_up, layer.w_down):
+            p.set_value(p.value * 20)
+        x = jnp.asarray(np.random.RandomState(2).randn(2, 8, 16),
+                        jnp.float32)
+        xt = x.reshape(16, 16)
+        experts, weights, _ = sigmoid_gating(
+            xt @ layer.gate_weight.value, 2, layer.choice_bias.value,
+            scaling=2.0)
+        routed = dense_moe(xt, experts, weights, layer.w_gate.value,
+                           layer.w_up.value, layer.w_down.value)
+        sh = layer.shared
+        shared = (jax.nn.silu(xt @ sh.gate_proj.weight.value)
+                  * (xt @ sh.up_proj.weight.value)) @ sh.down_proj.weight.value
+        np.testing.assert_allclose(np.asarray(layer(x)).reshape(16, 16),
+                                   np.asarray(routed + shared), rtol=2e-5,
+                                   atol=2e-6)
+        names = {n for n, _ in layer.named_parameters()}
+        assert {"choice_bias", "shared.gate_proj.weight",
+                "shared.up_proj.weight", "shared.down_proj.weight"} <= names
+        grads = jax.grad(lambda p: jnp.sum(
+            functional_call(layer, p, x)[0] ** 2))(trainable_state(layer))
+        assert float(jnp.abs(grads["choice_bias"]).max()) == 0.0
+        assert float(jnp.abs(grads["shared.down_proj.weight"]).max()) > 0.0
+
+    def test_the_eight_shares_and_the_shared_expert_once_make_the_layer(self):
+        whole = self.layer()
+        x = jnp.asarray(np.random.RandomState(3).randn(2, 8, 16),
+                        jnp.float32)
+        params = trainable_state(whole)
+        want, _ = functional_call(whole, params, x)
+        shared = whole.shared(x.reshape(16, 16)).reshape(x.shape)
+        total = shared
+        for off in range(8):
+            share = self.layer(experts_held=1, expert_offset=off)
+            cut = {n: (v[off:off + 1] if n.startswith("w_") else v)
+                   for n, v in params.items()}
+            total = total + functional_call(share, cut, x)[0] - shared
+        np.testing.assert_allclose(np.asarray(total), np.asarray(want),
+                                   rtol=2e-5, atol=2e-6)
+
+    def test_what_does_not_go_together_is_refused(self):
+        with pytest.raises(ValueError, match="scoring"):
+            MoEMLP(16, 8, 8, scoring="tanh")
+        with pytest.raises(ValueError, match="choice bias"):
+            MoEMLP(16, 8, 8, choice_bias=True)
+        plain = MoEMLP(16, 8, 8)
+        assert plain.shared is None and plain.choice_bias is None
+        assert {n for n, _ in plain.named_parameters()} == {
+            "gate_weight", "w_gate", "w_up", "w_down"}

@@ -189,6 +189,39 @@ def test_selected_grouped_kernels_compile_at_keye_widths(one_chip):
         assert name in text, name
 
 
+@pytest.mark.parametrize("concat", [False, True],
+                         ids=["rotary-key-by-index-map", "key-concatenated"])
+def test_latent_kernels_compile_at_kanana_widths(one_chip, concat):
+    """The kanana cell's attention at its real size: 32 heads, scores over
+    192 (128 + one shared rotary head of 64), values of 128, 8192
+    positions; both ways of handing the kernels the rotary key."""
+    b, s, h, dn, dr, dv = 2, 8192, 32, 128, 64, 128
+
+    def shape(*dims):
+        return jax.ShapeDtypeStruct(dims, jnp.bfloat16, sharding=one_chip)
+
+    def one_part(q, kn, kr, v):
+        # step 0's other variant (tools/flash_mla_step0.py): the same
+        # kernels, the key concatenated beforehand to one part of 192
+        def to3(x):
+            return jnp.swapaxes(x, 1, 2).reshape(-1, s, x.shape[-1])
+        k = jnp.concatenate([kn, jnp.broadcast_to(kr, (b, s, h, dr))], -1)
+        return fa._mla3(to3(q), (to3(k),), to3(v), (dn + dr) ** -0.5, h)
+
+    def loss_and_grads(*args):
+        latent = one_part if concat else fa.flash_attention_latent
+        return jax.value_and_grad(
+            lambda *a: latent(*a).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2, 3))(*args)
+
+    text = compiled_text(loss_and_grads, shape(b, s, h, dn + dr),
+                         shape(b, s, h, dn), shape(b, s, 1, dr),
+                         shape(b, s, h, dv))
+    assert text.count("tpu_custom_call") == 3
+    for name in ("flash_mla_fwd", "flash_mla_bwd_dq", "flash_mla_bwd_dkv"):
+        assert name in text, name
+
+
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
 def test_indexer_compiles_at_keye_widths(one_chip, dtype):
     """The index-score kernel (16 heads of 64 over 8192 positions) and
@@ -229,6 +262,32 @@ def test_dispatch_shards_kernel_over_four_chips(topo, monkeypatch):
 
     text = compiled_text(loss_and_grads, x, x, x)
     assert text.count("tpu_custom_call") == 3
+
+
+def test_dispatch_shards_latent_kernels_over_four_chips(topo, monkeypatch):
+    """`F.latent_attention` under a dp2 x mp2 mesh at the kanana widths:
+    the three `flash_mla_*` kernels per shard (16 heads a chip, the one
+    rotary head whole on each), not the XLA path."""
+    from paddle_tpu.distributed import build_mesh, topology
+    from paddle_tpu.nn.functional import attention as A
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(topology, "_GLOBAL_MESH", None)
+    mesh = build_mesh(dp=2, mp=2, devices=list(topo.devices))
+
+    def shape(heads, width, spec=P("data", None, "model", None)):
+        return jax.ShapeDtypeStruct((2, 8192, heads, width), jnp.bfloat16,
+                                    sharding=NamedSharding(mesh, spec))
+
+    def loss_and_grads(*args):
+        return jax.value_and_grad(
+            lambda *a: A.latent_attention(*a).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2, 3))(*args)
+
+    text = compiled_text(loss_and_grads, shape(32, 192), shape(32, 128),
+                         shape(1, 64, P("data")), shape(32, 128))
+    assert text.count("tpu_custom_call") == 3
+    for name in ("flash_mla_fwd", "flash_mla_bwd_dq", "flash_mla_bwd_dkv"):
+        assert name in text, name
 
 
 def test_dispatch_keeps_one_chip_under_a_stale_mesh(topo, one_chip,

@@ -191,8 +191,8 @@ def metric_files() -> dict:
 
 # what an `op_ms` pattern may name: the program's kernels, and the one
 # instruction the compiler names for it (`jax.lax.ragged_dot`)
-NAMED = prof.KERNELS + prof.SEL_KERNELS + (prof.INDEX_SCORES,
-                                           prof.INDEX_TOPK, prof.RAGGED_DOT)
+NAMED = prof.KERNELS + prof.SEL_KERNELS + prof.MLA_KERNELS + (
+    prof.INDEX_SCORES, prof.INDEX_TOPK, prof.RAGGED_DOT)
 
 
 def test_metric_patterns_name_the_programs_kernels():

@@ -168,8 +168,8 @@ class ToyLM(Layer):
         self.head = nn.Linear(WIDTH, VOCAB)
         self.criterion = ToyCriterion()
 
-    def block_template(self):
-        return self.layers[0]
+    def block_groups(self):
+        return [(self.layers[0], len(self.layers))]
 
     def embed(self, input_ids, position_ids=None):
         return self.table(input_ids)
@@ -241,7 +241,7 @@ def lacking(piece: str) -> Layer:
     return model
 
 
-@pytest.mark.parametrize("piece", ["block_template()", "embed()",
+@pytest.mark.parametrize("piece", ["block_groups()", "embed()",
                                    "final_norm()", "logits()",
                                    "criterion()", "criterion.ce()",
                                    "config.dropout", "layers"])
@@ -301,6 +301,117 @@ def test_flatten_round_trips_parameters_and_a_slot_tree():
     assert again.keys() == slots.keys()
     assert all(again[n] is slots[n] for n in slots)
     assert unflatten({}) == ({}, {})
+
+
+# -- (c') groups of alike blocks ---------------------------------------------
+
+class WideBlock(Layer):
+    """A block unlike `ToyBlock`: another width, other names."""
+
+    def __init__(self):
+        super().__init__()
+        self.norm = nn.LayerNorm(WIDTH)
+        self.a = nn.Linear(WIDTH, 3 * WIDTH)
+        self.b = nn.Linear(3 * WIDTH, WIDTH)
+
+    def forward(self, x):
+        return x + self.b(jax.nn.silu(self.a(self.norm(x))))
+
+
+class TwoGroupLM(ToyLM):
+    """One wide block, then three `ToyBlock`s: two groups."""
+
+    def __init__(self):
+        super().__init__()
+        self.layers = nn.LayerList([WideBlock()]
+                                   + [ToyBlock() for _ in range(3)])
+
+    def block_groups(self):
+        return [(self.layers[0], 1), (self.layers[1], 3)]
+
+
+def test_a_two_group_model_trains_through_the_trunk():
+    """Both groups run inside the trunk (the `decoder` scope, the remat
+    policy), each a scan over its own leaves `g<i>.<rel>`; the first
+    gradient is the plain model's, leaf by leaf, and the state goes back
+    into the model."""
+    from paddle_tpu.profiler import stats
+    pt.seed(0)
+    model, batch, lr = TwoGroupLM(), toy_batch(), 0.1
+    params = trainable_state(model)
+    want_loss, want = jax.value_and_grad(
+        lambda p: functional_call(model, p, *batch)[0])(params)
+    before = {n: np.asarray(v) for n, v in params.items()}
+    mesh = build_mesh(devices=jax.devices()[:1], dp=1)
+    step, state = build_train_step(
+        model, pt.optimizer.SGD(learning_rate=lr), mesh, loss_chunks=2,
+        donate=False)
+    stacked = state[1]
+    assert sorted(stacked) == sorted(
+        [f"g0.{n}" for n, _ in model.layers[0].named_parameters()]
+        + [f"g1.{n}" for n, _ in model.layers[1].named_parameters()])
+    assert stacked["g0.a.weight"].shape == (1, WIDTH, 3 * WIDTH)
+    assert stacked["g1.up.weight"].shape[0] == 3
+    text = step.lower(state, batch).as_text(debug_info=True)
+    assert text.count("stablehlo.while") >= 2      # a scan a group
+    new_state, loss = step(state, batch)
+    assert stats.REGISTRY.snapshot()["trunk.groups"] == 2
+    assert float(loss) == pytest.approx(float(want_loss), rel=1e-5)
+    sync_params_to_model(model, new_state)
+    got = {n: (before[n] - np.asarray(v)) / lr
+           for n, v in trainable_state(model).items()}
+    assert set(got) == set(want)
+    for n, g in want.items():
+        np.testing.assert_allclose(got[n], np.asarray(g), rtol=2e-3,
+                                   atol=2e-6, err_msg=n)
+    # the optimizer's slots are keyed as the flat parameters are
+    _, (outer, stacked, opt) = build_train_step(
+        model, pt.optimizer.AdamW(learning_rate=lr), mesh)
+    assert set(opt["slots"]) == set(outer) | {"blocks." + n for n in stacked}
+    assert opt["slots"]["blocks.g1.up.weight"]["moment1"].shape[0] == 3
+
+
+@pytest.mark.parametrize("make, rels", [
+    (lambda: GPTForPretraining(gpt_tiny(dtype=jnp.float32)), None),
+    (lambda: KeyeForCausalLM(keye_tiny(dtype=jnp.float32)), None),
+    (ToyLM, {"norm.weight", "norm.bias", "up.weight", "up.bias",
+             "down.weight", "down.bias"}),
+], ids=["gpt_tiny", "keye_tiny", "toy"])
+def test_a_one_group_state_keeps_its_keys(make, rels):
+    """`(outer, {rel: [L, ...]}, opt)` with the optimizer's slots under
+    `"blocks." + rel`: what `benchmarks/families/gpt.py` and `keye.py`
+    read, whatever the contract has grown."""
+    model = make()
+    (template, _), = model.block_groups()
+    rels = rels or {n for n, p in template.named_parameters() if p.trainable}
+    mesh = build_mesh(devices=jax.devices()[:1], dp=1)
+    _, (outer, stacked, opt) = build_train_step(
+        model, pt.optimizer.AdamW(learning_rate=1e-3), mesh)
+    assert set(stacked) == rels
+    assert not any(n.startswith("g0.") for n in stacked)
+    assert set(opt["slots"]) == set(outer) | {"blocks." + n for n in rels}
+    assert all(v.shape[0] == model.config.num_layers
+               for v in stacked.values())
+
+
+@pytest.mark.parametrize("mesh_axes, build, said", [
+    ({"pp": 2}, {"num_microbatches": 2}, "'pipe' axis of 2 over 2 groups"),
+    ({"dp": 1}, {"offload": True}, "offload=True over 2 groups"),
+], ids=["pipe", "offload"])
+def test_what_groups_cannot_do_yet_is_refused_by_name(mesh_axes, build, said):
+    model = TwoGroupLM()
+    mesh = build_mesh(devices=jax.devices()[:2 if "pp" in mesh_axes else 1],
+                      **mesh_axes)
+    with pytest.raises(NotImplementedError, match=said):
+        build_train_step(model, pt.optimizer.SGD(learning_rate=0.1), mesh,
+                         **build)
+
+
+def test_groups_that_do_not_cover_the_layers_are_refused():
+    model = TwoGroupLM()
+    model.block_groups = lambda: [(model.layers[0], 1), (model.layers[1], 2)]
+    with pytest.raises(TypeError, match="3 blocks and its `layers` 4"):
+        check_model(model)
 
 
 # -- (e) values bound to a Layer for the length of a trace ------------------

@@ -1,0 +1,313 @@
+#!/usr/bin/env python3
+"""Step 0 of latent attention and of the kanana cell (ISSUE 33), to be run
+on the chip:
+
+    python tools/flash_mla_step0.py [--kernels 1] [--step POLICY,POLICY]
+        [--balance SEED,SEED] [--out FILE]
+
+1. `--kernels 1`: the three latent flash kernels at the cell's widths
+   (bf16 [2, 8192, 32, 192] queries, keys as 32 x 128 + ONE rotary head
+   of 64, values 32 x 128), forward and backward, as the layer scan of a
+   step runs them: a `scan` of `--layers` calls of value-and-gradient.
+   VARIANTS ranks how the kernels are handed the rotary key: read through
+   the index map `b // 32` with two score products summed in the kernel
+   and `dk_rope` added up over the heads outside ("index"), or
+   concatenated in HBM to 192 a head beforehand ("concat"); and the
+   resident block: `_mla_plan`'s, sized by the values' 128 lanes (1024
+   rows at 8192 tokens), or `_plan`'s by the scores' 192, which pad to
+   256 (512 rows: "_by_score_width"). Ms a call by the device's clock:
+   each kernel's own events and the whole program, which holds what XLA
+   does around them (the concatenation and its transpose, the sum over
+   heads). Every variant's loss and gradient norms against the first's.
+2. `--step full,dots`: the cell's whole step as the benchmark builds it,
+   once for each remat policy named: compiled (or refused: the
+   compiler's message is the row), `--steps` steps by the host's clock
+   after the first, the allocator's peak.
+3. `--balance 1,2,3`: for each seed the cell's run as the benchmark makes
+   it (its weights, its pool, its step) for `--steps` steps, and at every
+   `--every`-th the rows a layer's router sends to the 16 held experts
+   (balanced: tokens x 6 x 16 / 128 = 12288), by the reference's routing
+   on the program's own state.
+
+One JSON line a reading on standard output, all of them in `--out`.
+Off the TPU (`--rehearse 1`) everything is cut to a toy size and the
+kernels are interpreted: a rehearsal of the control flow, whose times
+mean nothing.
+"""
+from __future__ import annotations
+
+import argparse
+import functools
+import gc
+import json
+import math
+import os
+import re
+import sys
+import time
+
+import jax
+import jax.numpy as jnp
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from paddle_tpu.ops import flash_attention as fa  # noqa: E402
+from paddle_tpu.profiler import MLA_KERNELS  # noqa: E402
+
+CONFIG = "kanana-2-30b-a3b-instruct-2601.json"
+MIX = "pretrain-s8192-fresh.json"
+# (name, key concatenated in HBM, the plan by the scores' width)
+VARIANTS = [("index", False, False), ("concat", True, False),
+            ("index_by_score_width", False, True),
+            ("concat_by_score_width", True, True)]
+
+
+def log(row: dict, rows: list) -> None:
+    rows.append(row)
+    print(json.dumps(row), flush=True)
+
+
+def device_trace(fn, reps: int) -> dict:
+    """The device's `ops` and `modules` events of `reps` runs of `fn()`
+    (compiled beforehand); nothing off the chip."""
+    import shutil
+    import tempfile
+    from benchmarks.harness import trace
+    where = tempfile.mkdtemp(prefix="flash_mla_step0_")
+    try:
+        with jax.profiler.trace(where):
+            for _ in range(reps):
+                jax.block_until_ready(fn())
+        return trace.load(trace.find_xplane(where))["devices"].get(
+            0, {"ops": [], "modules": []})
+    finally:
+        shutil.rmtree(where, ignore_errors=True)
+
+
+def latent(q, k_nope, k_rope, v, concat: bool):
+    """The production entry, or variant (a): the key laid out in HBM as
+    one [b, s, h, dn + dr] with the rotary head broadcast, one part of
+    the full width through the same kernels (`fa._mla3`)."""
+    if not concat:
+        return fa.flash_attention_latent(q, k_nope, k_rope, v)
+    b, s, h, d = q.shape
+
+    def to3(x):
+        return jnp.swapaxes(x, 1, 2).reshape(-1, s, x.shape[-1])
+    k = jnp.concatenate([k_nope, jnp.broadcast_to(
+        k_rope, k_nope.shape[:-1] + k_rope.shape[-1:])], -1)
+    o3 = fa._mla3(to3(q), (to3(k),), to3(v), 1.0 / math.sqrt(d), h)
+    return jnp.swapaxes(o3.reshape(b, h, s, -1), 1, 2)
+
+
+def kernel_table(args, rows: list) -> None:
+    b, s, h = 2, args.seq, args.heads
+    dn, dr, dv = args.widths
+    shapes = [(b, s, h, dn + dr), (b, s, h, dn), (b, s, 1, dr),
+              (b, s, h, dv), (b, s, h, dv)]
+
+    def operands(key):
+        return [jax.random.normal(k, sh, jnp.bfloat16)
+                for k, sh in zip(jax.random.split(key, 5), shapes)]
+    *stacks, dos = jax.jit(lambda keys: jax.lax.map(operands, keys))(
+        jax.random.split(jax.random.key(args.seed), args.layers))
+    mla_plan, first = fa._mla_plan, None
+    for name, concat, by_score_width in VARIANTS:
+        fa._mla_plan = (lambda s_, dv_, dtype: fa._plan(
+            s_, dn + dr, dtype, True)) if by_score_width else mla_plan
+
+        def layers(*qkv):
+            # the gradient is taken outside the scan, as a step takes it:
+            # a backward loop of its own, and the kernels under the names
+            # a step gives them
+            def one(xs):
+                *a, do = xs
+                return jnp.sum(latent(*a, concat).astype(jnp.float32)
+                               * do.astype(jnp.float32))
+            return jnp.sum(jax.lax.map(one, (*qkv, dos)))
+        fn = jax.jit(lambda xs: jax.value_and_grad(
+            layers, argnums=(0, 1, 2, 3))(*xs))
+        t = time.perf_counter()
+        try:
+            out = jax.block_until_ready(fn(stacks))
+            dev = device_trace(lambda: fn(stacks), args.reps)
+        except Exception as e:  # a variant the compiler refuses is a row
+            log({"what": "kernels", "variant": name,
+                 "error": str(e)[:300]}, rows)
+            continue
+        calls = args.reps * args.layers
+        row = {"what": "kernels", "variant": name,
+               "plan": list(fa._mla_plan(s, dv, jnp.bfloat16)),
+               "program_ms_a_call":
+                   1e3 * sum(e - s0 for s0, e, _ in dev["modules"]) / calls}
+        for kernel in MLA_KERNELS:
+            hits = [e - s0 for s0, e, n in dev["ops"]
+                    if re.match(rf"%{kernel}(\.[.\w]*)? = ", n)]
+            row[kernel + "_ms_a_call"] = 1e3 * sum(hits) / calls
+        # the loss and the L1 norm of each gradient: what a variant is
+        # held to the first by (the arrays themselves do not fit twice)
+        sums = [float(jnp.sum(jnp.abs(x.astype(jnp.float32))))
+                for x in jax.tree.leaves(out)]
+        if first is None:
+            first = sums
+        else:
+            row["max_gap_to_first"] = max(
+                abs(a - b) / max(abs(b), 1e-30) for a, b in zip(sums, first))
+        row["compile_and_runs_s"] = time.perf_counter() - t
+        log(row, rows)
+        del out
+    fa._mla_plan = mla_plan
+
+
+def cell_spec(args):
+    from benchmarks.harness import cells
+    config = cells.load_json("configs", CONFIG)
+    mix = cells.load_json("traffic", MIX)
+    config["step"].update(config["layouts"]["1"])
+    if args.rehearse:
+        config.update(hidden_size=64, intermediate_size=96,
+                      moe_intermediate_size=32, num_hidden_layers=3,
+                      num_attention_heads=4, kv_lora_rank=32,
+                      qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+                      n_routed_experts=4, num_experts_per_tok=2,
+                      vocab_size=256)
+        config["published"].update(n_routed_experts=8, vocab_size=512)
+        mix = dict(mix, seq=args.seq, pool_batches=8)
+    return config, mix, cells.family(config)
+
+
+def build(config, mix, family, seed, devices):
+    from benchmarks.harness import data
+    adapter, reference = family
+    pool = data.make_pool(mix, config["vocab_size"], seed)
+    init = jax.jit(functools.partial(reference.init_weights, config))
+    return adapter.build(config, mix, init(jax.random.key(seed)),
+                         devices), pool
+
+
+def step_table(args, rows: list) -> None:
+    config, mix, family = cell_spec(args)
+    devices = jax.devices()[:1]
+    for policy in args.step.split(","):
+        config["step"]["remat_policy"] = policy
+        t = time.perf_counter()
+        try:
+            prog, pool = build(config, mix, family, args.seed, devices)
+            state, prog.state = prog.state, None
+            state, loss = prog.step(state, prog.put(pool[0]))
+            first = float(loss)
+            compiled = time.perf_counter() - t
+            t = time.perf_counter()
+            for i in range(1, args.steps + 1):
+                state, loss = prog.step(state, prog.put(pool[i % len(pool)]))
+            last = float(loss)
+            ms = 1e3 * (time.perf_counter() - t) / args.steps
+        except Exception as e:
+            log({"what": "step", "remat_policy": policy,
+                 "error": str(e)[:400]}, rows)
+            continue
+        peak = (devices[0].memory_stats() or {}).get("peak_bytes_in_use")
+        log({"what": "step", "remat_policy": policy, "ms_a_step": ms,
+             "steps": args.steps, "first_loss": first, "last_loss": last,
+             "build_and_first_step_s": compiled,
+             "memory_peak_bytes_so_far": peak}, rows)
+        del state, prog
+        gc.collect()
+
+
+def held_rows(reference, config, w, ids):
+    """Rows each expert layer's router sends to the held experts, by the
+    reference's own attention, routing and expert layer on `w` (the
+    program's state under the reference's names); default matmul
+    precision: a census, not a comparison."""
+    z = reference.sizes(config)
+    mm = jnp.matmul
+
+    def under(prefix):
+        return {k[len(prefix):]: a.astype(jnp.float32)
+                for k, a in w.items() if k.startswith(prefix)}
+
+    def expert_layer(x, p):
+        h = x + reference.attention(
+            z, p, reference.rms_norm(x, p["ln1.w"], z["eps"]), mm)
+        u = reference.rms_norm(h, p["ln2.w"], z["eps"])
+        g = reference.routing(z, mm(u, p["router.w"]), p["router.bias"])
+        here = jax.lax.dynamic_slice_in_dim(g, z["off"], z["held"], -1)
+        return h + reference.moe(z, p, u, mm), jnp.sum(here > 0)
+    x = reference.layer(z, under("dense."),
+                        w["embed"][ids].astype(jnp.float32), mm, True)
+    return jax.lax.scan(expert_layer, x, under("blocks."))[1]
+
+
+def balance(args, rows: list) -> None:
+    config, mix, family = cell_spec(args)
+    census = jax.jit(functools.partial(held_rows, family[1], config))
+    tokens = mix["batch"] * mix["seq"]
+    even = tokens * config["num_experts_per_tok"] \
+        * config["n_routed_experts"] / config["published"]["n_routed_experts"]
+    for seed in (int(x) for x in args.balance.split(",")):
+        prog, pool = build(config, mix, family, seed, jax.devices()[:1])
+        state, prog.state = prog.state, None
+        for i in range(args.steps + 1):
+            if i % args.every == 0 or i == args.steps:
+                got = [int(n) for n in census(prog.params(state),
+                                              pool[i % len(pool)]["ids"])]
+                log({"what": "balance", "seed": seed, "before_step": i + 1,
+                     "rows_on_held_experts": got, "balanced": even,
+                     "worst_share_of_balanced":
+                         max(abs(n / even - 1.0) for n in got)}, rows)
+            state, loss = prog.step(state, prog.put(pool[i % len(pool)]))
+        log({"what": "balance", "seed": seed, "last_loss": float(loss)},
+            rows)
+        del state, prog
+        gc.collect()
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
+    ap.add_argument("--seed", type=int, default=2147491012)
+    ap.add_argument("--seq", type=int, default=8192)
+    ap.add_argument("--heads", type=int, default=32)
+    ap.add_argument("--widths", type=lambda t: tuple(map(int, t.split(","))),
+                    default=(128, 64, 128), help="nope,rope,value")
+    ap.add_argument("--layers", type=int, default=3,
+                    help="calls a program: operands, gradients and the "
+                         "scan's residuals of 6 do not fit the chip")
+    ap.add_argument("--reps", type=int, default=3)
+    ap.add_argument("--kernels", type=int, choices=(0, 1), default=0)
+    ap.add_argument("--step", default="")
+    ap.add_argument("--balance", default="")
+    ap.add_argument("--steps", type=int, default=8)
+    ap.add_argument("--every", type=int, default=8)
+    ap.add_argument("--rehearse", type=int, choices=(0, 1), default=0)
+    ap.add_argument("--out", default="chiprun_out/flash_mla_step0.json")
+    args = ap.parse_args(argv)
+    if args.rehearse:
+        from paddle_tpu.nn.functional import attention
+        fa._interpret = lambda: True
+        attention._pallas_ok = lambda q, k, causal: True
+    elif jax.default_backend() != "tpu":
+        raise SystemExit("flash_mla_step0: no TPU; nothing was run "
+                         "(--rehearse 1 walks the script off the chip)")
+    rows = []
+    log({"what": "device", "kind": jax.devices()[0].device_kind,
+         "seq": args.seq, "layers": args.layers, "seed": args.seed,
+         "rehearsal": bool(args.rehearse)}, rows)
+    try:
+        if args.kernels:
+            kernel_table(args, rows)
+        if args.step:
+            step_table(args, rows)
+        if args.balance:
+            balance(args, rows)
+    finally:
+        os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
+        with open(args.out, "w") as f:
+            json.dump(rows, f, indent=1)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
