@@ -38,6 +38,13 @@ so, a small fusion a call outside the kernels). Matmul operands stay bf16
 statistics are fp32. Row statistics (logsumexp/delta) ride an 8-lane
 broadcast between forward and dq because TPU block layouts need a
 lane-divisible trailing dim.
+
+Latent attention (further down) has two kernels, not three: its backward
+is the dk/dv walk alone, and dq comes out of it, because a dq kernel of
+its own computes the scores, their `exp` and dO vᵀ of every (q, k) pair a
+second time (640 of the pair's 1408 lanes of MXU work at 192-wide scores).
+The head's whole dq waits in a float32 VMEM accumulator while the walk
+passes the head's k blocks.
 """
 from __future__ import annotations
 
@@ -51,8 +58,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..profiler import (FLASH_BWD_DKV, FLASH_BWD_DQ, FLASH_FWD,
-                        FLASH_MLA_BWD_DKV, FLASH_MLA_BWD_DQ, FLASH_MLA_FWD,
-                        FLASH_SEL_BWD_DKV, FLASH_SEL_BWD_DQ, FLASH_SEL_FWD)
+                        FLASH_MLA_BWD_DKV, FLASH_MLA_FWD, FLASH_SEL_BWD_DKV,
+                        FLASH_SEL_BWD_DQ, FLASH_SEL_FWD)
+from ..profiler import stats
 
 # Row statistics (lse/delta) ride an 8-lane broadcast: TPU block layouts
 # need the last two dims (sublane, lane) to divide (8, 128) or equal the
@@ -674,15 +682,24 @@ def flash_attention(query, key, value, causal: bool = False,
 #
 # DeepSeek's latent attention scores over a wider head than its values
 # have (192 = 128 without position + 64 rotary, against 128), and its
-# rotary key is ONE head that every query head reads. The three kernels
-# below are the three above on the same `_walk`, causal only, with the
-# key given in PARTS along the score width: each part is an operand of
-# its own, `[b h, s, w]` or, shared by a row's heads, `[b, s, w]` read
-# through the index map `b // heads` (the grouped-head route, no copy).
-# The score is the sum of the parts' products with q's lanes of the same
-# place, dq and dk are written part by part, and a shared part's dk comes
-# out one partial sum a query head in float32, added up outside. One part
-# of the full width is the key concatenated in HBM beforehand.
+# rotary key is ONE head that every query head reads. The two latent
+# kernels below stand on the same `_walk` as the three above, causal
+# only, with the key given in PARTS along the score width: each part is an
+# operand of its own, `[b h, s, w]` or, shared by a row's heads, `[b, s,
+# w]` read through the index map `b // heads` (the grouped-head route, no
+# copy). The score is the sum of the parts' products with q's lanes of the
+# same place, dq and dk are written part by part, and a shared part's dk
+# comes out one partial sum a query head in float32, added up outside. One
+# part of the full width is the key concatenated in HBM beforehand.
+#
+# Two kernels, forward and backward: dq rides the dk/dv walk. A (q, k)
+# pair's scores, `exp` and dO vT are what dq and dk/dv both need, so the
+# one walk makes them once and adds the pair's share of dq beside
+# dst q and pT dO. A q block's dq is added to by every k block up to its
+# own, grid steps that do not follow each other, so it cannot be a carried
+# output block: the head's WHOLE dq stays in VMEM in float32 (6 MiB at
+# 8192 tokens) and each q block is rounded once, when its diagonal k block
+# has passed (PERF.md, PR 34).
 
 def _part_lanes(ks):
     """[(first lane, width)] of the key parts along the score width."""
@@ -735,52 +752,37 @@ def _mla_fwd_kernel(q_ref, *refs, lanes, scale, plan, n):
           refs[parts + 3:], prep, piece, finalize)
 
 
-def _mla_dq_kernel(q_ref, *refs, lanes, scale, plan, n):
+def _mla_bwd_kernel(q_ref, *refs, lanes, scale, plan, n):
+    """The latent backward, one kernel: dk of every key part and dv of
+    the resident k block as `_dkv_kernel` makes them (the scores
+    transposed, k qT), and out of the same scores and dO vT the pair's
+    share of dq, added into a float32 accumulator that holds the HEAD's
+    whole dq across all its k blocks (`dq_acc`, one scratch a key part).
+    The accumulator holds dq TRANSPOSED, [blocks, w, block]: the share is
+    then k_partT dst, a plain product of what the walk already has (dst
+    as it stands; k_partT made once a k chunk), where dstT k_part would
+    transpose every group's dst, and a 64-wide part is the product's rows
+    and not its lanes, which pad to 128 (PERF.md, PR 34: 23.9 ms a call
+    against 25.2). A q block's dq is whole once its own (diagonal) k
+    block has passed, which is that k block's first step on the grid:
+    there it is transposed back and rounded, once, into `dq_ref`."""
     parts = len(lanes)
     k_refs = refs[:parts]
     v_ref, do_ref, lse_ref, delta_ref, dq_ref = refs[parts:parts + 5]
+    dk_refs = refs[parts + 5:2 * parts + 5]
+    dv_ref = refs[2 * parts + 5]
+    dq_acc = refs[2 * parts + 6:3 * parts + 6]
     _, c, sub = plan
+    kb, qb = pl.program_id(1), pl.program_id(2)
+
+    @pl.when(kb == 0)
+    def _first_k_block():
+        for acc in dq_acc:
+            acc[qb] = jnp.zeros(acc.shape[1:], jnp.float32)
 
     def prep(r):
-        return (tuple(q_ref[0, pl.ds(r, c), pl.ds(lo, w)]
-                      for lo, w in lanes),
-                _rows(do_ref, r, c), _rows(lse_ref, r, c)[:, 0:1],
-                _rows(delta_ref, r, c)[:, 0:1])
-
-    def piece(ctx, j, g, lo, hi, tri, carry):
-        qs, do, lse, delta = ctx
-        s = _mla_scores([q[g:g + sub] for q in qs], k_refs, j * c, hi,
-                        scale)
-        if tri:
-            s = jnp.where(_keep_tri(sub, hi, g, True), s, NEG_INF)
-        v = _rows(v_ref, j * c, hi)
-        ds = jnp.exp(s - lse[g:g + sub]) * (
-            _dot(do[g:g + sub], v, _NT) - delta[g:g + sub]) * scale
-        ds = ds.astype(v.dtype)
-        return tuple(acc + _dot(ds, _rows(k_ref, j * c, hi), _NN)
-                     for acc, k_ref in zip(carry, k_refs))
-
-    def finalize(ctx, r, carry):
-        for (lo, w), acc in zip(lanes, carry):
-            dq_ref[0, pl.ds(r, c), pl.ds(lo, w)] = acc.astype(dq_ref.dtype)
-
-    _walk(plan, n, True, True, pl.program_id(1), pl.program_id(2),
-          tuple((0.0, w) for _, w in lanes), refs[parts + 5:], prep, piece,
-          finalize)
-
-
-def _mla_dkv_kernel(q_ref, *refs, lanes, scale, plan, n):
-    """dk of every key part and dv of the resident k block; the scores
-    transposed, as in `_dkv_kernel`."""
-    parts = len(lanes)
-    k_refs = refs[:parts]
-    v_ref, do_ref, lse_ref, delta_ref = refs[parts:parts + 4]
-    dk_refs = refs[parts + 4:2 * parts + 4]
-    dv_ref = refs[2 * parts + 4]
-    _, c, sub = plan
-
-    def prep(r):
-        return tuple(_rows(k, r, c) for k in k_refs), _rows(v_ref, r, c)
+        ks = tuple(_rows(k, r, c) for k in k_refs)
+        return ks, tuple(k.T for k in ks), _rows(v_ref, r, c)
 
     def stat_row(ref, start, size):
         return jnp.concatenate(
@@ -788,7 +790,7 @@ def _mla_dkv_kernel(q_ref, *refs, lanes, scale, plan, n):
              for u in range(0, size, sub)], axis=1)
 
     def piece(ctx, i, g, lo, hi, tri, carry):
-        ks, v = ctx
+        ks, kts, v = ctx
         *dks, dv = carry
         q0, nq = i * c + lo, hi - lo
         qs = [q_ref[0, pl.ds(q0, nq), pl.ds(l0, w)] for l0, w in lanes]
@@ -804,15 +806,22 @@ def _mla_dkv_kernel(q_ref, *refs, lanes, scale, plan, n):
         dv = dv + _dot(pt.astype(do.dtype), do, _NN)
         dst = (pt * (_dot(v[g:g + sub], do, _NT)
                      - stat_row(delta_ref, q0, nq)) * scale).astype(do.dtype)
+        for acc, kt in zip(dq_acc, kts):
+            acc[qb, :, pl.ds(q0, nq)] += _dot(kt[:, g:g + sub], dst, _NN)
         return (*(dk + _dot(dst, q, _NN) for dk, q in zip(dks, qs)), dv)
 
     def finalize(ctx, r, carry):
         for ref, x in zip((*dk_refs, dv_ref), carry):
             ref[0, pl.ds(r, c), :] = x.astype(ref.dtype)
 
-    _walk(plan, n, True, False, pl.program_id(1), pl.program_id(2),
+    _walk(plan, n, True, False, kb, qb,
           (*((0.0, w) for _, w in lanes), (0.0, v_ref.shape[-1])),
-          refs[2 * parts + 5:], prep, piece, finalize)
+          refs[3 * parts + 6:], prep, piece, finalize)
+
+    @pl.when(qb == kb)
+    def _diagonal():
+        for (lo, w), acc in zip(lanes, dq_acc):
+            dq_ref[0, :, pl.ds(lo, w)] = acc[kb].T.astype(dq_ref.dtype)
 
 
 def _mla_specs(plan, q3, ks, heads, out_is_q):
@@ -878,6 +887,28 @@ def _mla_fwd(q3, ks, v3, scale, heads):
     )(q3, *ks, v3)
 
 
+# The most a head's whole dq may take of VMEM in float32 (a v5e core has
+# 128 MiB; at the kanana cell's 8192 tokens it takes 6 MiB).
+_MLA_DQ_BYTES = 32 << 20
+
+
+def _mla_bwd_vmem(s, block, parts, dv):
+    """(bytes of the dq accumulator, `vmem_limit_bytes`) of the latent
+    backward, from its shapes (`parts`: the key parts' widths): the
+    accumulator (dq transposed: the widths lie along sublanes and pad to
+    8, not 128), every
+    operand and output block twice (the pipeline's two buffers) and the
+    carried dk, dv once, each counted as float32 rows of 128-lane tiles,
+    and 16 MiB for a group's scores and the compiler's own."""
+    def tiles(*widths):
+        return sum(-(-w // 128) * 128 * 4 for w in widths)
+    acc = s * sum(-(-w // 8) * 8 * 4 for w in parts)
+    blocks = block * (2 * tiles(sum(parts), *parts, dv, dv)
+                      + 2 * tiles(sum(parts), *parts, dv)
+                      + tiles(*parts, dv))
+    return acc, acc + blocks + (16 << 20)
+
+
 def _mla_bwd(scale, heads, res, do3):
     q3, ks, v3, o3, lse = res
     bh, s, d = q3.shape
@@ -885,49 +916,46 @@ def _mla_bwd(scale, heads, res, do3):
     plan = _mla_plan(s, dv, q3.dtype)
     block, _, sub = plan
     n = s // block
+    lanes = _part_lanes(ks)
+    widths = [w for _, w in lanes]
+    acc_bytes, vmem = _mla_bwd_vmem(s, block, widths, dv)
+    if acc_bytes > _MLA_DQ_BYTES:
+        raise ValueError(
+            f"flash_attention_latent's backward keeps a head's whole dq in "
+            f"VMEM: {s} rows of {widths} lanes take {acc_bytes} bytes, "
+            f"over {_MLA_DQ_BYTES}")
+    stats.static("attn.latent.bwd_kernels", 1)
     delta = jnp.sum(do3.astype(jnp.float32) * o3.astype(jnp.float32),
                     axis=-1)
-    delta3 = jnp.broadcast_to(delta[..., None], (bh, s, LANE))
     delta_rows = delta.reshape(bh, n, block // sub, sub)
     lse_rows = lse[..., 0].reshape(bh, n, block // sub, sub)
-    lanes = _part_lanes(ks)
 
-    def scratch(widths):
-        return [pltpu.VMEM((block, w), jnp.float32)
-                for w in widths] if n > 1 else []
-
-    sp = _mla_specs(plan, q3, ks, heads, out_is_q=True)
-    dq = pl.pallas_call(
-        functools.partial(_mla_dq_kernel, lanes=lanes, scale=scale,
-                          plan=plan, n=n),
-        grid=(bh, n, n),
-        in_specs=[sp["q"](d), *sp["ks"], sp["k"](dv), sp["q"](dv),
-                  sp["stat_out"], sp["stat_out"]],
-        out_specs=[sp["out"](d)],
-        out_shape=[jax.ShapeDtypeStruct((bh, s, d), q3.dtype)],
-        scratch_shapes=scratch(w for _, w in lanes),
-        interpret=_interpret(),
-        compiler_params=_COMPILER_PARAMS,
-        name=FLASH_MLA_BWD_DQ,
-    )(q3, *ks, v3, do3, lse, delta3)[0]
-
-    # a shared part's dk: one partial sum a query head in float32, added
-    # up over a row's heads outside (as grouped heads' dk, dv are)
+    # grid (b h, k block, q block). A shared part's dk: one partial sum a
+    # query head in float32, added up over a row's heads outside (as
+    # grouped heads' dk, dv are). The k blocks of a head follow each other
+    # ("arbitrary"): the head's dq crosses them in the scratch.
     sp = _mla_specs(plan, q3, ks, heads, out_is_q=False)
-    *dks, dv_ = pl.pallas_call(
-        functools.partial(_mla_dkv_kernel, lanes=lanes, scale=scale,
+    carried = [pltpu.VMEM((block, w), jnp.float32)
+               for w in (*widths, dv)] if n > 1 else []
+    dq, *dks, dv_ = pl.pallas_call(
+        functools.partial(_mla_bwd_kernel, lanes=lanes, scale=scale,
                           plan=plan, n=n),
         grid=(bh, n, n),
         in_specs=[sp["q"](d), *sp["ks"], sp["k"](dv), sp["q"](dv),
                   sp["stat_rows"], sp["stat_rows"]],
-        out_specs=[*(sp["out"](w) for _, w in lanes), sp["out"](dv)],
-        out_shape=[*(jax.ShapeDtypeStruct(
+        out_specs=[sp["out"](d), *(sp["out"](w) for w in widths),
+                   sp["out"](dv)],
+        out_shape=[jax.ShapeDtypeStruct((bh, s, d), q3.dtype),
+                   *(jax.ShapeDtypeStruct(
                          (bh, s, w), jnp.float32 if sh else k.dtype)
-                     for k, sh, (_, w) in zip(ks, sp["shared"], lanes)),
+                     for k, sh, w in zip(ks, sp["shared"], widths)),
                    jax.ShapeDtypeStruct((bh, s, dv), v3.dtype)],
-        scratch_shapes=scratch([*(w for _, w in lanes), dv]),
+        scratch_shapes=[*(pltpu.VMEM((n, w, block), jnp.float32)
+                          for w in widths), *carried],
         interpret=_interpret(),
-        compiler_params=_COMPILER_PARAMS,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=vmem),
         name=FLASH_MLA_BWD_DKV,
     )(q3, *ks, v3, do3, lse_rows, delta_rows)
     dks = tuple(
@@ -938,7 +966,7 @@ def _mla_bwd(scale, heads, res, do3):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 def _mla3(q3, ks, v3, scale, heads):
-    """The differentiable wrapper of the three latent kernels. q3 [b h,
+    """The differentiable wrapper of the two latent kernels. q3 [b h,
     s, d]; `ks` the key's parts along d (module comment above); v3 [b h,
     s, dv]; `heads` query heads a row."""
     return _mla_fwd(q3, ks, v3, scale, heads)[0]

@@ -53,10 +53,9 @@ INDEX_TOPK = "index_topk"
 # the same three for latent attention: scores over a wider head (with one
 # rotary key head shared by every query head) than the values read
 FLASH_MLA_FWD = "flash_mla_fwd"
-FLASH_MLA_BWD_DQ = "flash_mla_bwd_dq"
 FLASH_MLA_BWD_DKV = "flash_mla_bwd_dkv"
 KERNELS = (FLASH_FWD, FLASH_BWD_DQ, FLASH_BWD_DKV)
-MLA_KERNELS = (FLASH_MLA_FWD, FLASH_MLA_BWD_DQ, FLASH_MLA_BWD_DKV)
+MLA_KERNELS = (FLASH_MLA_FWD, FLASH_MLA_BWD_DKV)
 SEL_KERNELS = (FLASH_SEL_FWD, FLASH_SEL_BWD_DQ, FLASH_SEL_BWD_DKV)
 # not ours to choose: the instruction the TPU compiler makes of
 # `jax.lax.ragged_dot` (the expert layer's grouped product) is a custom

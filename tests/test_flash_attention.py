@@ -4,6 +4,8 @@ The kernels are TPU programs; these tests check their arithmetic on the
 CPU, so they ask for the Pallas interpreter themselves (the library
 never falls into it). tests/test_tpu_compile.py compiles the same
 kernels for a described chip."""
+import functools
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -293,12 +295,20 @@ class TestDispatchUnderMesh:
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        rtol=1e-4, atol=5e-5)
 
-    def test_latent_kernels_per_shard_match_xla(self, mesh, monkeypatch):
+    @pytest.mark.parametrize("resident", [None, 128 * 1024],
+                             ids=["one-block", "eight-blocks"])
+    def test_latent_kernels_per_shard_match_xla(self, mesh, monkeypatch,
+                                                resident):
         """`F.latent_attention` under dp2 x mp2: the `flash_mla_*` kernels
         per shard, the one rotary head whole on every chip and its
-        gradient summed over 'model'."""
+        gradient summed over a chip's heads and then over 'model'; with
+        eight blocks a head, dq crosses the k blocks in the fused
+        backward's accumulator."""
         from jax.sharding import NamedSharding, PartitionSpec as P
         from paddle_tpu.nn.functional import attention as A
+        if resident:
+            monkeypatch.setattr(fa, "_RESIDENT_BYTES", resident)
+            assert fa._mla_plan(1024, 16, jnp.float32).block * 8 == 1024
         q, kn, kr, v, do = _latent_operands(1024, 4, 16, 8, 16, b=4)
         sh = NamedSharding(mesh, P("data", None, "model", None))
         q, kn, v, do = (jax.device_put(a, sh) for a in (q, kn, v, do))
@@ -511,21 +521,35 @@ def _latent(q, kn, kr, v, concat=False):
 
 @pytest.mark.parametrize("concat", [False, True],
                          ids=["rotary-key-by-index-map", "key-concatenated"])
-@pytest.mark.parametrize("s,h,dn,dr,dv,resident", [
-    (256, 2, 128, 64, 128, None),       # the published widths, one block
-    (1024, 3, 16, 8, 16, None),         # a whole-sequence block of one chunk
-    (512, 2, 128, 64, 128, 64 * 1024),  # four blocks on the grid
-], ids=["192-128-s256", "24-16-s1024", "192-128-s512-four-blocks"])
+@pytest.mark.parametrize("s,h,dn,dr,dv,sizes", [
+    (256, 2, 128, 64, 128, {}),         # the published widths, one block
+    (1024, 3, 16, 8, 16, {}),           # a whole-sequence block of one chunk
+    # four blocks on the grid
+    (512, 2, 128, 64, 128, {"_RESIDENT_BYTES": 64 * 1024}),
+    # six blocks: a q block's dq is added to by up to six k blocks, grid
+    # steps that do not follow each other
+    (768, 2, 16, 8, 16, {"_RESIDENT_BYTES": 64 * 1024}),
+    # three blocks of two chunks of two groups: every offset of the walk
+    # (block, chunk, group) moves the rows of dq a pair adds to
+    (1536, 2, 16, 8, 16, {"_RESIDENT_BYTES": 512 * 1024, "_CHUNK": 256,
+                          "_CAUSAL_SUB": 128}),
+], ids=["192-128-s256", "24-16-s1024", "192-128-s512-four-blocks",
+        "24-16-s768-six-blocks", "24-16-s1536-blocks-chunks-groups"])
 def test_latent_kernels_match_the_xla_path(monkeypatch, s, h, dn, dr, dv,
-                                           resident, concat):
+                                           sizes, concat):
     """Scores over dn + dr lanes with ONE rotary key head shared by every
     query head, values dv wide: forward and all four gradients, `dk_rope`
     (summed over the heads) among them, against `F.latent_attention`'s
-    XLA path."""
+    XLA path; dq, which the backward sums over a head's k blocks, with
+    one block and with three and more."""
     from paddle_tpu.nn import functional as F
-    if resident:
-        monkeypatch.setattr(fa, "_RESIDENT_BYTES", resident)
-        assert fa._mla_plan(s, dv, jnp.float32).block < s
+    for name, size in sizes.items():
+        monkeypatch.setattr(fa, name, size)
+    if sizes:
+        plan = fa._mla_plan(s, dv, jnp.float32)
+        assert plan.block * 3 <= s, plan
+        if "_CHUNK" in sizes:
+            assert plan == (512, 256, 128)
     q, kn, kr, v, do = _latent_operands(s, h, dn, dr, dv)
 
     def xla(*a):
@@ -545,6 +569,75 @@ def test_latent_kernels_match_the_xla_path(monkeypatch, s, h, dn, dr, dv,
             atol=1e-5 * float(jnp.abs(b).max()), err_msg=name)
 
 
+def test_latent_dq_is_summed_in_float32_and_rounded_once(monkeypatch):
+    """bf16 operands, four k blocks a head: dq is the float32 sum over
+    ALL the head's keys of ds (bf16, as the MXU takes it) x k, rounded to
+    bf16 once. A backward that rounds each k block's share to bf16 and
+    adds the shares up is told apart: the reference does that too, and
+    must fail the same comparison."""
+    monkeypatch.setattr(fa, "_RESIDENT_BYTES", 64 * 1024)
+    s, h, dn, dr, dv = 512, 2, 128, 64, 128
+    block = fa._mla_plan(s, dv, jnp.bfloat16).block
+    assert block * 4 == s
+    q, kn, kr, v, do = (
+        jnp.swapaxes(x, 1, 2).reshape(-1, s, x.shape[-1])
+        for x in _latent_operands(s, h, dn, dr, dv, jnp.bfloat16, b=1))
+    scale = (dn + dr) ** -0.5
+    o, lse = fa._mla_fwd(q, (kn, kr), v, scale, h)
+    got = fa._mla_bwd(scale, h, (q, (kn, kr), v, o, lse), do)[0]
+    assert got.dtype == jnp.bfloat16
+
+    f32 = functools.partial(jnp.asarray, dtype=jnp.float32)
+    hi = functools.partial(jnp.einsum, precision="highest")
+    k = jnp.concatenate([f32(kn), jnp.broadcast_to(f32(kr), (h, s, dr))], -1)
+    scores = hi("hqd,hkd->hqk", f32(q), k) * scale
+    p = jnp.where(jnp.tril(jnp.ones((s, s), bool)),
+                  jnp.exp(scores - lse[..., :1]), 0.0)
+    delta = jnp.sum(f32(do) * f32(o), -1, keepdims=True)
+    ds = f32((p * (hi("hqd,hkd->hqk", f32(do), f32(v)) - delta)
+              * scale).astype(jnp.bfloat16))
+    once = hi("hqk,hkd->hqd", ds, k).astype(jnp.bfloat16)
+    shares = [hi("hqk,hkd->hqd", ds[:, :, i:i + block], k[:, i:i + block])
+              for i in range(0, s, block)]
+    by_block = sum(f32(x.astype(jnp.bfloat16)) for x in shares).astype(
+        jnp.bfloat16)
+
+    def differ(a):
+        # the share of dq's elements that are not `once`'s bf16 value
+        return float(jnp.mean(f32(a) != f32(once)))
+    # the kernel sums the same products in another order and takes lse's
+    # exp and ds's rounding from its own float32 scores: an element whose
+    # sum lies next to a rounding boundary may fall to the other side
+    # (read here: 0.03 % of the kernel's elements, 28 % of the shares')
+    assert differ(got) < 0.005, differ(got)
+    assert differ(by_block) > 0.1, differ(by_block)
+
+
+def test_latent_backward_counts_itself_and_sizes_its_vmem(monkeypatch):
+    """One backward kernel, said by a static counter where it is traced;
+    the head's dq accumulator and the kernel's VMEM limit follow the
+    shapes, and a head whose dq does not fit is refused by name."""
+    from paddle_tpu.profiler import stats
+    acc, limit = fa._mla_bwd_vmem(8192, 1024, [128, 64], 128)
+    assert acc == 8192 * 192 * 4            # dq transposed: no lane padding
+    assert acc + (16 << 20) < limit < (40 << 20)
+    assert fa._mla_bwd_vmem(8192, 1024, [192], 128)[0] == acc
+    assert fa._mla_bwd_vmem(32768, 1024, [128, 64], 128)[0] \
+        <= fa._MLA_DQ_BYTES
+
+    q, kn, kr, v, _ = _latent_operands(128, 2, 16, 8, 16)
+
+    def trace_backward():       # a new function each time: no cached trace
+        jax.make_jaxpr(jax.grad(
+            lambda *a: fa.flash_attention_latent(*a).sum()))(q, kn, kr, v)
+    stats.static("attn.latent.bwd_kernels", 0)
+    trace_backward()
+    assert stats.REGISTRY.counter("attn.latent.bwd_kernels").value == 1
+    monkeypatch.setattr(fa, "_MLA_DQ_BYTES", 128 * 24 * 4 - 1)
+    with pytest.raises(ValueError, match="whole dq in VMEM"):
+        trace_backward()
+
+
 def test_latent_kernels_in_bf16_and_by_their_own_names():
     from paddle_tpu.nn import functional as F
     q, kn, kr, v, _ = _latent_operands(256, 2, 128, 64, 128, jnp.bfloat16)
@@ -557,8 +650,9 @@ def test_latent_kernels_in_bf16_and_by_their_own_names():
     text = jax.jit(jax.grad(lambda *a: fa.flash_attention_latent(
         *a).astype(jnp.float32).sum(), argnums=(0, 1, 2, 3))).lower(
         q, kn, kr, v).as_text(debug_info=True)
-    for name in ("flash_mla_fwd", "flash_mla_bwd_dq", "flash_mla_bwd_dkv"):
+    for name in ("flash_mla_fwd", "flash_mla_bwd_dkv"):
         assert name in text, name
+    assert "flash_mla_bwd_dq" not in text
     assert "flash_fwd" not in text and "flash_sel" not in text
     # 192 lanes pad to 256, so `_plan` by the scores' width would keep
     # 512 rows resident at 8192 tokens; the latent kernels plan by the

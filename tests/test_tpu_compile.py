@@ -217,9 +217,10 @@ def test_latent_kernels_compile_at_kanana_widths(one_chip, concat):
     text = compiled_text(loss_and_grads, shape(b, s, h, dn + dr),
                          shape(b, s, h, dn), shape(b, s, 1, dr),
                          shape(b, s, h, dv))
-    assert text.count("tpu_custom_call") == 3
-    for name in ("flash_mla_fwd", "flash_mla_bwd_dq", "flash_mla_bwd_dkv"):
+    assert text.count("tpu_custom_call") == 2
+    for name in ("flash_mla_fwd", "flash_mla_bwd_dkv"):
         assert name in text, name
+    assert "flash_mla_bwd_dq" not in text
 
 
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
@@ -266,7 +267,7 @@ def test_dispatch_shards_kernel_over_four_chips(topo, monkeypatch):
 
 def test_dispatch_shards_latent_kernels_over_four_chips(topo, monkeypatch):
     """`F.latent_attention` under a dp2 x mp2 mesh at the kanana widths:
-    the three `flash_mla_*` kernels per shard (16 heads a chip, the one
+    the two `flash_mla_*` kernels per shard (16 heads a chip, the one
     rotary head whole on each), not the XLA path."""
     from paddle_tpu.distributed import build_mesh, topology
     from paddle_tpu.nn.functional import attention as A
@@ -285,9 +286,10 @@ def test_dispatch_shards_latent_kernels_over_four_chips(topo, monkeypatch):
 
     text = compiled_text(loss_and_grads, shape(32, 192), shape(32, 128),
                          shape(1, 64, P("data")), shape(32, 128))
-    assert text.count("tpu_custom_call") == 3
-    for name in ("flash_mla_fwd", "flash_mla_bwd_dq", "flash_mla_bwd_dkv"):
+    assert text.count("tpu_custom_call") == 2
+    for name in ("flash_mla_fwd", "flash_mla_bwd_dkv"):
         assert name in text, name
+    assert "flash_mla_bwd_dq" not in text
 
 
 def test_dispatch_keeps_one_chip_under_a_stale_mesh(topo, one_chip,

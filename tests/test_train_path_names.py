@@ -193,6 +193,10 @@ def metric_files() -> dict:
 # instruction the compiler names for it (`jax.lax.ragged_dot`)
 NAMED = prof.KERNELS + prof.SEL_KERNELS + prof.MLA_KERNELS + (
     prof.INDEX_SCORES, prof.INDEX_TOPK, prof.RAGGED_DOT)
+# a name the accepted patterns still carry and no kernel bears: the latent
+# dq kernel went into the dk/dv walk (ISSUE 34), and `mla_attn_ms` /
+# `mla_attn_roofline` keep its alternative until a `benchmark` PR renames
+RETIRED = ("flash_mla_bwd_dq",)
 
 
 def test_metric_patterns_name_the_programs_kernels():
@@ -209,7 +213,7 @@ def test_metric_patterns_name_the_programs_kernels():
                 continue
             pattern = spec["params"][key]
             named = re.findall(r"%([\w-]+)", pattern)
-            assert named and set(named) <= set(NAMED), (name, key)
+            assert named and set(named) <= set(NAMED + RETIRED), (name, key)
             seen.update(named)
             for kernel in named:
                 assert re.search(pattern, f"%{kernel}.16 = (bf16[128,1024,64]"
@@ -220,7 +224,8 @@ def test_metric_patterns_name_the_programs_kernels():
             for stem in ("checkpoint", "closed_call", "rematted_computation",
                          "bf16[", "f32["):
                 assert stem not in pattern, (name, stem)
-    assert seen == set(NAMED)
+    assert seen == set(NAMED + RETIRED)
+    assert not set(RETIRED) & set(NAMED)
 
 
 def test_index_topk_ms_is_the_keye_cells_alone():

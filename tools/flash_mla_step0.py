@@ -5,10 +5,12 @@ on the chip:
     python tools/flash_mla_step0.py [--kernels 1] [--step POLICY,POLICY]
         [--balance SEED,SEED] [--out FILE]
 
-1. `--kernels 1`: the three latent flash kernels at the cell's widths
-   (bf16 [2, 8192, 32, 192] queries, keys as 32 x 128 + ONE rotary head
-   of 64, values 32 x 128), forward and backward, as the layer scan of a
-   step runs them: a `scan` of `--layers` calls of value-and-gradient.
+1. `--kernels 1`: the latent flash kernels (`profiler.MLA_KERNELS`: since
+   ISSUE 34 the forward and ONE backward, dq out of the dk/dv walk) at
+   the cell's widths (bf16 [2, 8192, 32, 192] queries, keys as 32 x 128 +
+   ONE rotary head of 64, values 32 x 128), forward and backward, as the
+   layer scan of a step runs them: a `scan` of `--layers` calls of
+   value-and-gradient.
    VARIANTS ranks how the kernels are handed the rotary key: read through
    the index map `b // 32` with two score products summed in the kernel
    and `dk_rope` added up over the heads outside ("index"), or
