@@ -26,13 +26,17 @@ that buffer (`jax.lax.ragged_dot`, which the TPU compiler turns into a
 tiled grouped matmul). The buffer is static and sized for the load the
 router is expected to send here with half as much again (`rows_buffer`);
 nothing is dropped, because the ordered assignments are taken a buffer at
-a time for as many rounds as they need (`grouped_experts`: a loop whose
+a time for as many rounds as they need (`grouped_experts`: loops whose
 trip count is the data's, one round while the load stays under the buffer,
-tokens x min(top_k, held) / buffer rounds at most), so all rows routed to
-one expert still come out right, and memory is bounded by the buffer
-whatever the router does. Dispatch is a row gather and combine a
-scatter-add by token over the buffer's rows, each the other's transpose,
-so their cost is the buffer's; the products' is the load's.
+tokens x min(top_k, held) / buffer rounds and `TAIL_ROUNDS` at most), so
+all rows routed to one expert still come out right, and memory is bounded
+by the buffer whatever the router does. Dispatch is a row gather and
+combine a scatter-add by token over the buffer's rows, each the other's
+transpose, so their cost is the buffer's; the products' is the load's.
+That is why the last rounds are short (`_schedule`): a load just over the
+buffer leaves a second buffer nearly empty and would pay for all of it
+(8.9 ms a layer at 24576 rows of 2048, 3.3 ms for 3072:
+`tools/moe_rounds_step0.py`).
 """
 from __future__ import annotations
 
@@ -47,6 +51,12 @@ from ...nn import initializer as I
 from ...nn.layer import Layer
 from ...profiler import MOE_EXPERTS, MOE_ROUTE, MOE_SHARED, stats
 from .mp_layers import ColumnParallelLinear, RowParallelLinear
+
+# the rounds behind the first buffer: a short one takes 1 / TAIL_SHARE of
+# the buffer, and where TAIL_ROUNDS of them do not hold what is left, a
+# whole buffer is taken first
+TAIL_SHARE = 8
+TAIL_ROUNDS = 2
 
 
 def topk_gating(logits, top_k: int, norm_topk_prob: bool = True):
@@ -175,38 +185,68 @@ def _one_round(x, weights, w_gate, w_up, w_down, plan: Dispatch):
         y * w_row[:, None])
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
-def _rounds(x, weights, w_gate, w_up, w_down, plan: Dispatch, rows: int):
-    """`_one_round` over the plan's rows, `rows` at a time, for as many
-    rounds as hold an assignment: a loop with the data's trip count,
-    which reverse mode cannot go through, so the backward is a loop of
-    its own that replays a round and takes its gradient."""
-    def body(i, y):
-        return y + _one_round(x, weights, w_gate, w_up, w_down,
-                              _round_of(plan, i * rows, rows))
-    return jax.lax.fori_loop(0, _rounds_needed(plan, rows), body,
-                             jnp.zeros(x.shape, jnp.float32))
+def _schedule(plan: Dispatch, rows: int, tail: int):
+    """(whole buffers, short rounds) that hold the assignments behind the
+    first buffer: short rounds of `tail` rows where `TAIL_ROUNDS` of them
+    hold what is left, a whole buffer of `rows` while more is."""
+    behind = jnp.maximum(jnp.sum(plan.sizes) - rows, 0)
+    whole = jnp.maximum(behind + rows - 1 - TAIL_ROUNDS * tail, 0) // rows
+    return whole, (behind - whole * rows + tail - 1) // tail
 
 
-def _rounds_needed(plan: Dispatch, rows: int):
-    return (jnp.sum(plan.sizes) + rows - 1) // rows
+def plan_rows(worst: int, rows: int, tail: int) -> int:
+    """Rows of a plan whose every round lies inside it, for at most
+    `worst` assignments: whole buffers, and a short round behind the last
+    that is full."""
+    return -(-worst // rows) * rows + (tail if tail < rows else 0)
 
 
-def _rounds_fwd(x, weights, w_gate, w_up, w_down, plan, rows):
-    return _rounds(x, weights, w_gate, w_up, w_down, plan, rows), \
+def _over_rounds(plan: Dispatch, rows: int, tail: int, one, first):
+    """Fold `one(carry, start, size)` over the plan's rounds: the first
+    buffer, the whole buffers behind it, then the short rounds."""
+    whole, short = _schedule(plan, rows, tail)
+    acc = jax.lax.fori_loop(
+        0, whole, lambda i, acc: one(acc, (1 + i) * rows, rows), first)
+    return jax.lax.fori_loop(
+        0, short,
+        lambda i, acc: one(acc, (1 + whole) * rows + i * tail, tail), acc)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def _rounds(x, weights, w_gate, w_up, w_down, plan: Dispatch, rows: int,
+            tail: int):
+    """`_one_round` over the plan's rows, a buffer of `rows` at a time and
+    the last of them `tail` at a time, for as many rounds as hold an
+    assignment: loops with the data's trip count, which reverse mode
+    cannot go through, so the backward is loops of its own that replay a
+    round and take its gradient. A round's gather and scatter-add cost
+    what its buffer holds, filled or not: a load just over the buffer
+    pays for a short round, not for a second buffer."""
+    def one(start, size):
+        return _one_round(x, weights, w_gate, w_up, w_down,
+                          _round_of(plan, start, size))
+    return _over_rounds(plan, rows, tail,
+                        lambda y, start, size: y + one(start, size),
+                        one(0, rows))
+
+
+def _rounds_fwd(x, weights, w_gate, w_up, w_down, plan, rows, tail):
+    return _rounds(x, weights, w_gate, w_up, w_down, plan, rows, tail), \
         (x, weights, w_gate, w_up, w_down, plan)
 
 
-def _rounds_bwd(rows, res, g):
+def _rounds_bwd(rows, tail, res, g):
     *args, plan = res
 
-    def body(i, acc):
+    def one(start, size):
         _, vjp = jax.vjp(
-            lambda *a: _one_round(*a, _round_of(plan, i * rows, rows)),
-            *args)
-        return jax.tree.map(jnp.add, acc, vjp(g))
-    zero = tuple(jnp.zeros(a.shape, a.dtype) for a in args)
-    grads = jax.lax.fori_loop(0, _rounds_needed(plan, rows), body, zero)
+            lambda *a: _one_round(*a, _round_of(plan, start, size)), *args)
+        return vjp(g)
+    grads = _over_rounds(
+        plan, rows, tail,
+        lambda acc, start, size: jax.tree.map(jnp.add, acc,
+                                              one(start, size)),
+        one(0, rows))
     return (*grads, None)
 
 
@@ -214,21 +254,23 @@ _rounds.defvjp(_rounds_fwd, _rounds_bwd)
 
 
 def grouped_experts(x, plan: Dispatch, weights, w_gate, w_up, w_down,
-                    compute_dtype=None, rows: Optional[int] = None):
+                    compute_dtype=None, rows: Optional[int] = None,
+                    tail: Optional[int] = None):
     """The held experts over their rows. x [t, d]; weights [t, k] fp32;
     w_gate / w_up [held, d, f], w_down [held, f, d]. Returns [t, d] fp32:
     sum over a token's held assignments of weight x expert(x). `rows`:
-    buffer rows a round (default: the whole plan in one round); the
-    plan's rows are a multiple of it."""
+    rows of the buffer (default: the whole plan in one round); `tail`:
+    of a short round (default: `rows`); the plan has `plan_rows` rows."""
     dt = compute_dtype or x.dtype
     total = plan.token.shape[0]
     rows = rows or total
-    assert total % rows == 0, (total, rows)
+    tail = tail or rows
+    assert tail <= rows <= total, (total, rows, tail)
     args = (x.astype(dt), weights, w_gate.astype(dt), w_up.astype(dt),
             w_down.astype(dt))
     if rows == total:
         return _one_round(*args, plan)
-    return _rounds(*args, plan, rows)
+    return _rounds(*args, plan, rows, tail)
 
 
 class GatedMLP(Layer):
@@ -310,16 +352,19 @@ class MoEMLP(Layer):
         self._aux = aux_loss
 
     def rows_buffer(self, tokens: int) -> tuple:
-        """(rows of the static buffer, rounds that hold every assignment
-        that can fall on the held experts). The buffer: what balanced
-        routing sends here, tokens x top_k x held / experts, and half as
-        much again, in whole tiles of the grouped product (512 rows);
-        never more than the worst case, tokens x min(top_k, held)."""
+        """(rows of the static buffer, rows of a short round, rows of the
+        plan: what holds every assignment that can fall on the held
+        experts). The buffer: what balanced routing sends here, tokens x
+        top_k x held / experts, and half as much again, in whole tiles of
+        the grouped product (512 rows); never more than the worst case,
+        tokens x min(top_k, held). A short round: 1 / `TAIL_SHARE` of the
+        buffer, in whole tiles."""
         worst = tokens * min(self.top_k, self.experts_held)
         rows = self._rows or -(-3 * tokens * self.top_k * self.experts_held
                                // (2 * self.num_experts * 512)) * 512
         rows = min(rows, worst)
-        return rows, -(-worst // rows)
+        tail = min(rows, -(-rows // (TAIL_SHARE * 512)) * 512)
+        return rows, tail, plan_rows(worst, rows, tail)
 
     def forward(self, x):
         b, s, d = x.shape
@@ -342,16 +387,17 @@ class MoEMLP(Layer):
                                                       self.norm_topk_prob)
             if self._aux:
                 self.aux_loss.value = balance_loss(experts, probs)
-            rows, rounds = self.rows_buffer(b * s)
+            rows, tail, total = self.rows_buffer(b * s)
             plan = dispatch_plan(experts, self.expert_offset,
-                                 self.experts_held, rows * rounds)
+                                 self.experts_held, total)
         stats.static("moe.experts_held", self.experts_held)
         stats.static("moe.rows_buffer", rows)
         stats.static("moe.top_k", self.top_k)
         with jax.named_scope(MOE_EXPERTS):
             y = grouped_experts(xt, plan, weights, jnp.asarray(self.w_gate),
                                 jnp.asarray(self.w_up),
-                                jnp.asarray(self.w_down), self._cdt, rows)
+                                jnp.asarray(self.w_down), self._cdt, rows,
+                                tail)
         if self.shared is not None:
             stats.static("moe.shared_width",
                          self.shared.gate_proj.out_features)

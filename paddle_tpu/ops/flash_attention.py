@@ -7,8 +7,9 @@ both directions: the S×S score matrix lives only chunk-by-chunk in VMEM, so
 long sequences never materialize O(S²) in HBM.
 
 Layout contract: [batch, seq, heads, head_dim] (paddle 2.x attention
-layout); internally [b·h, s, d]. All three kernels (fwd, dq, dk/dv) run on
-a grid (bh, out_block, reduce_block) and share one skeleton (`_walk`). The
+layout); internally [b·h, s, d]. All kernels (fwd, dq, dk/dv, and the fused
+backward further down) run on a grid (bh, out_block, reduce_block) and
+share one skeleton (`_walk`). The
 resident block is the whole sequence while an operand of one head fits
 `_RESIDENT_BYTES` (2K tokens in bf16), so at 1K tokens the grid is
 (bh, 1, 1): K and V (dk/dv: Q and dO) are fetched once a head, nothing is
@@ -39,12 +40,14 @@ statistics are fp32. Row statistics (logsumexp/delta) ride an 8-lane
 broadcast between forward and dq because TPU block layouts need a
 lane-divisible trailing dim.
 
-Latent attention (further down) has two kernels, not three: its backward
-is the dk/dv walk alone, and dq comes out of it, because a dq kernel of
-its own computes the scores, their `exp` and dO vᵀ of every (q, k) pair a
-second time (640 of the pair's 1408 lanes of MXU work at 192-wide scores).
-The head's whole dq waits in a float32 VMEM accumulator while the walk
-passes the head's k blocks.
+Latent attention (further down) and attention under a per-pair selection
+have two kernels, not three: their backward is the dk/dv walk alone
+(`_fused_bwd`), and dq comes out of it, because a dq kernel of its own
+computes the scores, their mask and selection, their `exp` and dO vᵀ of
+every (q, k) pair a second time (640 of the pair's 1408 lanes of MXU work
+at 192-wide scores, 384 of 896 at 128). The head's whole dq waits in a
+float32 VMEM accumulator while the walk passes the head's k blocks. The
+causal and the k-side-masked family keep a dq kernel and a dk/dv kernel.
 """
 from __future__ import annotations
 
@@ -59,7 +62,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ..profiler import (FLASH_BWD_DKV, FLASH_BWD_DQ, FLASH_FWD,
                         FLASH_MLA_BWD_DKV, FLASH_MLA_FWD, FLASH_SEL_BWD_DKV,
-                        FLASH_SEL_BWD_DQ, FLASH_SEL_FWD)
+                        FLASH_SEL_FWD)
 from ..profiler import stats
 
 # Row statistics (lse/delta) ride an 8-lane broadcast: TPU block layouts
@@ -71,9 +74,11 @@ NEG_INF = -1e30
 
 # The most one resident operand block may take of VMEM, counted as it lies
 # there (rows x the head dim padded to 128 lanes x the operand's bytes).
-# dq holds five such blocks and two columns of statistics, all
-# double-buffered: 5 MiB + 4 MiB of the 16 MiB a kernel may use on a v5e,
-# beside a group's scores.
+# The causal and masked family's dq kernel holds five such blocks and two
+# columns of statistics, all double-buffered: 5 MiB + 4 MiB of the 16 MiB
+# a kernel may use on a v5e unasked, beside a group's scores. The fused
+# backward of the selected and the latent family holds a head's whole dq
+# besides, and asks for its VMEM by its shapes (`_fused_bwd_vmem`).
 _RESIDENT_BYTES = 512 * 1024
 
 
@@ -349,11 +354,14 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, causal, scale, plan, n, masked,
 def _specs(plan, d, causal, heads, out_is_q, group=1):
     """Block specs of a grid (bh, out_block, reduce_block): operand rows of
     the out side and of the reduce side, the q side's statistics as
-    columns (`stat_out`) or one row a group (`stat_rows`), the k-side
-    mask one row a chunk (`mask_rows`) or as a column (`mask_col`),
-    each along the axis its side lies on, and the (out, reduce) block of
-    a per-pair selection (`sel`), which like the mask is one per batch
-    row. `kv_out` / `kv_red` are the key/value side where `group` query
+    columns (`stat_out`: the forward writes them, the causal and masked
+    dq kernel reads them) or one row a group (`stat_rows`: every dk/dv
+    walk), the k-side mask one row a chunk (`mask_rows`) or as a column
+    (`mask_col`), each along the axis its side lies on, and the (out,
+    reduce) block of a per-pair selection (`sel`: [q, k] to the forward,
+    [k, q] of the transposed operand to the fused backward, which is the
+    one backward kernel that reads it), which like the mask is one per
+    batch row. `kv_out` / `kv_red` are the key/value side where `group` query
     heads share one key/value head: the grid runs over query heads and
     the index map folds them (b // group), so no copy is made. Under a
     causal mask the reduce side stops (dk/dv: starts) at the out block,
@@ -430,10 +438,9 @@ def _fwd(q3, k3, v3, causal, scale, mask3=None, heads=1, sel=None, group=1):
 # --------------------------------------------------------------- backward
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
-               causal, scale, plan, n, masked, selected=False):
+               causal, scale, plan, n, masked):
     refs = list(refs)
     mask_ref = refs.pop(0) if masked else None
-    sel_ref = refs.pop(0) if selected else None
     dq_ref = refs[0]
     _, c, sub = plan
     d = q_ref.shape[-1]
@@ -441,19 +448,16 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
     def prep(r):
         return (_rows(q_ref, r, c), _rows(do_ref, r, c),
                 _rows(lse_ref, r, c)[:, 0:1],
-                _rows(delta_ref, r, c)[:, 0:1]), r
+                _rows(delta_ref, r, c)[:, 0:1])
 
     def piece(ctx, j, g, lo, hi, tri, carry):
-        q, do, lse, delta = (x[g:g + sub] for x in ctx[0])
+        q, do, lse, delta = (x[g:g + sub] for x in ctx)
         k, v = _rows(k_ref, j * c, hi), _rows(v_ref, j * c, hi)
         s = _dot(q, k, _NT) * scale
         if tri:
             s = jnp.where(_keep_tri(sub, hi, g, True), s, NEG_INF)
         if masked:
             s = jnp.where(mask_ref[0, 0, pl.ds(j, 1), :hi] > 0, s, NEG_INF)
-        if selected:
-            s = jnp.where(_selected(sel_ref, ctx[1] + g, sub, j * c, hi), s,
-                          NEG_INF)
         ds = jnp.exp(s - lse) * (_dot(do, v, _NT) - delta) * scale
         return (carry[0] + _dot(ds.astype(k.dtype), k, _NN),)
 
@@ -465,34 +469,24 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
 
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
-                causal, scale, plan, n, masked, selected=False):
+                causal, scale, plan, n, masked):
     """dk and dv of the resident k block, a chunk of k rows at a time over
     the chunks of the resident q block. The scores are computed
     transposed, sᵀ = k qᵀ: k runs along sublanes and q along lanes, so
     lse and delta arrive as rows [1, q], the k-side mask as a column, and
-    pᵀ dO and dsᵀ q contract over lanes like any matmul; a per-pair
-    selection arrives transposed too, [k, q]."""
+    pᵀ dO and dsᵀ q contract over lanes like any matmul."""
     refs = list(refs)
     mask_ref = refs.pop(0) if masked else None
-    sel_ref = refs.pop(0) if selected else None
     dk_ref, dv_ref = refs[:2]
     _, c, sub = plan
     d = q_ref.shape[-1]
 
     def prep(r):
         keep = _rows(mask_ref, r, c)[:, 0:1] > 0 if masked else None
-        return (_rows(k_ref, r, c), _rows(v_ref, r, c), keep), r
-
-    def stat_row(ref, start, size):
-        # [1, size] from rows of `sub`: a row whose lanes are cut after the
-        # load keeps its lane offset, which no broadcast accepts
-        return jnp.concatenate(
-            [ref[0, 0, pl.ds((start + u) // sub, 1), :]
-             for u in range(0, size, sub)], axis=1)
+        return _rows(k_ref, r, c), _rows(v_ref, r, c), keep
 
     def piece(ctx, i, g, lo, hi, tri, carry):
-        k, v, keep = (x[g:g + sub] if x is not None else None
-                      for x in ctx[0])
+        k, v, keep = (x[g:g + sub] if x is not None else None for x in ctx)
         dk, dv = carry
         q0, nq = i * c + lo, hi - lo
         q, do = _rows(q_ref, q0, nq), _rows(do_ref, q0, nq)
@@ -501,12 +495,10 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
             st = jnp.where(_keep_tri(sub, nq, 0, False), st, NEG_INF)
         if masked:
             st = jnp.where(keep, st, NEG_INF)
-        if selected:
-            st = jnp.where(_selected(sel_ref, ctx[1] + g, sub, q0, nq), st,
-                           NEG_INF)
-        pt = jnp.exp(st - stat_row(lse_ref, q0, nq))
+        pt = jnp.exp(st - _stat_row(lse_ref, q0, nq, sub))
         dv = dv + _dot(pt.astype(do.dtype), do, _NN)
-        dst = pt * (_dot(v, do, _NT) - stat_row(delta_ref, q0, nq)) * scale
+        dst = pt * (_dot(v, do, _NT)
+                    - _stat_row(delta_ref, q0, nq, sub)) * scale
         return dk + _dot(dst.astype(q.dtype), q, _NN), dv
 
     def finalize(ctx, r, carry):
@@ -518,9 +510,32 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
           ((0.0, d), (0.0, d)), refs[2:], prep, piece, finalize)
 
 
+def _stat_row(ref, start, size, sub):
+    """[1, size] of a q-side statistic that lies along lanes in rows of
+    `sub` (`_specs` `stat_rows`): a row whose lanes are cut after the load
+    keeps its lane offset, which no broadcast accepts."""
+    return jnp.concatenate(
+        [ref[0, 0, pl.ds((start + u) // sub, 1), :]
+         for u in range(0, size, sub)], axis=1)
+
+
 def _bwd_impl(causal, scale, res, g, mask3=None, heads=1, sel=None,
               group=1):
+    """dq, dk, dv. The causal and the k-side-masked family: a dq kernel
+    and a dk/dv kernel, each of which computes the scores, their `exp`
+    and dO vᵀ of every pair it visits. Under a selection: the ONE fused
+    kernel further down (`_fused_bwd`), which the latent family shares."""
     q3, k3, v3, o3, lse = res
+    if sel is not None:
+        if not causal:
+            raise NotImplementedError(
+                "a selection without causal=True: the fused backward ends "
+                "a query block's dq at its diagonal key block")
+        # the selection arrives transposed, [k, q], like the scores
+        dq, (dk,), dv = _fused_bwd(
+            FLASH_SEL_BWD_DKV, "attn.selected.bwd_kernels", scale, q3,
+            (k3,), v3, o3, lse, g, sel_t=jnp.swapaxes(sel, 1, 2))
+        return dq, dk, dv
     bh, s, d = q3.shape
     plan = _plan(s, d, q3.dtype, causal)
     block, c, sub = plan
@@ -535,21 +550,18 @@ def _bwd_impl(causal, scale, res, g, mask3=None, heads=1, sel=None,
     delta_rows = delta.reshape(bh, n, block // sub, sub)
     lse_rows = lse[..., 0].reshape(bh, n, block // sub, sub)
     masked = mask3 is not None
-    selected = sel is not None
     acc = [pltpu.VMEM((block, d), jnp.float32)] if n > 1 else []
 
     # dq grid: (bh, q_block, k_block) — the k-side mask follows axis 2
     sp = _specs(plan, d, causal, heads, out_is_q=True, group=group)
     dq_in = [sp["out"], sp["kv_red"], sp["kv_red"], sp["out"],
              sp["stat_out"], sp["stat_out"]] + (
-        [sp["mask_rows"]] if masked else []) + (
-        [sp["sel"]] if selected else [])
+        [sp["mask_rows"]] if masked else [])
     dq_args = [q3, k3, v3, do3, lse, delta3] + (
-        [mask3.reshape(-1, n, block // c, c)] if masked else []) + (
-        [sel] if selected else [])
+        [mask3.reshape(-1, n, block // c, c)] if masked else [])
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, causal=causal, scale=scale,
-                          plan=plan, n=n, masked=masked, selected=selected),
+                          plan=plan, n=n, masked=masked),
         grid=(bh, n, n),
         in_specs=dq_in,
         out_specs=[sp["out"]],
@@ -557,29 +569,26 @@ def _bwd_impl(causal, scale, res, g, mask3=None, heads=1, sel=None,
         scratch_shapes=acc,
         interpret=_interpret(),
         compiler_params=_COMPILER_PARAMS,
-        name=FLASH_SEL_BWD_DQ if selected else FLASH_BWD_DQ,
+        name=FLASH_BWD_DQ,
     )(*dq_args)[0]
 
     # grid dims: (bh, k_block, q_block) — q is the reduce (innermost) dim;
-    # the k-side mask follows axis 1 here, as a column beside k's rows,
-    # and a selection arrives transposed, [k, q]. Where `group` query
-    # heads share a key/value head the grid still runs over query heads:
-    # each writes its own dk, dv in fp32 and XLA adds a group's up, which
-    # costs one pass over [bh, s, d] where a second reduce axis would
-    # cost the kernels their static walk.
+    # the k-side mask follows axis 1 here, as a column beside k's rows.
+    # Where `group` query heads share a key/value head the grid still runs
+    # over query heads: each writes its own dk, dv in fp32 and XLA adds a
+    # group's up, which costs one pass over [bh, s, d] where a second
+    # reduce axis would cost the kernels their static walk.
     sp = _specs(plan, d, causal, heads, out_is_q=False, group=group)
     dkv_in = [sp["red"], sp["kv_out"], sp["kv_out"], sp["red"],
               sp["stat_rows"], sp["stat_rows"]] + (
-        [sp["mask_col"]] if masked else []) + (
-        [sp["sel"]] if selected else [])
+        [sp["mask_col"]] if masked else [])
     dkv_args = [q3, k3, v3, do3, lse_rows, delta_rows] + (
         [jnp.broadcast_to(mask3.reshape(-1, s, 1), (mask3.shape[0], s, LANE))]
-        if masked else []) + (
-        [jnp.swapaxes(sel, 1, 2)] if selected else [])
+        if masked else [])
     part = jnp.float32 if group > 1 else None
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, causal=causal, scale=scale,
-                          plan=plan, n=n, masked=masked, selected=selected),
+                          plan=plan, n=n, masked=masked),
         grid=(bh, n, n),
         in_specs=dkv_in,
         out_specs=[sp["out"], sp["out"]],
@@ -588,7 +597,7 @@ def _bwd_impl(causal, scale, res, g, mask3=None, heads=1, sel=None,
         scratch_shapes=acc * 2,
         interpret=_interpret(),
         compiler_params=_COMPILER_PARAMS,
-        name=FLASH_SEL_BWD_DKV if selected else FLASH_BWD_DKV,
+        name=FLASH_BWD_DKV,
     )(*dkv_args)
     if group > 1:
         dk, dv = (x.reshape(bh // group, group, s, d).sum(1).astype(k3.dtype)
@@ -600,11 +609,12 @@ def _bwd_impl(causal, scale, res, g, mask3=None, heads=1, sel=None,
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
 def _flash3(q3, k3, v3, mask3, sel, causal, scale, heads, group):
-    """The one differentiable wrapper of the three kernels. `mask3`
-    ([batch, 1, s] float, or None): the k-side padding mask; `sel`
-    ([batch, s_q, s_k] 0/1 bytes, or None): a per-pair selection; a
-    row's `heads` query heads share both. `group` query heads share one
-    key/value head."""
+    """The one differentiable wrapper of the forward kernel and its
+    backward: a dq and a dk/dv kernel, or under a selection the one fused
+    kernel (`_bwd_impl`). `mask3` ([batch, 1, s] float, or None): the
+    k-side padding mask; `sel` ([batch, s_q, s_k] 0/1 bytes, or None): a
+    per-pair selection; a row's `heads` query heads share both. `group`
+    query heads share one key/value head."""
     o, _ = _fwd(q3, k3, v3, causal, scale, mask3=mask3, heads=heads, sel=sel,
                 group=group)
     return o
@@ -648,9 +658,10 @@ def flash_attention(query, key, value, causal: bool = False,
 
     selection ([b, s_q, s_k] int8, optional): 1 where query position q may
     read key position k, the same for every head of a row (a learned
-    top-k key selection). `causal` still says which blocks the walk may
-    skip, so a selection that lies under the diagonal is passed WITH
-    causal=True. Rows that select nothing return 0.
+    top-k key selection) and lies under the diagonal: it is passed WITH
+    causal=True, which says which blocks the walk may skip and where the
+    backward has a query block's dq whole. Rows that select nothing
+    return 0.
     """
     b, s, h, d = query.shape
     if s % 128 != 0:
@@ -668,6 +679,10 @@ def flash_attention(query, key, value, causal: bool = False,
         raise NotImplementedError(
             "kv_mask with grouped heads or a selection: fold the padding "
             "into the selection")
+    if selection is not None and not causal:
+        raise NotImplementedError(
+            "a selection without causal=True: the selected backward ends a "
+            "query block's dq at its diagonal key block")
     # [batch, 1, s] / [batch, s_q, s_k]: heads share the batch row via the
     # kernels' b // heads index map (no h-fold HBM duplication)
     m3 = None if kv_mask is None else jnp.asarray(
@@ -699,7 +714,10 @@ def flash_attention(query, key, value, causal: bool = False,
 # own, grid steps that do not follow each other, so it cannot be a carried
 # output block: the head's WHOLE dq stays in VMEM in float32 (6 MiB at
 # 8192 tokens) and each q block is rounded once, when its diagonal k block
-# has passed (PERF.md, PR 34).
+# has passed (PERF.md, PR 34). The selected family's backward (`_bwd_impl`)
+# is the same kernel with ONE key part of the full width, which with the
+# values `group` query heads share, and the selection beside the causal
+# mask (PERF.md, PR 35).
 
 def _part_lanes(ks):
     """[(first lane, width)] of the key parts along the score width."""
@@ -752,26 +770,30 @@ def _mla_fwd_kernel(q_ref, *refs, lanes, scale, plan, n):
           refs[parts + 3:], prep, piece, finalize)
 
 
-def _mla_bwd_kernel(q_ref, *refs, lanes, scale, plan, n):
-    """The latent backward, one kernel: dk of every key part and dv of
-    the resident k block as `_dkv_kernel` makes them (the scores
-    transposed, k qT), and out of the same scores and dO vT the pair's
-    share of dq, added into a float32 accumulator that holds the HEAD's
-    whole dq across all its k blocks (`dq_acc`, one scratch a key part).
-    The accumulator holds dq TRANSPOSED, [blocks, w, block]: the share is
-    then k_partT dst, a plain product of what the walk already has (dst
-    as it stands; k_partT made once a k chunk), where dstT k_part would
-    transpose every group's dst, and a 64-wide part is the product's rows
-    and not its lanes, which pad to 128 (PERF.md, PR 34: 23.9 ms a call
-    against 25.2). A q block's dq is whole once its own (diagonal) k
-    block has passed, which is that k block's first step on the grid:
-    there it is transposed back and rounded, once, into `dq_ref`."""
+def _fused_bwd_kernel(q_ref, *refs, lanes, scale, plan, n, selected):
+    """The backward as ONE kernel, of the latent family and of the
+    selected: dk of every key part and dv of the resident k block as
+    `_dkv_kernel` makes them (the scores transposed, k qT; a per-pair
+    selection arrives transposed too, [k, q]), and out of the same scores
+    and dO vT the pair's share of dq, added into a float32 accumulator
+    that holds the HEAD's whole dq across all its k blocks (`dq_acc`, one
+    scratch a key part). The accumulator holds dq TRANSPOSED, [blocks, w,
+    block]: the share is then k_partT dst, a plain product of what the
+    walk already has (dst as it stands; k_partT made once a k chunk), and
+    a 64-wide part is the product's rows and not its lanes, which pad to
+    128 (PERF.md, PR 34: 23.9 ms a call against 25.2 for the latent
+    family; PR 35: a tie where every part is 128 wide, 16.40 against
+    16.39, so the one layout serves both). A q block's dq is whole once
+    its own (diagonal) k block has passed, which is that k block's first
+    step on the grid: there it is transposed back and rounded, once, into
+    `dq_ref`."""
     parts = len(lanes)
-    k_refs = refs[:parts]
-    v_ref, do_ref, lse_ref, delta_ref, dq_ref = refs[parts:parts + 5]
-    dk_refs = refs[parts + 5:2 * parts + 5]
-    dv_ref = refs[2 * parts + 5]
-    dq_acc = refs[2 * parts + 6:3 * parts + 6]
+    k_refs, refs = refs[:parts], list(refs[parts:])
+    v_ref, do_ref, lse_ref, delta_ref = refs[:4]
+    sel_ref = refs[4] if selected else None
+    refs = refs[4 + selected:]
+    dq_ref, dk_refs, dv_ref = refs[0], refs[1:parts + 1], refs[parts + 1]
+    dq_acc = refs[parts + 2:2 * parts + 2]
     _, c, sub = plan
     kb, qb = pl.program_id(1), pl.program_id(2)
 
@@ -782,15 +804,10 @@ def _mla_bwd_kernel(q_ref, *refs, lanes, scale, plan, n):
 
     def prep(r):
         ks = tuple(_rows(k, r, c) for k in k_refs)
-        return ks, tuple(k.T for k in ks), _rows(v_ref, r, c)
-
-    def stat_row(ref, start, size):
-        return jnp.concatenate(
-            [ref[0, 0, pl.ds((start + u) // sub, 1), :]
-             for u in range(0, size, sub)], axis=1)
+        return ks, tuple(k.T for k in ks), _rows(v_ref, r, c), r
 
     def piece(ctx, i, g, lo, hi, tri, carry):
-        ks, kts, v = ctx
+        ks, kts, v, r = ctx
         *dks, dv = carry
         q0, nq = i * c + lo, hi - lo
         qs = [q_ref[0, pl.ds(q0, nq), pl.ds(l0, w)] for l0, w in lanes]
@@ -802,10 +819,14 @@ def _mla_bwd_kernel(q_ref, *refs, lanes, scale, plan, n):
         st = st * scale                                   # [sub, nq] fp32
         if tri:
             st = jnp.where(_keep_tri(sub, nq, 0, False), st, NEG_INF)
-        pt = jnp.exp(st - stat_row(lse_ref, q0, nq))
+        if selected:
+            st = jnp.where(_selected(sel_ref, r + g, sub, q0, nq), st,
+                           NEG_INF)
+        pt = jnp.exp(st - _stat_row(lse_ref, q0, nq, sub))
         dv = dv + _dot(pt.astype(do.dtype), do, _NN)
         dst = (pt * (_dot(v[g:g + sub], do, _NT)
-                     - stat_row(delta_ref, q0, nq)) * scale).astype(do.dtype)
+                     - _stat_row(delta_ref, q0, nq, sub))
+               * scale).astype(do.dtype)
         for acc, kt in zip(dq_acc, kts):
             acc[qb, :, pl.ds(q0, nq)] += _dot(kt[:, g:g + sub], dst, _NN)
         return (*(dk + _dot(dst, q, _NN) for dk, q in zip(dks, qs)), dv)
@@ -816,7 +837,7 @@ def _mla_bwd_kernel(q_ref, *refs, lanes, scale, plan, n):
 
     _walk(plan, n, True, False, kb, qb,
           (*((0.0, w) for _, w in lanes), (0.0, v_ref.shape[-1])),
-          refs[3 * parts + 6:], prep, piece, finalize)
+          refs[2 * parts + 2:], prep, piece, finalize)
 
     @pl.when(qb == kb)
     def _diagonal():
@@ -824,14 +845,18 @@ def _mla_bwd_kernel(q_ref, *refs, lanes, scale, plan, n):
             dq_ref[0, :, pl.ds(lo, w)] = acc[kb].T.astype(dq_ref.dtype)
 
 
-def _mla_specs(plan, q3, ks, heads, out_is_q):
-    """Block specs of the latent kernels' operands on a grid (b h, out
-    block, reduce block), under the causal mask: q-side operands of the
-    width `w` (`q(w)`), k-side ones (`k(w)`), the key's parts (`ks`; a
-    shared one is read at b // heads), an output (`out(w)`), and the
-    statistics as `_specs` lays them."""
+def _parts_specs(plan, q3, ks, out_is_q, heads=1):
+    """Block specs of the latent kernels' and the fused backward's
+    operands on a grid (b h, out block, reduce block), under the causal
+    mask: q-side operands of the width `w` (`q(w)`), k-side ones (`k(w,
+    fold)`), the key's parts (`ks`), an output (`out(w)`), and the
+    statistics and the selection as `_specs` lays them (`heads` query
+    heads a row share a selection). A k-side operand with fewer heads
+    than q has is read by `fold` query heads each, at b // fold (`folds`,
+    of the key's parts: the one rotary head of a row, or a key/value head
+    of a group)."""
     block = plan.block
-    stats = _specs(plan, q3.shape[-1], True, heads, out_is_q)
+    laid = _specs(plan, q3.shape[-1], True, heads, out_is_q)
 
     def red(i, j):
         return jnp.minimum(j, i) if out_is_q else jnp.maximum(j, i)
@@ -839,22 +864,26 @@ def _mla_specs(plan, q3, ks, heads, out_is_q):
     def spec(w, index):
         return pl.BlockSpec((1, block, w), index, memory_space=pltpu.VMEM)
 
-    def out_side(w, shared=False):
-        return spec(w, lambda b, i, j: (b // heads if shared else b, i, 0))
+    def out_side(w, fold=1):
+        return spec(w, lambda b, i, j: (b if fold == 1 else b // fold, i, 0))
 
-    def red_side(w, shared=False):
-        return spec(w, lambda b, i, j: (b // heads if shared else b,
+    def red_side(w, fold=1):
+        return spec(w, lambda b, i, j: (b if fold == 1 else b // fold,
                                         red(i, j), 0))
     q_side, k_side = (out_side, red_side) if out_is_q else \
         (red_side, out_side)
-    shared = [k.shape[0] != q3.shape[0] for k in ks]
-    return dict(q=q_side, k=k_side, out=out_side, shared=shared,
-                ks=[k_side(k.shape[-1], sh) for k, sh in zip(ks, shared)],
-                stat_out=stats["stat_out"], stat_rows=stats["stat_rows"])
+    folds = [q3.shape[0] // k.shape[0] for k in ks]
+    return dict(q=q_side, k=k_side, out=out_side, folds=folds,
+                ks=[k_side(k.shape[-1], f) for k, f in zip(ks, folds)],
+                stat_out=laid["stat_out"], stat_rows=laid["stat_rows"],
+                sel=laid["sel"])
 
 
-def _mla_plan(s: int, dv: int, dtype) -> Plan:
-    """`_plan` by the VALUES' width: of the latent kernels' operands only
+def _values_plan(s: int, dv: int, dtype) -> Plan:
+    """`_plan` by the VALUES' width, of the latent kernels and of the
+    fused backward (`_fused_bwd`), which the selected family shares: there
+    scores and values are one width and this is `_plan(s, d, dtype,
+    True)`, the selected forward's. Of the latent kernels' operands only
     q and dq are wider (and 64 of their 256 lanes are padding), and what
     dq holds at that block, q and dq at 256 lanes and the key's parts, v
     and dO at 128, is 8 operand blocks of `_RESIDENT_BYTES` / 2 where the
@@ -864,12 +893,12 @@ def _mla_plan(s: int, dv: int, dtype) -> Plan:
     return _plan(s, dv, dtype, True)
 
 
-def _mla_fwd(q3, ks, v3, scale, heads):
+def _mla_fwd(q3, ks, v3, scale):
     bh, s, d = q3.shape
     dv = v3.shape[-1]
-    plan = _mla_plan(s, dv, q3.dtype)
+    plan = _values_plan(s, dv, q3.dtype)
     n = s // plan.block
-    sp = _mla_specs(plan, q3, ks, heads, out_is_q=True)
+    sp = _parts_specs(plan, q3, ks, out_is_q=True)
     carried = [pltpu.VMEM((plan.block, w), jnp.float32)
                for w in (1, 1, dv)] if n > 1 else []
     return pl.pallas_call(
@@ -888,92 +917,109 @@ def _mla_fwd(q3, ks, v3, scale, heads):
 
 
 # The most a head's whole dq may take of VMEM in float32 (a v5e core has
-# 128 MiB; at the kanana cell's 8192 tokens it takes 6 MiB).
-_MLA_DQ_BYTES = 32 << 20
+# 128 MiB; at 8192 tokens the kanana cell's takes 6 MiB, the Keye cell's 4).
+_DQ_BYTES = 32 << 20
 
 
-def _mla_bwd_vmem(s, block, parts, dv):
-    """(bytes of the dq accumulator, `vmem_limit_bytes`) of the latent
+def _fused_bwd_vmem(s, block, parts, dv, selected=False):
+    """(bytes of the dq accumulator, `vmem_limit_bytes`) of the fused
     backward, from its shapes (`parts`: the key parts' widths): the
-    accumulator (dq transposed: the widths lie along sublanes and pad to
-    8, not 128), every
-    operand and output block twice (the pipeline's two buffers) and the
-    carried dk, dv once, each counted as float32 rows of 128-lane tiles,
-    and 16 MiB for a group's scores and the compiler's own."""
+    accumulator (dq is held transposed, so the widths lie along sublanes
+    and pad to 8, not to 128 lanes), the selection's int8 block twice
+    where there is one, every operand and output block twice (the
+    pipeline's two buffers) and the carried dk, dv once, each counted as
+    float32 rows of 128-lane tiles, and 16 MiB for a group's scores and
+    the compiler's own."""
     def tiles(*widths):
         return sum(-(-w // 128) * 128 * 4 for w in widths)
     acc = s * sum(-(-w // 8) * 8 * 4 for w in parts)
     blocks = block * (2 * tiles(sum(parts), *parts, dv, dv)
                       + 2 * tiles(sum(parts), *parts, dv)
                       + tiles(*parts, dv))
-    return acc, acc + blocks + (16 << 20)
+    sel = 2 * block * block if selected else 0
+    return acc, acc + sel + blocks + (16 << 20)
 
 
-def _mla_bwd(scale, heads, res, do3):
-    q3, ks, v3, o3, lse = res
+def _fused_bwd(name, counter, scale, q3, ks, v3, o3, lse, do3, sel_t=None):
+    """dq, the dk of every key part and dv from ONE `pallas_call` named
+    `name`, which the static counter `counter` says where it is traced.
+    q3 [b h, s, d]; `ks` the key's parts along d, `v3` the values, each
+    with q's heads or fewer (`_parts_specs`); `sel_t` ([b, s_k, s_q] 0/1
+    bytes, or None): a per-pair selection, transposed. Causal."""
     bh, s, d = q3.shape
     dv = v3.shape[-1]
-    plan = _mla_plan(s, dv, q3.dtype)
+    plan = _values_plan(s, dv, q3.dtype)
     block, _, sub = plan
     n = s // block
     lanes = _part_lanes(ks)
     widths = [w for _, w in lanes]
-    acc_bytes, vmem = _mla_bwd_vmem(s, block, widths, dv)
-    if acc_bytes > _MLA_DQ_BYTES:
+    selected = sel_t is not None
+    acc_bytes, vmem = _fused_bwd_vmem(s, block, widths, dv, selected)
+    if acc_bytes > _DQ_BYTES:
         raise ValueError(
-            f"flash_attention_latent's backward keeps a head's whole dq in "
-            f"VMEM: {s} rows of {widths} lanes take {acc_bytes} bytes, "
-            f"over {_MLA_DQ_BYTES}")
-    stats.static("attn.latent.bwd_kernels", 1)
+            f"{name} keeps a head's whole dq in VMEM: {s} rows of {widths} "
+            f"lanes take {acc_bytes} bytes, over {_DQ_BYTES}")
+    stats.static(counter, 1)
     delta = jnp.sum(do3.astype(jnp.float32) * o3.astype(jnp.float32),
                     axis=-1)
     delta_rows = delta.reshape(bh, n, block // sub, sub)
     lse_rows = lse[..., 0].reshape(bh, n, block // sub, sub)
 
-    # grid (b h, k block, q block). A shared part's dk: one partial sum a
-    # query head in float32, added up over a row's heads outside (as
-    # grouped heads' dk, dv are). The k blocks of a head follow each other
-    # ("arbitrary"): the head's dq crosses them in the scratch.
-    sp = _mla_specs(plan, q3, ks, heads, out_is_q=False)
+    # grid (b h, k block, q block). A k-side operand that several query
+    # heads read (`folds`): its gradient is one partial sum a query head
+    # in float32, added up outside, which costs one pass over [b h, s, w]
+    # where a second reduce axis would cost the kernel its static walk.
+    # The k blocks of a head follow each other ("arbitrary"): the head's
+    # dq crosses them in the scratch.
+    sp = _parts_specs(plan, q3, ks, out_is_q=False,
+                    heads=bh // sel_t.shape[0] if selected else 1)
+    folds = [*sp["folds"], bh // v3.shape[0]]
     carried = [pltpu.VMEM((block, w), jnp.float32)
                for w in (*widths, dv)] if n > 1 else []
-    dq, *dks, dv_ = pl.pallas_call(
-        functools.partial(_mla_bwd_kernel, lanes=lanes, scale=scale,
-                          plan=plan, n=n),
+    dq, *dkv = pl.pallas_call(
+        functools.partial(_fused_bwd_kernel, lanes=lanes, scale=scale,
+                          plan=plan, n=n, selected=selected),
         grid=(bh, n, n),
-        in_specs=[sp["q"](d), *sp["ks"], sp["k"](dv), sp["q"](dv),
-                  sp["stat_rows"], sp["stat_rows"]],
+        in_specs=[sp["q"](d), *sp["ks"], sp["k"](dv, folds[-1]), sp["q"](dv),
+                  sp["stat_rows"], sp["stat_rows"],
+                  *([sp["sel"]] if selected else [])],
         out_specs=[sp["out"](d), *(sp["out"](w) for w in widths),
                    sp["out"](dv)],
         out_shape=[jax.ShapeDtypeStruct((bh, s, d), q3.dtype),
                    *(jax.ShapeDtypeStruct(
-                         (bh, s, w), jnp.float32 if sh else k.dtype)
-                     for k, sh, w in zip(ks, sp["shared"], widths)),
-                   jax.ShapeDtypeStruct((bh, s, dv), v3.dtype)],
+                         (bh, s, x.shape[-1]),
+                         jnp.float32 if f > 1 else x.dtype)
+                     for x, f in zip((*ks, v3), folds))],
         scratch_shapes=[*(pltpu.VMEM((n, w, block), jnp.float32)
                           for w in widths), *carried],
         interpret=_interpret(),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
             vmem_limit_bytes=vmem),
-        name=FLASH_MLA_BWD_DKV,
-    )(q3, *ks, v3, do3, lse_rows, delta_rows)
-    dks = tuple(
-        x.reshape(bh // heads, heads, s, -1).sum(1).astype(k.dtype)
-        if sh else x for x, k, sh in zip(dks, ks, sp["shared"]))
-    return dq, dks, dv_
+        name=name,
+    )(q3, *ks, v3, do3, lse_rows, delta_rows, *([sel_t] if selected else []))
+    *dks, dv_ = (
+        g.reshape(bh // f, f, s, -1).sum(1).astype(x.dtype) if f > 1 else g
+        for g, x, f in zip(dkv, (*ks, v3), folds))
+    return dq, tuple(dks), dv_
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _mla3(q3, ks, v3, scale, heads):
+def _mla_bwd(scale, res, do3):
+    q3, ks, v3, o3, lse = res
+    return _fused_bwd(FLASH_MLA_BWD_DKV, "attn.latent.bwd_kernels", scale,
+                      q3, ks, v3, o3, lse, do3)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _mla3(q3, ks, v3, scale):
     """The differentiable wrapper of the two latent kernels. q3 [b h,
     s, d]; `ks` the key's parts along d (module comment above); v3 [b h,
-    s, dv]; `heads` query heads a row."""
-    return _mla_fwd(q3, ks, v3, scale, heads)[0]
+    s, dv]."""
+    return _mla_fwd(q3, ks, v3, scale)[0]
 
 
-def _mla3_fwd(q3, ks, v3, scale, heads):
-    o, lse = _mla_fwd(q3, ks, v3, scale, heads)
+def _mla3_fwd(q3, ks, v3, scale):
+    o, lse = _mla_fwd(q3, ks, v3, scale)
     return o, (q3, ks, v3, o, lse)
 
 
@@ -1003,5 +1049,5 @@ def flash_attention_latent(query, key_nope, key_rope, value, scale=None):
         return jnp.swapaxes(x, 1, 2).reshape(-1, s, x.shape[-1])
 
     o3 = _mla3(to3(query), (to3(key_nope), to3(key_rope)), to3(value),
-               scale, h)
+               scale)
     return jnp.swapaxes(o3.reshape(b, h, s, -1), 1, 2)

@@ -43,20 +43,20 @@ GPT_OFFLOAD_OUTER = "gpt_offload_outer"
 FLASH_FWD = "flash_fwd"
 FLASH_BWD_DQ = "flash_bwd_dq"
 FLASH_BWD_DKV = "flash_bwd_dkv"
-# the same three under a per-(q, k) selection, and the indexer that makes
-# the selection: its scores, and the exact top-k over them
+# under a per-(q, k) selection: the forward and ONE backward, the dk/dv
+# walk, out of which dq comes too; and the indexer that makes the
+# selection: its scores, and the exact top-k over them
 FLASH_SEL_FWD = "flash_sel_fwd"
-FLASH_SEL_BWD_DQ = "flash_sel_bwd_dq"
 FLASH_SEL_BWD_DKV = "flash_sel_bwd_dkv"
 INDEX_SCORES = "index_scores"
 INDEX_TOPK = "index_topk"
-# the same three for latent attention: scores over a wider head (with one
+# the same two for latent attention: scores over a wider head (with one
 # rotary key head shared by every query head) than the values read
 FLASH_MLA_FWD = "flash_mla_fwd"
 FLASH_MLA_BWD_DKV = "flash_mla_bwd_dkv"
 KERNELS = (FLASH_FWD, FLASH_BWD_DQ, FLASH_BWD_DKV)
 MLA_KERNELS = (FLASH_MLA_FWD, FLASH_MLA_BWD_DKV)
-SEL_KERNELS = (FLASH_SEL_FWD, FLASH_SEL_BWD_DQ, FLASH_SEL_BWD_DKV)
+SEL_KERNELS = (FLASH_SEL_FWD, FLASH_SEL_BWD_DKV)
 # not ours to choose: the instruction the TPU compiler makes of
 # `jax.lax.ragged_dot` (the expert layer's grouped product) is a custom
 # call of this name, "%ragged-dot-none.3", forward and backward alike

@@ -308,7 +308,7 @@ class TestDispatchUnderMesh:
         from paddle_tpu.nn.functional import attention as A
         if resident:
             monkeypatch.setattr(fa, "_RESIDENT_BYTES", resident)
-            assert fa._mla_plan(1024, 16, jnp.float32).block * 8 == 1024
+            assert fa._values_plan(1024, 16, jnp.float32).block * 8 == 1024
         q, kn, kr, v, do = _latent_operands(1024, 4, 16, 8, 16, b=4)
         sh = NamedSharding(mesh, P("data", None, "model", None))
         q, kn, v, do = (jax.device_put(a, sh) for a in (q, kn, v, do))
@@ -483,6 +483,98 @@ def test_selected_kernels_carry_their_own_names():
     for name in prof.SEL_KERNELS:
         assert f"name={name}" in text or name in text, name
     assert "name=flash_fwd" not in text
+    # the forward and ONE backward: no dq kernel of its own (ISSUE 35)
+    assert text.count("pallas_call[") == len(prof.SEL_KERNELS) == 2
+    assert "flash_sel_bwd_dq" not in text and "flash_bwd" not in text
+
+
+@pytest.mark.parametrize("s,h,h_kv,d,sizes", [
+    # four blocks a head
+    (512, 2, 2, 128, {"_RESIDENT_BYTES": 64 * 1024}),
+    # six blocks, four query heads on one key/value head, a 64-wide dq
+    (768, 4, 1, 64, {"_RESIDENT_BYTES": 64 * 1024}),
+    # three blocks of two chunks of two groups: every offset of the walk
+    # (block, chunk, group) moves the rows of dq a pair adds to, and the
+    # rows and columns of the selection it reads
+    (1536, 2, 1, 128, {"_RESIDENT_BYTES": 512 * 1024, "_CHUNK": 256,
+                       "_CAUSAL_SUB": 128}),
+], ids=["s512-2over2-d128-four-blocks", "s768-4over1-d64-six-blocks",
+        "s1536-2over1-d128-blocks-chunks-groups"])
+def test_selected_backward_sums_dq_across_k_blocks(monkeypatch, s, h, h_kv,
+                                                   d, sizes):
+    """The selected backward is the dk/dv walk alone (ISSUE 35): a q
+    block's dq is added to by every k block up to its own, grid steps that
+    do not follow each other, zeroed at the head's first k block and
+    written when its diagonal k block has passed. All three gradients
+    against masked XLA attention, one query head a key/value head and
+    several; one row selects nothing but its own position, one nothing at
+    all."""
+    for name, size in sizes.items():
+        monkeypatch.setattr(fa, name, size)
+    plan = fa._plan(s, d, jnp.float32, True)
+    assert plan.block * 3 <= s, plan
+    if "_CHUNK" in sizes:
+        assert plan == (512, 256, 128)
+    rs = np.random.RandomState(s + h + d)
+    q = jnp.asarray(rs.randn(1, s, h, d) * 0.5, jnp.float32)
+    k, v = [jnp.asarray(rs.randn(1, s, h_kv, d) * 0.5, jnp.float32)
+            for _ in range(2)]
+    w = jnp.asarray(rs.randn(1, s, h, d), jnp.float32)
+    eye = jnp.eye(s, dtype=bool)
+    keep = (jnp.tril(jnp.ones((s, s), bool))
+            & jnp.asarray(rs.rand(1, s, s) < 0.3)) | eye
+    lone = plan.block + 7       # a row of the second q block
+    keep = keep.at[:, lone, :].set(eye[lone]).at[:, 5, :].set(False)
+    sel = keep.astype(jnp.int8)
+
+    def kernel(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, causal=True,
+                                       selection=sel) * w)
+
+    def plain(q, k, v):
+        return jnp.sum(_masked_xla(q, k, v, keep) * w)
+
+    got = jax.grad(kernel, (0, 1, 2))(q, k, v)
+    want = jax.grad(plain, (0, 1, 2))(q, k, v)
+    for name, a, b_ in zip(("dq", "dk", "dv"), got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_), rtol=1e-4,
+                                   atol=5e-5, err_msg=name)
+    # the lone row reads its own key with weight 1: dq = (dO v - delta) k
+    # = 0 there, and nothing of the row that selects nothing
+    assert float(jnp.abs(got[0][:, lone]).max()) < 1e-5
+    assert float(jnp.abs(got[0][:, 5]).max()) == 0.0
+
+
+def test_selected_backward_counts_itself_and_sizes_its_vmem(monkeypatch):
+    """One backward kernel under a selection, said by a static counter
+    where it is traced; the head's dq accumulator and the kernel's VMEM
+    limit follow the shapes (the Keye cell's: 4 MiB of dq, the selection's
+    1 MiB block twice), and a sequence whose dq does not fit is refused by
+    name, as the latent backward refuses it."""
+    from paddle_tpu.profiler import stats
+    acc, limit = fa._fused_bwd_vmem(8192, 1024, [128], 128, selected=True)
+    assert acc == 8192 * 128 * 4
+    assert limit == fa._fused_bwd_vmem(8192, 1024, [128], 128)[1] \
+        + 2 * 1024 * 1024
+    assert acc + (16 << 20) < limit < (32 << 20)
+    # dq is held transposed: a narrow one pads to 8 sublanes, not 128 lanes
+    assert fa._fused_bwd_vmem(1024, 1024, [64], 64)[0] == 1024 * 64 * 4
+
+    x = jnp.zeros((1, 128, 2, 64), jnp.float32)
+    sel = jnp.ones((1, 128, 128), jnp.int8)
+
+    def trace_backward():       # a new function each time: no cached trace
+        return str(jax.make_jaxpr(jax.grad(lambda q: flash_attention(
+            q, x[:, :, :1], x[:, :, :1], causal=True,
+            selection=sel).sum()))(x))
+    stats.static("attn.selected.bwd_kernels", 0)
+    assert trace_backward().count("pallas_call[") == 2
+    assert stats.REGISTRY.counter("attn.selected.bwd_kernels").value == 1
+    monkeypatch.setattr(fa, "_DQ_BYTES", 128 * 64 * 4 - 1)
+    with pytest.raises(ValueError,
+                       match="flash_sel_bwd_dkv keeps a head's whole dq in "
+                             "VMEM: 128 rows"):
+        trace_backward()
 
 
 def test_selection_with_a_kv_mask_is_refused():
@@ -490,8 +582,26 @@ def test_selection_with_a_kv_mask_is_refused():
     with pytest.raises(NotImplementedError):
         flash_attention(x, x, x, kv_mask=jnp.ones((1, 128), bool),
                         selection=jnp.ones((1, 128, 128), jnp.int8))
+    # the selected backward ends a q block's dq at its diagonal k block
+    with pytest.raises(NotImplementedError, match="causal=True"):
+        flash_attention(x, x, x, selection=jnp.ones((1, 128, 128), jnp.int8))
     with pytest.raises(ValueError):
         flash_attention(jnp.zeros((1, 128, 3, 64)), x, x)
+
+
+def test_selected_backward_refuses_a_walk_that_is_not_causal():
+    """The refusal stands where the assumption is made too: the wrapper
+    under the public op, handed a selection without the causal mask,
+    would pair a forward over every block with a backward that ends a q
+    block's dq at its diagonal."""
+    x = jnp.ones((2, 128, 64), jnp.float32)
+    sel = jnp.ones((1, 128, 128), jnp.int8)
+
+    def loss(q, causal):
+        return jnp.sum(fa._flash3(q, x, x, None, sel, causal, 0.125, 2, 1))
+    jax.grad(loss)(x, True)
+    with pytest.raises(NotImplementedError, match="causal=True"):
+        jax.grad(loss)(x, False)
 
 
 # ------------------------------------------------------ latent attention
@@ -515,7 +625,7 @@ def _latent(q, kn, kr, v, concat=False):
         return jnp.swapaxes(x, 1, 2).reshape(-1, s, x.shape[-1])
     k = jnp.concatenate(
         [kn, jnp.broadcast_to(kr, kn.shape[:-1] + kr.shape[-1:])], -1)
-    o3 = fa._mla3(to3(q), (to3(k),), to3(v), d ** -0.5, h)
+    o3 = fa._mla3(to3(q), (to3(k),), to3(v), d ** -0.5)
     return jnp.swapaxes(o3.reshape(b, h, s, -1), 1, 2)
 
 
@@ -546,7 +656,7 @@ def test_latent_kernels_match_the_xla_path(monkeypatch, s, h, dn, dr, dv,
     for name, size in sizes.items():
         monkeypatch.setattr(fa, name, size)
     if sizes:
-        plan = fa._mla_plan(s, dv, jnp.float32)
+        plan = fa._values_plan(s, dv, jnp.float32)
         assert plan.block * 3 <= s, plan
         if "_CHUNK" in sizes:
             assert plan == (512, 256, 128)
@@ -577,14 +687,14 @@ def test_latent_dq_is_summed_in_float32_and_rounded_once(monkeypatch):
     must fail the same comparison."""
     monkeypatch.setattr(fa, "_RESIDENT_BYTES", 64 * 1024)
     s, h, dn, dr, dv = 512, 2, 128, 64, 128
-    block = fa._mla_plan(s, dv, jnp.bfloat16).block
+    block = fa._values_plan(s, dv, jnp.bfloat16).block
     assert block * 4 == s
     q, kn, kr, v, do = (
         jnp.swapaxes(x, 1, 2).reshape(-1, s, x.shape[-1])
         for x in _latent_operands(s, h, dn, dr, dv, jnp.bfloat16, b=1))
     scale = (dn + dr) ** -0.5
-    o, lse = fa._mla_fwd(q, (kn, kr), v, scale, h)
-    got = fa._mla_bwd(scale, h, (q, (kn, kr), v, o, lse), do)[0]
+    o, lse = fa._mla_fwd(q, (kn, kr), v, scale)
+    got = fa._mla_bwd(scale, (q, (kn, kr), v, o, lse), do)[0]
     assert got.dtype == jnp.bfloat16
 
     f32 = functools.partial(jnp.asarray, dtype=jnp.float32)
@@ -618,12 +728,12 @@ def test_latent_backward_counts_itself_and_sizes_its_vmem(monkeypatch):
     the head's dq accumulator and the kernel's VMEM limit follow the
     shapes, and a head whose dq does not fit is refused by name."""
     from paddle_tpu.profiler import stats
-    acc, limit = fa._mla_bwd_vmem(8192, 1024, [128, 64], 128)
+    acc, limit = fa._fused_bwd_vmem(8192, 1024, [128, 64], 128)
     assert acc == 8192 * 192 * 4            # dq transposed: no lane padding
     assert acc + (16 << 20) < limit < (40 << 20)
-    assert fa._mla_bwd_vmem(8192, 1024, [192], 128)[0] == acc
-    assert fa._mla_bwd_vmem(32768, 1024, [128, 64], 128)[0] \
-        <= fa._MLA_DQ_BYTES
+    assert fa._fused_bwd_vmem(8192, 1024, [192], 128)[0] == acc
+    assert fa._fused_bwd_vmem(32768, 1024, [128, 64], 128)[0] \
+        <= fa._DQ_BYTES
 
     q, kn, kr, v, _ = _latent_operands(128, 2, 16, 8, 16)
 
@@ -633,7 +743,7 @@ def test_latent_backward_counts_itself_and_sizes_its_vmem(monkeypatch):
     stats.static("attn.latent.bwd_kernels", 0)
     trace_backward()
     assert stats.REGISTRY.counter("attn.latent.bwd_kernels").value == 1
-    monkeypatch.setattr(fa, "_MLA_DQ_BYTES", 128 * 24 * 4 - 1)
+    monkeypatch.setattr(fa, "_DQ_BYTES", 128 * 24 * 4 - 1)
     with pytest.raises(ValueError, match="whole dq in VMEM"):
         trace_backward()
 
@@ -658,7 +768,7 @@ def test_latent_kernels_in_bf16_and_by_their_own_names():
     # 512 rows resident at 8192 tokens; the latent kernels plan by the
     # values' 128 and keep 1024 (PERF.md, PR 33: 58.4 ms a layer for 68.2)
     assert fa._plan(8192, 192, jnp.bfloat16, True) == (512, 512, 256)
-    assert fa._mla_plan(8192, 128, jnp.bfloat16) == (1024, 1024, 256)
+    assert fa._values_plan(8192, 128, jnp.bfloat16) == (1024, 1024, 256)
 
 
 def test_latent_attention_off_the_kernels_and_what_it_refuses():

@@ -77,6 +77,35 @@ class TestGroupedExperts:
         return [jnp.asarray(rs.randn(*s) * 0.3, jnp.float32)
                 for s in ((held, d, f), (held, d, f), (held, f, d))]
 
+    @staticmethod
+    def _poison(monkeypatch):
+        """NaN in the rows of no group, in every grouped product forward
+        and backward: what the TPU may leave there."""
+        from paddle_tpu.distributed.meta_parallel import moe as mod
+        real = jax.lax.ragged_dot
+
+        @jax.custom_vjp
+        def poisoned(a, w, sizes):
+            out = real(a, w, sizes, preferred_element_type=jnp.float32)
+            beyond = jnp.arange(a.shape[0])[:, None] >= jnp.sum(sizes)
+            return jnp.where(beyond, jnp.nan, out)
+
+        def fwd(a, w, sizes):
+            return poisoned(a, w, sizes), (a, w, sizes)
+
+        def bwd(res, g):
+            a, w, sizes = res
+            _, vjp = jax.vjp(lambda a, w: real(
+                a, w, sizes, preferred_element_type=jnp.float32), a, w)
+            da, dw = vjp(g)
+            beyond = jnp.arange(a.shape[0])[:, None] >= jnp.sum(sizes)
+            return jnp.where(beyond, jnp.nan, da), dw, None
+        poisoned.defvjp(fwd, bwd)
+        monkeypatch.setattr(
+            mod.jax.lax, "ragged_dot",
+            lambda a, w, sizes, preferred_element_type=None:
+            poisoned(a, w, sizes))
+
     @pytest.mark.parametrize("held,offset", [(8, 0), (4, 0), (4, 2), (2, 6)])
     def test_held_share_is_the_dense_masked_sum(self, held, offset):
         rs = np.random.RandomState(2)
@@ -120,30 +149,7 @@ class TestGroupedExperts:
         group and leaves there what it finds: with NaN in those rows, in
         every product forward and backward, output and gradients are the
         dense answer still."""
-        from paddle_tpu.distributed.meta_parallel import moe as mod
-        real = jax.lax.ragged_dot
-
-        @jax.custom_vjp
-        def poisoned(a, w, sizes):
-            out = real(a, w, sizes, preferred_element_type=jnp.float32)
-            beyond = jnp.arange(a.shape[0])[:, None] >= jnp.sum(sizes)
-            return jnp.where(beyond, jnp.nan, out)
-
-        def fwd(a, w, sizes):
-            return poisoned(a, w, sizes), (a, w, sizes)
-
-        def bwd(res, g):
-            a, w, sizes = res
-            _, vjp = jax.vjp(lambda a, w: real(
-                a, w, sizes, preferred_element_type=jnp.float32), a, w)
-            da, dw = vjp(g)
-            beyond = jnp.arange(a.shape[0])[:, None] >= jnp.sum(sizes)
-            return jnp.where(beyond, jnp.nan, da), dw, None
-        poisoned.defvjp(fwd, bwd)
-        monkeypatch.setattr(
-            mod.jax.lax, "ragged_dot",
-            lambda a, w, sizes, preferred_element_type=None:
-            poisoned(a, w, sizes))
+        self._poison(monkeypatch)
         rs = np.random.RandomState(5)
         x = jnp.asarray(rs.randn(24, 16), jnp.float32)
         experts, weights, _ = topk_gating(
@@ -189,6 +195,66 @@ class TestGroupedExperts:
         for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        rtol=1e-4, atol=1e-5)
+
+    @pytest.mark.parametrize("rows,tail", [(16, 4), (24, 8), (40, 8),
+                                           (16, 16)])
+    @pytest.mark.parametrize("first", [0, 3, 17, 29, 40])
+    def test_short_rounds_behind_the_buffer_drop_nothing(
+            self, rows, tail, first, monkeypatch):
+        """`first` of 40 tokens send their first choice to held expert 1:
+        the load ends inside the buffer, just behind it (a short round or
+        two), and far behind it (whole buffers, then short rounds), with
+        NaN wherever the grouped product would leave its rows alone."""
+        from paddle_tpu.distributed.meta_parallel.moe import plan_rows
+        self._poison(monkeypatch)
+        rs = np.random.RandomState(7)
+        x = jnp.asarray(rs.randn(40, 16), jnp.float32)
+        logits = jnp.asarray(rs.randn(40, 8), jnp.float32)
+        logits = logits.at[:first, 3].set(9.0).at[:, 2].set(-9.0)
+        experts, weights, _ = topk_gating(logits, 2)
+        ws = self._weights(4)
+        total = plan_rows(80, rows, tail)
+
+        def grouped(x, weights, *ws):
+            plan = dispatch_plan(experts, 2, 4, total)
+            return jnp.sum(grouped_experts(x, plan, weights, *ws, rows=rows,
+                                           tail=tail) ** 2)
+
+        def dense(x, weights, *ws):
+            return jnp.sum(dense_moe(x, experts, weights, *ws,
+                                     offset=2) ** 2)
+        got = jax.jit(jax.value_and_grad(grouped, (0, 1, 2, 3, 4)))(
+            x, weights, *ws)
+        want = jax.value_and_grad(dense, (0, 1, 2, 3, 4))(x, weights, *ws)
+        for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-4, atol=1e-5)
+
+    @pytest.mark.parametrize("rows,tail", [(24576, 3072), (18432, 2560),
+                                           (16, 4), (16, 16), (40, 8)])
+    def test_the_schedule_covers_every_load_inside_the_plan(self, rows,
+                                                            tail):
+        """Whatever the load: the rounds tile [0, load) without a gap,
+        end inside `plan_rows` (a slice that did not would be moved back
+        without a word), and what lies behind the whole buffers takes at
+        most `TAIL_ROUNDS` short rounds."""
+        from paddle_tpu.distributed.meta_parallel import moe as mod
+        worst = 8 * rows + 5
+        total = mod.plan_rows(worst, rows, tail)
+        loads = sorted({0, 1, rows - 1, rows, rows + 1, rows + tail,
+                        rows + 2 * tail, rows + 2 * tail + 1, 2 * rows,
+                        2 * rows + 1, 3 * rows + tail - 1, worst - 1,
+                        worst} | set(range(0, worst, max(1, worst // 97))))
+        sizes = jnp.asarray(loads, jnp.int32)[:, None]
+        whole, short = jax.vmap(
+            lambda s: mod._schedule(mod.Dispatch(None, None, None, s),
+                                    rows, tail))(sizes)
+        for load, w, s in zip(loads, np.asarray(whole), np.asarray(short)):
+            s = max(int(s), 0)
+            end = (1 + w) * rows + s * tail
+            assert w >= 0 and load <= end <= total, (load, w, s)
+            assert end - load < (tail if s else rows) or load < rows
+            assert s <= max(mod.TAIL_ROUNDS, 2 * (tail == rows)), (load, s)
 
     def test_a_buffer_that_is_too_small_is_seen(self):
         experts = jnp.zeros((6, 1), jnp.int32)
@@ -278,7 +344,7 @@ class TestMoEMLP:
         assert snap["moe.rows_buffer"] == 32 * 2
         # at the cell's size: 16384 tokens, top 8 of 128, 16 held
         big = MoEMLP(16, 32, num_experts=128, top_k=8, experts_held=16)
-        assert big.rows_buffer(16384) == (24576, 6)
+        assert big.rows_buffer(16384) == (24576, 3072, 6 * 24576 + 3072)
         with pytest.raises(ValueError):
             MoEMLP(16, 32, num_experts=8, experts_held=4, expert_offset=6)
 

@@ -184,9 +184,15 @@ def test_selected_grouped_kernels_compile_at_keye_widths(one_chip):
     text = compiled_text(loss_and_grads, shape(b, s, h, d),
                          shape(b, s, h_kv, d), shape(b, s, h_kv, d),
                          shape(b, s, s, dtype=jnp.int8))
-    assert text.count("tpu_custom_call") == 3
-    for name in ("flash_sel_fwd", "flash_sel_bwd_dq", "flash_sel_bwd_dkv"):
+    # the forward and ONE backward (ISSUE 35): dq comes out of the dk/dv
+    # walk, which the compiler takes within the `vmem_limit_bytes` that
+    # `_fused_bwd` reckons from these shapes (over it, it refuses)
+    assert text.count("tpu_custom_call") == 2
+    for name in ("flash_sel_fwd", "flash_sel_bwd_dkv"):
         assert name in text, name
+    assert "flash_sel_bwd_dq" not in text
+    acc, limit = fa._fused_bwd_vmem(s, 1024, [d], d, selected=True)
+    assert acc == s * d * 4 and acc + (18 << 20) < limit < (32 << 20)
 
 
 @pytest.mark.parametrize("concat", [False, True],
@@ -206,7 +212,7 @@ def test_latent_kernels_compile_at_kanana_widths(one_chip, concat):
         def to3(x):
             return jnp.swapaxes(x, 1, 2).reshape(-1, s, x.shape[-1])
         k = jnp.concatenate([kn, jnp.broadcast_to(kr, (b, s, h, dr))], -1)
-        return fa._mla3(to3(q), (to3(k),), to3(v), (dn + dr) ** -0.5, h)
+        return fa._mla3(to3(q), (to3(k),), to3(v), (dn + dr) ** -0.5)
 
     def loss_and_grads(*args):
         latent = one_part if concat else fa.flash_attention_latent

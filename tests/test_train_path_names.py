@@ -193,10 +193,11 @@ def metric_files() -> dict:
 # instruction the compiler names for it (`jax.lax.ragged_dot`)
 NAMED = prof.KERNELS + prof.SEL_KERNELS + prof.MLA_KERNELS + (
     prof.INDEX_SCORES, prof.INDEX_TOPK, prof.RAGGED_DOT)
-# a name the accepted patterns still carry and no kernel bears: the latent
-# dq kernel went into the dk/dv walk (ISSUE 34), and `mla_attn_ms` /
-# `mla_attn_roofline` keep its alternative until a `benchmark` PR renames
-RETIRED = ("flash_mla_bwd_dq",)
+# names the accepted patterns still carry and no kernel bears: the latent
+# dq kernel went into the dk/dv walk (ISSUE 34) and the selected one after
+# it (ISSUE 35); `mla_attn_ms` / `mla_attn_roofline` and `sel_attn_ms` /
+# `sel_attn_roofline` keep the alternative until a `benchmark` PR renames
+RETIRED = ("flash_mla_bwd_dq", "flash_sel_bwd_dq")
 
 
 def test_metric_patterns_name_the_programs_kernels():

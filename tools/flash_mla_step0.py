@@ -1,26 +1,42 @@
 #!/usr/bin/env python3
-"""Step 0 of latent attention and of the kanana cell (ISSUE 33), to be run
-on the chip:
+"""Step 0 of latent attention and of the kanana cell (ISSUE 33), and of
+the fused backward kernel that the latent and the selected family share
+(ISSUES 34, 35), to be run on the chip:
 
-    python tools/flash_mla_step0.py [--kernels 1] [--step POLICY,POLICY]
-        [--balance SEED,SEED] [--out FILE]
+    python tools/flash_mla_step0.py [--kernels latent,selected]
+        [--checkout DIR] [--step POLICY,POLICY] [--balance SEED,SEED]
+        [--out FILE]
 
-1. `--kernels 1`: the latent flash kernels (`profiler.MLA_KERNELS`: since
-   ISSUE 34 the forward and ONE backward, dq out of the dk/dv walk) at
-   the cell's widths (bf16 [2, 8192, 32, 192] queries, keys as 32 x 128 +
-   ONE rotary head of 64, values 32 x 128), forward and backward, as the
-   layer scan of a step runs them: a `scan` of `--layers` calls of
-   value-and-gradient.
-   VARIANTS ranks how the kernels are handed the rotary key: read through
-   the index map `b // 32` with two score products summed in the kernel
-   and `dk_rope` added up over the heads outside ("index"), or
-   concatenated in HBM to 192 a head beforehand ("concat"); and the
-   resident block: `_mla_plan`'s, sized by the values' 128 lanes (1024
-   rows at 8192 tokens), or `_plan`'s by the scores' 192, which pad to
-   256 (512 rows: "_by_score_width"). Ms a call by the device's clock:
-   each kernel's own events and the whole program, which holds what XLA
-   does around them (the concatenation and its transpose, the sum over
-   heads). Every variant's loss and gradient norms against the first's.
+1. `--kernels latent` (or `1`): the latent flash kernels
+   (`profiler.MLA_KERNELS`: since ISSUE 34 the forward and ONE backward,
+   dq out of the dk/dv walk) at the kanana cell's widths (bf16 [2, 8192,
+   32, 192] queries, keys as 32 x 128 + ONE rotary head of 64, values 32
+   x 128); `--kernels selected`: the selected ones (`profiler.SEL_KERNELS`:
+   since ISSUE 35 likewise two) at the Keye cell's (32 query heads over
+   `--kv-heads` 4 of 128, an int8 selection a row that keeps 2048 keys of
+   a query's causal ones, 43.75 % of the causal pairs at 8192). Forward
+   and backward, as the layer scan of a step runs them: a `scan` of
+   `--layers` calls of value-and-gradient. Ms a call by the device's
+   clock: each kernel's own events and the whole program, which holds
+   what XLA does around them (a concatenation and its transpose, the sum
+   over heads, the selection's transpose). Every variant's loss and
+   gradient norms against the first's.
+   VARIANTS of the latent family rank how the kernels are handed the
+   rotary key: read through the index map `b // 32` with two score
+   products summed in the kernel and `dk_rope` added up over the heads
+   outside ("index"), or concatenated in HBM to 192 a head beforehand
+   ("concat"); and the resident block: `_values_plan`'s, sized by the
+   values' 128 lanes (1024 rows at 8192 tokens), or `_plan`'s by the
+   scores' 192, which pad to 256 (512 rows: "_by_score_width"). The
+   selected family has one row, "as_built". (Where the fused backward
+   holds the head's dq was ranked twice and is settled: transposed, `[w,
+   rows]`, with the share kT dst; `[rows, w]` with the share dstT k read
+   5 % slower at the latent widths and the same at the selected ones:
+   PERF.md, PRs 34 and 35.)
+   `--checkout DIR` reads the same table from another tree's kernels
+   (the parent's, unpacked by `git archive`): what that tree has no
+   switch for is one row, "as_built", under the kernel names it has (an
+   unfused tree's dq and dk/dv kernels each).
 2. `--step full,dots`: the cell's whole step as the benchmark builds it,
    once for each remat policy named: compiled (or refused: the
    compiler's message is the row), `--steps` steps by the host's clock
@@ -52,18 +68,10 @@ import jax
 import jax.numpy as jnp
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-if ROOT not in sys.path:
-    sys.path.insert(0, ROOT)
-
-from paddle_tpu.ops import flash_attention as fa  # noqa: E402
-from paddle_tpu.profiler import MLA_KERNELS  # noqa: E402
 
 CONFIG = "kanana-2-30b-a3b-instruct-2601.json"
 MIX = "pretrain-s8192-fresh.json"
-# (name, key concatenated in HBM, the plan by the scores' width)
-VARIANTS = [("index", False, False), ("concat", True, False),
-            ("index_by_score_width", False, True),
-            ("concat_by_score_width", True, True)]
+fa = None       # `paddle_tpu.ops.flash_attention` of the tree under test
 
 
 def log(row: dict, rows: list) -> None:
@@ -100,51 +108,107 @@ def latent(q, k_nope, k_rope, v, concat: bool):
         return jnp.swapaxes(x, 1, 2).reshape(-1, s, x.shape[-1])
     k = jnp.concatenate([k_nope, jnp.broadcast_to(
         k_rope, k_nope.shape[:-1] + k_rope.shape[-1:])], -1)
-    o3 = fa._mla3(to3(q), (to3(k),), to3(v), 1.0 / math.sqrt(d), h)
+    o3 = fa._mla3(to3(q), (to3(k),), to3(v), 1.0 / math.sqrt(d))
     return jnp.swapaxes(o3.reshape(b, h, s, -1), 1, 2)
 
 
-def kernel_table(args, rows: list) -> None:
+def plan_name() -> str:
+    """What the tree under `--checkout` calls the plan by the values'
+    width (`_mla_plan` before PR 35)."""
+    return "_values_plan" if hasattr(fa, "_values_plan") else "_mla_plan"
+
+
+def latent_family(args):
+    """(kernel names, differentiable operands' shapes, the output's,
+    further operands of a layer from a key, [(variant, attributes of `fa`
+    to set, the call)])."""
+    from paddle_tpu.profiler import MLA_KERNELS
     b, s, h = 2, args.seq, args.heads
     dn, dr, dv = args.widths
-    shapes = [(b, s, h, dn + dr), (b, s, h, dn), (b, s, 1, dr),
-              (b, s, h, dv), (b, s, h, dv)]
+    by_score_width = {plan_name(): lambda s_, dv_, dtype: fa._plan(
+        s_, dn + dr, dtype, True)}
+    index, concat = (functools.partial(latent, concat=c)
+                     for c in (False, True))
+    variants = [("index", {}, index), ("concat", {}, concat),
+                ("index_by_score_width", by_score_width, index),
+                ("concat_by_score_width", by_score_width, concat)]
+    return (MLA_KERNELS, [(b, s, h, dn + dr), (b, s, h, dn), (b, s, 1, dr),
+                          (b, s, h, dv)], (b, s, h, dv), None, variants)
+
+
+def selected_family(args):
+    from paddle_tpu.profiler import SEL_KERNELS
+    b, s, h, d = 2, args.seq, args.heads, args.widths[0]
+    topk = s // 4
+
+    def selection(key):
+        # about `topk` of a query's causal keys, its own among them
+        qi, ki = jnp.arange(s)[:, None], jnp.arange(s)[None, :]
+        u = jax.random.uniform(key, (b, s, s))
+        return ((ki == qi) | ((ki < qi) & (u * (qi + 1) < topk))).astype(
+            jnp.int8)
+
+    def call(q, k, v, sel):
+        return fa.flash_attention(q, k, v, causal=True, selection=sel)
+    return (SEL_KERNELS, [(b, s, h, d), (b, s, args.kv_heads, d),
+                          (b, s, args.kv_heads, d)], (b, s, h, d),
+            selection, [("as_built", {}, call)])
+
+
+FAMILIES = {"latent": latent_family, "selected": selected_family}
+
+
+def kernel_table(args, rows: list, family: str) -> None:
+    kernels, shapes, out_shape, further, variants = FAMILIES[family](args)
+    shapes = shapes + [out_shape]
 
     def operands(key):
-        return [jax.random.normal(k, sh, jnp.bfloat16)
-                for k, sh in zip(jax.random.split(key, 5), shapes)]
-    *stacks, dos = jax.jit(lambda keys: jax.lax.map(operands, keys))(
+        keys = jax.random.split(key, len(shapes) + 1)
+        return ([jax.random.normal(k, sh, jnp.bfloat16)
+                 for k, sh in zip(keys, shapes)],
+                further(keys[-1]) if further else ())
+    (*stacks, dos), more = jax.jit(lambda keys: jax.lax.map(operands, keys))(
         jax.random.split(jax.random.key(args.seed), args.layers))
-    mla_plan, first = fa._mla_plan, None
-    for name, concat, by_score_width in VARIANTS:
-        fa._mla_plan = (lambda s_, dv_, dtype: fa._plan(
-            s_, dn + dr, dtype, True)) if by_score_width else mla_plan
+    first = None
+    for name, patches, call in variants:
+        kept = {k: getattr(fa, k) for k in patches}
+        for k, v in patches.items():
+            setattr(fa, k, v)
+        plan = list(getattr(fa, plan_name())(args.seq, out_shape[-1],
+                                             jnp.bfloat16))
 
-        def layers(*qkv):
+        def layers(*diff):
             # the gradient is taken outside the scan, as a step takes it:
             # a backward loop of its own, and the kernels under the names
             # a step gives them
             def one(xs):
-                *a, do = xs
-                return jnp.sum(latent(*a, concat).astype(jnp.float32)
-                               * do.astype(jnp.float32))
-            return jnp.sum(jax.lax.map(one, (*qkv, dos)))
+                *a, do, extra = xs
+                return jnp.sum(call(*a, *((extra,) if further else ()))
+                               .astype(jnp.float32) * do.astype(jnp.float32))
+            return jnp.sum(jax.lax.map(one, (*diff, dos, more)))
         fn = jax.jit(lambda xs: jax.value_and_grad(
-            layers, argnums=(0, 1, 2, 3))(*xs))
+            layers, argnums=tuple(range(len(xs))))(*xs))
         t = time.perf_counter()
         try:
             out = jax.block_until_ready(fn(stacks))
             dev = device_trace(lambda: fn(stacks), args.reps)
         except Exception as e:  # a variant the compiler refuses is a row
-            log({"what": "kernels", "variant": name,
+            log({"what": "kernels", "family": family, "variant": name,
                  "error": str(e)[:300]}, rows)
             continue
+        finally:
+            for k, v in kept.items():
+                setattr(fa, k, v)
         calls = args.reps * args.layers
-        row = {"what": "kernels", "variant": name,
-               "plan": list(fa._mla_plan(s, dv, jnp.bfloat16)),
+        row = {"what": "kernels", "family": family, "variant": name,
+               "plan": plan,
                "program_ms_a_call":
                    1e3 * sum(e - s0 for s0, e, _ in dev["modules"]) / calls}
-        for kernel in MLA_KERNELS:
+        if further:
+            row["selection_keeps_of_causal"] = float(
+                jnp.sum(more[0], dtype=jnp.float32)
+                / (more[0].shape[0] * args.seq * (args.seq + 1) / 2))
+        for kernel in kernels:
             hits = [e - s0 for s0, e, n in dev["ops"]
                     if re.match(rf"%{kernel}(\.[.\w]*)? = ", n)]
             row[kernel + "_ms_a_call"] = 1e3 * sum(hits) / calls
@@ -160,7 +224,6 @@ def kernel_table(args, rows: list) -> None:
         row["compile_and_runs_s"] = time.perf_counter() - t
         log(row, rows)
         del out
-    fa._mla_plan = mla_plan
 
 
 def cell_spec(args):
@@ -278,7 +341,13 @@ def main(argv=None) -> int:
                     help="calls a program: operands, gradients and the "
                          "scan's residuals of 6 do not fit the chip")
     ap.add_argument("--reps", type=int, default=3)
-    ap.add_argument("--kernels", type=int, choices=(0, 1), default=0)
+    ap.add_argument("--kernels", default="",
+                    help="families, of " + ",".join(FAMILIES)
+                         + " (1: latent)")
+    ap.add_argument("--kv-heads", type=int, default=4,
+                    help="key/value heads of the selected family")
+    ap.add_argument("--checkout", default=ROOT,
+                    help="the tree whose kernels are read")
     ap.add_argument("--step", default="")
     ap.add_argument("--balance", default="")
     ap.add_argument("--steps", type=int, default=8)
@@ -286,6 +355,14 @@ def main(argv=None) -> int:
     ap.add_argument("--rehearse", type=int, choices=(0, 1), default=0)
     ap.add_argument("--out", default="chiprun_out/flash_mla_step0.json")
     args = ap.parse_args(argv)
+    families = [f for f in args.kernels.replace("1", "latent").split(",")
+                if f and f != "0"]
+    if set(families) - set(FAMILIES):
+        ap.error(f"--kernels: of {sorted(FAMILIES)}, got {args.kernels}")
+    global fa
+    root = os.path.abspath(args.checkout)
+    sys.path.insert(0, root)
+    from paddle_tpu.ops import flash_attention as fa
     if args.rehearse:
         from paddle_tpu.nn.functional import attention
         fa._interpret = lambda: True
@@ -296,10 +373,10 @@ def main(argv=None) -> int:
     rows = []
     log({"what": "device", "kind": jax.devices()[0].device_kind,
          "seq": args.seq, "layers": args.layers, "seed": args.seed,
-         "rehearsal": bool(args.rehearse)}, rows)
+         "checkout": root, "rehearsal": bool(args.rehearse)}, rows)
     try:
-        if args.kernels:
-            kernel_table(args, rows)
+        for family in families:
+            kernel_table(args, rows, family)
         if args.step:
             step_table(args, rows)
         if args.balance:
