@@ -48,6 +48,29 @@ every (q, k) pair a second time (640 of the pair's 1408 lanes of MXU work
 at 192-wide scores, 384 of 896 at 128). The head's whole dq waits in a
 float32 VMEM accumulator while the walk passes the head's k blocks. The
 causal and the k-side-masked family keep a dq kernel and a dk/dv kernel.
+
+Which kernels walk their scores transposed (sᵀ = k qᵀ, k along sublanes,
+q along lanes), and where the running statistics lie. Every dk/dv walk
+(`_dkv_kernel`, `_fused_bwd_kernel`) is transposed, for the plain matmuls
+above; it reads lse and delta as rows along lanes and reduces nothing.
+The forwards and dq keep q along sublanes. There a row's running max and
+sum are reductions across lanes, and `m`, `l`, `corr` were columns
+[rows, 1]: a vreg for every 8 numbers with one live lane, and a lane
+broadcast a vreg row wherever one meets the scores or the accumulator
+(`s - m`, `acc * corr`). Measured on the latent forward at 8k tokens
+(PERF.md, PR 36, ms a call): 13.80 with columns; 11.08 with the
+reductions replaced by a constant and 11.05 with no statistics at all;
+11.54 with the statistics REPLICATED over a vreg's 128 lanes, the same
+numbers, and 11.18 with lse written as rows besides; 13.14 with the
+scores transposed and the statistics as rows along lanes (10.50 with no
+statistics: the transposed walk is the faster one, but its statistics
+cost 2.4 ms however they are held or folded). The columns were the cost,
+not the reductions across lanes. So `_mla_fwd_kernel` keeps q along sublanes and holds its
+statistics [rows, 128], alike in every lane (`_STAT_LANES`,
+`_over_lanes`), and writes lse once a q block as the rows the backward
+reads. `_fwd_kernel` and `_dq_kernel` (causal, masked, selected) still
+carry columns: at 1k tokens their time goes with basic blocks, not vector
+work, and the selected forward's cell cannot show a gain yet (ROADMAP L1).
 """
 from __future__ import annotations
 
@@ -356,9 +379,11 @@ def _specs(plan, d, causal, heads, out_is_q, group=1):
     the out side and of the reduce side, the q side's statistics as
     columns (`stat_out`: the forward writes them, the causal and masked
     dq kernel reads them) or one row a group (`stat_rows`: every dk/dv
-    walk), the k-side mask one row a chunk (`mask_rows`) or as a column
-    (`mask_col`), each along the axis its side lies on, and the (out,
-    reduce) block of a per-pair selection (`sel`: [q, k] to the forward,
+    walk reads them; `stat_rows_out`: the latent forward, whose out side
+    they are, writes them), the k-side mask one row a chunk (`mask_rows`)
+    or as a column (`mask_col`), each along the axis its side lies on, and
+    the (out, reduce) block of a per-pair selection (`sel`: [q, k] to the
+    forward,
     [k, q] of the transposed operand to the fused backward, which is the
     one backward kernel that reads it), which like the mask is one per
     batch row. `kv_out` / `kv_red` are the key/value side where `group` query
@@ -387,6 +412,8 @@ def _specs(plan, d, causal, heads, out_is_q, group=1):
         stat_out=spec((1, block, LANE), lambda b, i, j: (b, i, 0)),
         stat_rows=spec((1, 1, block // sub, sub),
                        lambda *g: (g[0], red(*g), 0, 0)),
+        stat_rows_out=spec((1, 1, block // sub, sub),
+                           lambda b, i, j: (b, i, 0, 0)),
         mask_rows=spec((1, 1, block // c, c),
                        lambda *g: (g[0] // heads, red(*g), 0, 0)),
         mask_col=spec((1, block, LANE),
@@ -707,6 +734,10 @@ def flash_attention(query, key, value, causal: bool = False,
 # comes out one partial sum a query head in float32, added up outside. One
 # part of the full width is the key concatenated in HBM beforehand.
 #
+# The forward (`_mla_fwd_kernel`) holds its running max, sum and correction
+# replicated over a vreg's lanes and hands lse to the backward as rows
+# along lanes (PERF.md, PR 36).
+#
 # Two kernels, forward and backward: dq rides the dk/dv walk. A (q, k)
 # pair's scores, `exp` and dO vT are what dq and dk/dv both need, so the
 # one walk makes them once and adds the pair's share of dq beside
@@ -737,10 +768,35 @@ def _mla_scores(q_parts, k_refs, row0, rows, scale):
     return s * scale
 
 
+# The lanes a running statistic of the latent forward is replicated over:
+# a vreg's.
+_STAT_LANES = 128
+
+
+def _over_lanes(x, w):
+    """A statistic [rows, _STAT_LANES], alike in every lane, over `w`
+    lanes: whole vregs side by side, which costs nothing, where a column
+    [rows, 1] costs a lane broadcast a vreg row."""
+    reps = -(-w // x.shape[1])
+    x = jnp.tile(x, (1, reps)) if reps > 1 else x
+    return x if x.shape[1] == w else x[:, :w]
+
+
 def _mla_fwd_kernel(q_ref, *refs, lanes, scale, plan, n):
+    """The latent forward. Its running statistics `m`, `l` and the
+    correction `corr` are NOT columns [rows, 1] but [rows, _STAT_LANES],
+    alike in every lane: a column is a vreg for every 8 numbers with one
+    live lane, and each use of it against the scores or the accumulator
+    (`s - m`, `acc * corr`) is a lane broadcast a vreg row, which was a
+    sixth of this kernel's time (PERF.md, PR 36: 13.80 ms a call with
+    columns, 11.54 so, 11.18 with lse as rows too; the scores transposed,
+    with the statistics as rows along lanes, read 13.14). lse leaves as
+    the backward reads it, rows along lanes (`_specs` `stat_rows_out`),
+    transposed once a q block."""
     parts = len(lanes)
     k_refs, (v_ref, o_ref, lse_ref) = refs[:parts], refs[parts:parts + 3]
     _, c, sub = plan
+    dv = v_ref.shape[-1]
 
     def prep(r):
         return tuple(q_ref[0, pl.ds(r, c), pl.ds(lo, w)] for lo, w in lanes)
@@ -753,20 +809,23 @@ def _mla_fwd_kernel(q_ref, *refs, lanes, scale, plan, n):
             s = jnp.where(_keep_tri(sub, hi, g, True), s, NEG_INF)
         v = _rows(v_ref, j * c, hi)
         m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
+        p = jnp.exp(s - _over_lanes(m_new, hi))
         corr = jnp.exp(m - m_new)
         l = l * corr + jnp.sum(p, axis=1, keepdims=True)
-        return m_new, l, acc * corr + _dot(p.astype(v.dtype), v, _NN)
+        return m_new, l, (acc * _over_lanes(corr, dv)
+                          + _dot(p.astype(v.dtype), v, _NN))
 
     def finalize(ctx, r, carry):
         m, l, acc = carry
         l_safe = jnp.maximum(l, 1e-30)
-        o_ref[0, pl.ds(r, c), :] = (acc / l_safe).astype(o_ref.dtype)
-        lse_ref[0, pl.ds(r, c), :] = jnp.broadcast_to(
-            m + jnp.log(l_safe), (c, LANE))
+        o_ref[0, pl.ds(r, c), :] = (acc / _over_lanes(l_safe, dv)).astype(
+            o_ref.dtype)
+        lse = (m + jnp.log(l_safe)).T[0:1]                # [1, c]
+        for u in range(0, c, sub):
+            lse_ref[0, 0, pl.ds((r + u) // sub, 1), :] = lse[:, u:u + sub]
 
     _walk(plan, n, True, True, pl.program_id(1), pl.program_id(2),
-          ((NEG_INF, 1), (0.0, 1), (0.0, v_ref.shape[-1])),
+          ((NEG_INF, _STAT_LANES), (0.0, _STAT_LANES), (0.0, dv)),
           refs[parts + 3:], prep, piece, finalize)
 
 
@@ -875,8 +934,8 @@ def _parts_specs(plan, q3, ks, out_is_q, heads=1):
     folds = [q3.shape[0] // k.shape[0] for k in ks]
     return dict(q=q_side, k=k_side, out=out_side, folds=folds,
                 ks=[k_side(k.shape[-1], f) for k, f in zip(ks, folds)],
-                stat_out=laid["stat_out"], stat_rows=laid["stat_rows"],
-                sel=laid["sel"])
+                stat_rows=laid["stat_rows"],
+                stat_rows_out=laid["stat_rows_out"], sel=laid["sel"])
 
 
 def _values_plan(s: int, dv: int, dtype) -> Plan:
@@ -894,21 +953,26 @@ def _values_plan(s: int, dv: int, dtype) -> Plan:
 
 
 def _mla_fwd(q3, ks, v3, scale):
+    """o [b h, s, dv] and lse as the fused backward reads it, [b h,
+    blocks, groups a block, rows a group] (`_specs` `stat_rows`)."""
     bh, s, d = q3.shape
     dv = v3.shape[-1]
     plan = _values_plan(s, dv, q3.dtype)
     n = s // plan.block
     sp = _parts_specs(plan, q3, ks, out_is_q=True)
+    stats.static("attn.latent.fwd_stat_lanes", _STAT_LANES)
     carried = [pltpu.VMEM((plan.block, w), jnp.float32)
-               for w in (1, 1, dv)] if n > 1 else []
+               for w in (_STAT_LANES, _STAT_LANES, dv)] if n > 1 else []
     return pl.pallas_call(
         functools.partial(_mla_fwd_kernel, lanes=_part_lanes(ks),
                           scale=scale, plan=plan, n=n),
         grid=(bh, n, n),
         in_specs=[sp["q"](d), *sp["ks"], sp["k"](dv)],
-        out_specs=[sp["out"](dv), sp["stat_out"]],
+        out_specs=[sp["out"](dv), sp["stat_rows_out"]],
         out_shape=[jax.ShapeDtypeStruct((bh, s, dv), q3.dtype),
-                   jax.ShapeDtypeStruct((bh, s, LANE), jnp.float32)],
+                   jax.ShapeDtypeStruct(
+                       (bh, n, plan.block // plan.sub, plan.sub),
+                       jnp.float32)],
         scratch_shapes=carried,
         interpret=_interpret(),
         compiler_params=_COMPILER_PARAMS,
@@ -944,8 +1008,10 @@ def _fused_bwd(name, counter, scale, q3, ks, v3, o3, lse, do3, sel_t=None):
     """dq, the dk of every key part and dv from ONE `pallas_call` named
     `name`, which the static counter `counter` says where it is traced.
     q3 [b h, s, d]; `ks` the key's parts along d, `v3` the values, each
-    with q's heads or fewer (`_parts_specs`); `sel_t` ([b, s_k, s_q] 0/1
-    bytes, or None): a per-pair selection, transposed. Causal."""
+    with q's heads or fewer (`_parts_specs`); `lse` as the family's
+    forward writes it, the rows the kernel reads (`_mla_fwd`) or the
+    columns [b h, s, LANE] of `_fwd`; `sel_t` ([b, s_k, s_q] 0/1 bytes,
+    or None): a per-pair selection, transposed. Causal."""
     bh, s, d = q3.shape
     dv = v3.shape[-1]
     plan = _values_plan(s, dv, q3.dtype)
@@ -963,7 +1029,8 @@ def _fused_bwd(name, counter, scale, q3, ks, v3, o3, lse, do3, sel_t=None):
     delta = jnp.sum(do3.astype(jnp.float32) * o3.astype(jnp.float32),
                     axis=-1)
     delta_rows = delta.reshape(bh, n, block // sub, sub)
-    lse_rows = lse[..., 0].reshape(bh, n, block // sub, sub)
+    if lse.ndim == 3:       # columns of the stat-lane layout: laid out here
+        lse = lse[..., 0].reshape(bh, n, block // sub, sub)
 
     # grid (b h, k block, q block). A k-side operand that several query
     # heads read (`folds`): its gradient is one partial sum a query head
@@ -997,7 +1064,7 @@ def _fused_bwd(name, counter, scale, q3, ks, v3, o3, lse, do3, sel_t=None):
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
             vmem_limit_bytes=vmem),
         name=name,
-    )(q3, *ks, v3, do3, lse_rows, delta_rows, *([sel_t] if selected else []))
+    )(q3, *ks, v3, do3, lse, delta_rows, *([sel_t] if selected else []))
     *dks, dv_ = (
         g.reshape(bh // f, f, s, -1).sum(1).astype(x.dtype) if f > 1 else g
         for g, x, f in zip(dkv, (*ks, v3), folds))

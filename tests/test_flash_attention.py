@@ -634,6 +634,9 @@ def _latent(q, kn, kr, v, concat=False):
 @pytest.mark.parametrize("s,h,dn,dr,dv,sizes", [
     (256, 2, 128, 64, 128, {}),         # the published widths, one block
     (1024, 3, 16, 8, 16, {}),           # a whole-sequence block of one chunk
+    # one block of two chunks of two groups, values narrower than the
+    # 128 lanes the forward's statistics are replicated over
+    (512, 2, 16, 8, 16, {"_CHUNK": 256, "_CAUSAL_SUB": 128}),
     # four blocks on the grid
     (512, 2, 128, 64, 128, {"_RESIDENT_BYTES": 64 * 1024}),
     # six blocks: a q block's dq is added to by up to six k blocks, grid
@@ -643,8 +646,9 @@ def _latent(q, kn, kr, v, concat=False):
     # (block, chunk, group) moves the rows of dq a pair adds to
     (1536, 2, 16, 8, 16, {"_RESIDENT_BYTES": 512 * 1024, "_CHUNK": 256,
                           "_CAUSAL_SUB": 128}),
-], ids=["192-128-s256", "24-16-s1024", "192-128-s512-four-blocks",
-        "24-16-s768-six-blocks", "24-16-s1536-blocks-chunks-groups"])
+], ids=["192-128-s256", "24-16-s1024", "24-16-s512-chunks-groups",
+        "192-128-s512-four-blocks", "24-16-s768-six-blocks",
+        "24-16-s1536-blocks-chunks-groups"])
 def test_latent_kernels_match_the_xla_path(monkeypatch, s, h, dn, dr, dv,
                                            sizes, concat):
     """Scores over dn + dr lanes with ONE rotary key head shared by every
@@ -655,11 +659,11 @@ def test_latent_kernels_match_the_xla_path(monkeypatch, s, h, dn, dr, dv,
     from paddle_tpu.nn import functional as F
     for name, size in sizes.items():
         monkeypatch.setattr(fa, name, size)
-    if sizes:
-        plan = fa._values_plan(s, dv, jnp.float32)
+    plan = fa._values_plan(s, dv, jnp.float32)
+    if "_RESIDENT_BYTES" in sizes:
         assert plan.block * 3 <= s, plan
-        if "_CHUNK" in sizes:
-            assert plan == (512, 256, 128)
+    if "_CHUNK" in sizes:
+        assert plan == (512, 256, 128)
     q, kn, kr, v, do = _latent_operands(s, h, dn, dr, dv)
 
     def xla(*a):
@@ -677,6 +681,112 @@ def test_latent_kernels_match_the_xla_path(monkeypatch, s, h, dn, dr, dv,
         np.testing.assert_allclose(
             np.asarray(a), np.asarray(b), rtol=1e-5,
             atol=1e-5 * float(jnp.abs(b).max()), err_msg=name)
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-4),
+                                       (jnp.bfloat16, 2e-2)],
+                         ids=["float32", "bf16"])
+@pytest.mark.parametrize("s,sizes", [
+    (256, {}),                                      # one block, one chunk
+    (512, {"_CHUNK": 256, "_CAUSAL_SUB": 128}),     # one block, two chunks
+    (512, {"_RESIDENT_BYTES": 64 * 1024}),          # four k blocks a head
+], ids=["one-block", "two-chunks", "four-k-blocks"])
+def test_latent_forward_o_and_lse_wherever_a_rows_maximum_lies(
+        monkeypatch, s, sizes, dtype, tol):
+    """The forward's o and lse, as `_mla_fwd` hands them to the backward
+    (rows along lanes), against the formula written out in float32. Head
+    0's scores rise with the key's position, so every row's running
+    maximum is replaced in each chunk and block and arrives in its LAST;
+    head 1's fall, so the maximum is the first key's and every later chunk
+    is added under it; head 2 is noise."""
+    for name, size in sizes.items():
+        monkeypatch.setattr(fa, name, size)
+    h, dn, dr, dv = 3, 128, 64, 128
+    block, _, sub = fa._values_plan(s, dv, dtype)
+    assert (s // block == 4) == ("_RESIDENT_BYTES" in sizes)
+    q, kn, kr, v, _ = _latent_operands(s, h, dn, dr, dv, b=1)
+    ramp = jnp.linspace(0.0, 100.0, s)
+    q = q.at[..., 0].set(8.0)
+    kn = kn.at[:, :, 0, 0].set(ramp).at[:, :, 1, 0].set(-ramp)
+    q3, kn3, kr3, v3 = (jnp.swapaxes(x, 1, 2).reshape(-1, s, x.shape[-1])
+                        .astype(dtype) for x in (q, kn, kr, v))
+    scale = (dn + dr) ** -0.5
+    o, lse = fa._mla_fwd(q3, (kn3, kr3), v3, scale)
+    assert o.dtype == dtype and o.shape == (h, s, dv)
+    assert lse.dtype == jnp.float32
+    assert lse.shape == (h, s // block, block // sub, sub)
+
+    f32 = functools.partial(jnp.asarray, dtype=jnp.float32)
+    hi = functools.partial(jnp.einsum, precision="highest")
+    k = jnp.concatenate([f32(kn3), jnp.broadcast_to(f32(kr3), (h, s, dr))],
+                        -1)
+    scores = jnp.where(jnp.tril(jnp.ones((s, s), bool)),
+                       hi("hqd,hkd->hqk", f32(q3), k) * scale, -jnp.inf)
+    want_lse = jax.nn.logsumexp(scores, axis=-1)
+    # where the maximum lies: head 0 among a row's last keys (its last
+    # group of every plan here), head 1 among its first
+    assert (jnp.arange(s) - jnp.argmax(scores[0], -1)).max() < 64
+    assert jnp.argmax(scores[1], -1).max() < 64
+    np.testing.assert_allclose(np.asarray(lse.reshape(h, s)),
+                               np.asarray(want_lse), rtol=1e-6, atol=1e-5)
+    want = hi("hqk,hkd->hqd", jnp.exp(scores - want_lse[..., None]), f32(v3))
+    np.testing.assert_allclose(np.asarray(o, np.float32), np.asarray(want),
+                               atol=tol)
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs its equations hold (a
+    `pallas_call`'s kernel, a `cond`'s branches)."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _eqns(sub)
+
+
+@pytest.mark.parametrize("s,sizes", [
+    (1024, {}), (512, {"_RESIDENT_BYTES": 64 * 1024})],
+    ids=["one-block", "four-blocks"])
+def test_latent_forward_counts_itself_and_carries_no_columns(
+        monkeypatch, s, sizes):
+    """The statistics' layout, said by a static counter where the forward
+    is traced and seen in the traced kernel: `m`, `l` and `corr` are
+    [rows, 128], alike in every lane. A reduction's result, a column
+    [rows, 1], meets them at once (one broadcast a row group); no column
+    is broadcast over the scores or the accumulator, none goes through
+    `exp`, and none is carried between grid steps."""
+    from paddle_tpu.profiler import stats
+    for name, size in sizes.items():
+        monkeypatch.setattr(fa, name, size)
+    x = [jax.ShapeDtypeStruct((4, s, w), jnp.bfloat16)
+         for w in (192, 128, 64, 128)]
+    stats.static("attn.latent.fwd_stat_lanes", 0)
+    jaxpr = jax.make_jaxpr(lambda q, kn, kr, v: fa._mla_fwd(
+        q, (kn, kr), v, 192 ** -0.5))(*x)
+    assert stats.REGISTRY.counter("attn.latent.fwd_stat_lanes").value \
+        == fa._STAT_LANES == 128
+    block, _, sub = fa._values_plan(s, 128, jnp.bfloat16)
+    eqns = list(_eqns(jaxpr.jaxpr))
+    reduces = [e for e in eqns if e.primitive.name.startswith("reduce_")]
+    assert {e.primitive.name for e in reduces} == {"reduce_max",
+                                                   "reduce_sum"}
+    # every use of a column: the reduction's result against the statistic
+    met = {(e.primitive.name, *(v.aval.shape for v in (*e.invars,
+                                                       *e.outvars)))
+           for e in eqns
+           if any(getattr(v.aval, "shape", ())[-1:] == (1,)
+                  for v in e.invars)}
+    assert met == {("max", (sub, 128), (sub, 1), (sub, 128)),
+                   ("add", (sub, 128), (sub, 1), (sub, 128))}, met
+    exps = {e.outvars[0].aval.shape for e in eqns
+            if e.primitive.name == "exp"}
+    assert (sub, 128) in exps and all(sh[1] % 128 == 0 for sh in exps), exps
+    call, = (e for e in jaxpr.jaxpr.eqns if e.primitive.name == "pallas_call")
+    scratch = [v.aval.shape for v in call.params["jaxpr"].invars[-3:]]
+    if s > block:
+        assert scratch == [(block, 128), (block, 128), (block, 128)], scratch
 
 
 def test_latent_dq_is_summed_in_float32_and_rounded_once(monkeypatch):
@@ -702,7 +812,7 @@ def test_latent_dq_is_summed_in_float32_and_rounded_once(monkeypatch):
     k = jnp.concatenate([f32(kn), jnp.broadcast_to(f32(kr), (h, s, dr))], -1)
     scores = hi("hqd,hkd->hqk", f32(q), k) * scale
     p = jnp.where(jnp.tril(jnp.ones((s, s), bool)),
-                  jnp.exp(scores - lse[..., :1]), 0.0)
+                  jnp.exp(scores - lse.reshape(h, s, 1)), 0.0)
     delta = jnp.sum(f32(do) * f32(o), -1, keepdims=True)
     ds = f32((p * (hi("hqd,hkd->hqk", f32(do), f32(v)) - delta)
               * scale).astype(jnp.bfloat16))

@@ -1,7 +1,8 @@
 #!/usr/bin/env python3
-"""Step 0 of latent attention and of the kanana cell (ISSUE 33), and of
-the fused backward kernel that the latent and the selected family share
-(ISSUES 34, 35), to be run on the chip:
+"""Step 0 of latent attention and of the kanana cell (ISSUE 33), of the
+fused backward kernel that the latent and the selected family share
+(ISSUES 34, 35) and of the latent forward (ISSUE 36), to be run on the
+chip:
 
     python tools/flash_mla_step0.py [--kernels latent,selected]
         [--checkout DIR] [--step POLICY,POLICY] [--balance SEED,SEED]
@@ -32,7 +33,12 @@ the fused backward kernel that the latent and the selected family share
    holds the head's dq was ranked twice and is settled: transposed, `[w,
    rows]`, with the share kT dst; `[rows, w]` with the share dstT k read
    5 % slower at the latent widths and the same at the selected ones:
-   PERF.md, PRs 34 and 35.)
+   PERF.md, PRs 34 and 35. Where the forward keeps its running
+   statistics was ranked in ISSUE 36 and is settled too: replicated over
+   a vreg's 128 lanes, q along sublanes; columns `[rows, 1]` read 13.80
+   ms a call for 11.18, the scores transposed 13.14: PERF.md, PR 36. The
+   forward as it stays is every row's `flash_mla_fwd`; the parent's is
+   `--checkout <parent>`.)
    `--checkout DIR` reads the same table from another tree's kernels
    (the parent's, unpacked by `git archive`): what that tree has no
    switch for is one row, "as_built", under the kernel names it has (an
