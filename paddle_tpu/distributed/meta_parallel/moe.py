@@ -49,7 +49,7 @@ import jax.numpy as jnp
 from ...nn import functional as F
 from ...nn import initializer as I
 from ...nn.layer import Layer
-from ...profiler import MOE_EXPERTS, MOE_ROUTE, MOE_SHARED, stats
+from ...profiler import MOE_EXPERTS, MOE_ROUTE, MOE_SHARED, count, stats
 from .mp_layers import ColumnParallelLinear, RowParallelLinear
 
 # the rounds behind the first buffer: a short one takes 1 / TAIL_SHARE of
@@ -192,6 +192,23 @@ def _schedule(plan: Dispatch, rows: int, tail: int):
     behind = jnp.maximum(jnp.sum(plan.sizes) - rows, 0)
     whole = jnp.maximum(behind + rows - 1 - TAIL_ROUNDS * tail, 0) // rows
     return whole, (behind - whole * rows + tail - 1) // tail
+
+
+def round_counts(plan: Dispatch, rows: int, tail: int) -> dict:
+    """What a call of `grouped_experts` does, as int32 values: `routed`,
+    the assignments that fell on the held experts; `computed`, the rows
+    its rounds gathered; `whole` and `short`, the rounds behind the first
+    buffer (none where the plan is one buffer)."""
+    routed = jnp.sum(plan.sizes)
+    if rows == plan.token.shape[0]:
+        whole = short = jnp.zeros((), jnp.int32)
+    else:
+        # a whole buffer that holds all that is left leaves `_schedule` a
+        # negative count of short rounds, which its loop runs no times
+        whole, short = _schedule(plan, rows, tail)
+        short = jnp.maximum(short, 0)
+    return {"routed": routed, "computed": (1 + whole) * rows + short * tail,
+            "whole": whole, "short": short}
 
 
 def plan_rows(worst: int, rows: int, tail: int) -> int:
@@ -393,6 +410,8 @@ class MoEMLP(Layer):
         stats.static("moe.experts_held", self.experts_held)
         stats.static("moe.rows_buffer", rows)
         stats.static("moe.top_k", self.top_k)
+        for name, value in round_counts(plan, rows, tail).items():
+            count("moe." + name, value)
         with jax.named_scope(MOE_EXPERTS):
             y = grouped_experts(xt, plan, weights, jnp.asarray(self.w_gate),
                                 jnp.asarray(self.w_up),

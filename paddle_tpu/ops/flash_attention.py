@@ -86,7 +86,6 @@ from jax.experimental.pallas import tpu as pltpu
 from ..profiler import (FLASH_BWD_DKV, FLASH_BWD_DQ, FLASH_FWD,
                         FLASH_MLA_BWD_DKV, FLASH_MLA_FWD, FLASH_SEL_BWD_DKV,
                         FLASH_SEL_FWD)
-from ..profiler import stats
 
 # Row statistics (lse/delta) ride an 8-lane broadcast: TPU block layouts
 # need the last two dims (sublane, lane) to divide (8, 128) or equal the
@@ -560,7 +559,7 @@ def _bwd_impl(causal, scale, res, g, mask3=None, heads=1, sel=None,
                 "a query block's dq at its diagonal key block")
         # the selection arrives transposed, [k, q], like the scores
         dq, (dk,), dv = _fused_bwd(
-            FLASH_SEL_BWD_DKV, "attn.selected.bwd_kernels", scale, q3,
+            FLASH_SEL_BWD_DKV, scale, q3,
             (k3,), v3, o3, lse, g, sel_t=jnp.swapaxes(sel, 1, 2))
         return dq, dk, dv
     bh, s, d = q3.shape
@@ -960,7 +959,6 @@ def _mla_fwd(q3, ks, v3, scale):
     plan = _values_plan(s, dv, q3.dtype)
     n = s // plan.block
     sp = _parts_specs(plan, q3, ks, out_is_q=True)
-    stats.static("attn.latent.fwd_stat_lanes", _STAT_LANES)
     carried = [pltpu.VMEM((plan.block, w), jnp.float32)
                for w in (_STAT_LANES, _STAT_LANES, dv)] if n > 1 else []
     return pl.pallas_call(
@@ -1004,14 +1002,13 @@ def _fused_bwd_vmem(s, block, parts, dv, selected=False):
     return acc, acc + sel + blocks + (16 << 20)
 
 
-def _fused_bwd(name, counter, scale, q3, ks, v3, o3, lse, do3, sel_t=None):
+def _fused_bwd(name, scale, q3, ks, v3, o3, lse, do3, sel_t=None):
     """dq, the dk of every key part and dv from ONE `pallas_call` named
-    `name`, which the static counter `counter` says where it is traced.
-    q3 [b h, s, d]; `ks` the key's parts along d, `v3` the values, each
-    with q's heads or fewer (`_parts_specs`); `lse` as the family's
-    forward writes it, the rows the kernel reads (`_mla_fwd`) or the
-    columns [b h, s, LANE] of `_fwd`; `sel_t` ([b, s_k, s_q] 0/1 bytes,
-    or None): a per-pair selection, transposed. Causal."""
+    `name`. q3 [b h, s, d]; `ks` the key's parts along d, `v3` the
+    values, each with q's heads or fewer (`_parts_specs`); `lse` as the
+    family's forward writes it, the rows the kernel reads (`_mla_fwd`)
+    or the columns [b h, s, LANE] of `_fwd`; `sel_t` ([b, s_k, s_q] 0/1
+    bytes, or None): a per-pair selection, transposed. Causal."""
     bh, s, d = q3.shape
     dv = v3.shape[-1]
     plan = _values_plan(s, dv, q3.dtype)
@@ -1025,7 +1022,6 @@ def _fused_bwd(name, counter, scale, q3, ks, v3, o3, lse, do3, sel_t=None):
         raise ValueError(
             f"{name} keeps a head's whole dq in VMEM: {s} rows of {widths} "
             f"lanes take {acc_bytes} bytes, over {_DQ_BYTES}")
-    stats.static(counter, 1)
     delta = jnp.sum(do3.astype(jnp.float32) * o3.astype(jnp.float32),
                     axis=-1)
     delta_rows = delta.reshape(bh, n, block // sub, sub)
@@ -1073,8 +1069,7 @@ def _fused_bwd(name, counter, scale, q3, ks, v3, o3, lse, do3, sel_t=None):
 
 def _mla_bwd(scale, res, do3):
     q3, ks, v3, o3, lse = res
-    return _fused_bwd(FLASH_MLA_BWD_DKV, "attn.latent.bwd_kernels", scale,
-                      q3, ks, v3, o3, lse, do3)
+    return _fused_bwd(FLASH_MLA_BWD_DKV, scale, q3, ks, v3, o3, lse, do3)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))

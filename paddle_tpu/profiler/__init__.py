@@ -12,6 +12,13 @@ parity). Device-side timing comes from `jax.profiler` (XLA's tracer
 replaces the reference's CUPTI `DeviceTracer`,
 `platform/device_tracer.h:43`).
 
+Step records: a jitted step's blocks may hand out traced counters
+(`count`, collected per block by `counting()` while the step builder
+traces it), and the step's callable keeps what each call returned, with
+its host dispatch on the same `time.perf_counter_ns`, in a bounded record
+(`step_records()`). The counters, packed into one array, are copied to
+the host asynchronously: keeping them never waits on the device.
+
 Device names: the constants below are the names of the step programs, of
 the Pallas kernels and of the `jax.named_scope`s on the training path.
 They end up in every HLO instruction's `op_name`, and a kernel's name is
@@ -21,13 +28,18 @@ readers match (`benchmarks/metrics/*.json`;
 """
 from __future__ import annotations
 
+import collections
 import contextlib
 import functools
 import itertools
+import math
 import threading
 import time
 from typing import NamedTuple, Optional
 
+import jax
+import jax.numpy as jnp
+import numpy as np
 from jax.profiler import TraceAnnotation
 
 from ..core import native
@@ -239,9 +251,99 @@ def event_count() -> int:
         else 0
 
 
+class Counters:
+    """A step's counters `{name: int32 [blocks]}` packed into ONE int32
+    array (`pack`, in the traced step), so that keeping them costs one
+    copy to the host a step; `unpack` gives them back by name. A pytree
+    whose one leaf is the packed array and whose layout is static."""
+
+    def __init__(self, packed, layout):
+        self.packed, self.layout = packed, layout
+
+    @classmethod
+    def pack(cls, counters: dict) -> "Counters":
+        names = sorted(counters)
+        return cls(jnp.concatenate([jnp.ravel(counters[n]) for n in names]),
+                   tuple((n, tuple(counters[n].shape)) for n in names))
+
+    def unpack(self) -> dict:
+        flat, out, at = np.asarray(self.packed), {}, 0
+        for name, shape in self.layout:
+            size = math.prod(shape)
+            out[name], at = flat[at:at + size].reshape(shape), at + size
+        return out
+
+
+jax.tree_util.register_pytree_node(
+    Counters, lambda c: ((c.packed,), c.layout),
+    lambda layout, leaves: Counters(leaves[0], layout))
+
+
+class StepRecord(NamedTuple):
+    """One call of a step: its index among the calls of that step
+    callable, its host dispatch on `time.perf_counter_ns`, and the
+    counters its blocks handed out (`Counters`; in `step_records()`
+    `{name: int32 [blocks]}`, empty where no block counts)."""
+    step: int
+    begin_ns: int
+    end_ns: int
+    counters: object
+
+
+# a few minutes of steps at any step time; the oldest go first
+MAX_STEP_RECORDS = 512
+_steps: collections.deque = collections.deque(maxlen=MAX_STEP_RECORDS)
+
+
+class _Counting(threading.local):
+    """The counters of the block being traced on this thread, or None."""
+
+    def __init__(self):
+        self.open = None
+
+
+_counting = _Counting()
+
+
+@contextlib.contextmanager
+def counting():
+    """Collect what `count` is given while the block inside is traced:
+    yields the dict `{name: traced value}` it fills."""
+    outer, _counting.open = _counting.open, {}
+    try:
+        yield _counting.open
+    finally:
+        _counting.open = outer
+
+
+def count(name: str, value) -> None:
+    """Add a traced value to the counter `name` of the block being traced
+    under `counting()`; anywhere else, nothing."""
+    got = _counting.open
+    if got is not None:
+        got[name] = got[name] + value if name in got else value
+
+
+def record_step(step: int, begin_ns: int, end_ns: int, counters) -> None:
+    """Keep one call of a step; its `Counters` (or `{}`: none) start
+    their way to the host now and are not waited for."""
+    if counters:
+        counters.packed.copy_to_host_async()
+    _steps.append(StepRecord(step, begin_ns, end_ns, counters))
+
+
+def step_records() -> list:
+    """The kept calls, oldest first, their counters by name as numpy
+    arrays."""
+    return [r._replace(counters=r.counters.unpack() if r.counters else {})
+            for r in list(_steps)]
+
+
 def reset():
-    """Forget the spans kept in memory and clear the native ring."""
+    """Forget the spans and step records kept in memory and clear the
+    native ring."""
     del _spans[:]
+    _steps.clear()
     if native.available():
         native.lib().ptpu_profiler_clear()
 

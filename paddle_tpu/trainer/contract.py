@@ -21,7 +21,10 @@ alike). It gives the builder:
   * optionally `step_name`, the compiled step's module name.
 
 The builder reads nothing else of a model, and no model file knows the
-builder.
+builder. What a block gives back beside its output are counters: traced
+int32 values it hands to `profiler.count` (the expert layer's rows and
+rounds, `moe.py MoEMLP.forward`), which the trunk stacks by block and the
+step returns beside the loss, for `profiler.step_records()`.
 """
 from __future__ import annotations
 

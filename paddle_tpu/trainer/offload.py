@@ -118,7 +118,8 @@ def _programs(optimizer, layout, loss_and_grads, batch_sharding, grads_sh,
     g_outer_sh, g_stacked_sh = grads_sh
 
     def gpt_offload_grad(params_pair, opt_step, batch, rng=None):
-        loss, grads = loss_and_grads(params_pair, batch, rng)
+        # the blocks' counters are not handed out by this path
+        (loss, _), grads = loss_and_grads(params_pair, batch, rng)
         flat_g = layout.grads_as_slots(flatten(*grads))
         if optimizer._grad_clip is not None:
             # global-norm clip sees the FULL grad set here; the per-chunk

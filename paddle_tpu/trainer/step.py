@@ -2,6 +2,8 @@
 compiled hybrid-parallel training step and its state."""
 from __future__ import annotations
 
+import itertools
+import time
 import warnings
 
 import jax
@@ -12,7 +14,8 @@ from ..distributed.meta_parallel.stacked_pipeline import one_f_one_b
 from ..distributed.topology import mesh_scope
 from ..framework.random import next_key, rng_guard
 from ..nn.layer import Layer, swap_state
-from ..profiler import EMBED, GPT_TRAIN_STEP, LM_LOSS, RecordEvent
+from ..profiler import (EMBED, GPT_TRAIN_STEP, LM_LOSS, Counters,
+                        RecordEvent, record_step)
 from .contract import block_groups, check_model
 from .offload import build_offload_step
 from .state import Layout, flatten, init_opt_state, stack_params, unflatten
@@ -72,6 +75,8 @@ class _Forward:
         return tot / (b * s)
 
     def loss(self, params, batch):
+        """`(loss, counters)`: what the trunk's blocks counted rides
+        beside the loss."""
         outer_p, stacked_p = params
         input_ids, labels, pos_ids = self.sp_layout(*batch)
         # with dropout, one base key from the ambient rng_guard scope
@@ -80,9 +85,9 @@ class _Forward:
         # identical masks (exact loss parity between schedules)
         base = next_key() if self.model.config.dropout > 0.0 else None
         x = self.embed(outer_p, input_ids, pos_ids, base)
-        x = self.trunk(stacked_p, x, None if base is None
-                       else jax.random.fold_in(base, 1))
-        return self.head(outer_p, x, labels)
+        x, counters = self.trunk(stacked_p, x, None if base is None
+                                 else jax.random.fold_in(base, 1))
+        return self.head(outer_p, x, labels), counters
 
     def value_and_grad_1f1b(self, params, batch, rng):
         """Loss + grads via the 1F1B schedule (SectionWorker mode 1,
@@ -121,9 +126,10 @@ class _Forward:
         (g_outer_embed,) = embed_vjp(dx)
         grads = (jax.tree.map(jnp.add, g_outer_head, g_outer_embed),
                  trunk.from_staged(g_staged))
-        return loss_sum / M, grads
+        return (loss_sum / M, {}), grads
 
     def loss_and_grads(self, params, batch, rng):
+        """`((loss, counters), grads)` of `params` on `batch`."""
         # all model code of the step (plain and offloaded) is traced in
         # here: it shards for THIS step's mesh, not for whatever mesh is
         # the global one by the time of the first call
@@ -137,7 +143,7 @@ class _Forward:
                 # bake one constant mask into the compiled program)
                 with keyed(rng):
                     return self.loss(params_, batch_)
-            return jax.value_and_grad(loss)(params, batch)
+            return jax.value_and_grad(loss, has_aux=True)(params, batch)
 
 
 @RecordEvent("build_train_step")   # one frame more: warnings below say 3
@@ -176,7 +182,8 @@ def build_train_step(model: Layer, optimizer, mesh,
     opt_state) and step_fn(state, batch) -> (state, loss);
     batch = (input_ids, labels) int32 [B, S]. When cfg.dropout > 0 the
     signature is step_fn(state, batch, rng_key) — pass a fresh key per
-    step.
+    step. Every call is kept in `profiler.step_records()` with what the
+    blocks counted (`recorded`; the expert layers' rows and rounds).
 
     offload=True keeps the optimizer slots (Adam m/v, master weights) at
     rest in HOST memory (`memory_kind="pinned_host"`): the step streams
@@ -249,12 +256,15 @@ def build_train_step(model: Layer, optimizer, mesh,
     def train_step(state, batch, rng=None):
         require_key(cfg.dropout, rng)
         outer_p, stacked_p, opt_state = state
-        loss, grads = forward.loss_and_grads((outer_p, stacked_p), batch,
-                                             rng)
+        (loss, counters), grads = forward.loss_and_grads(
+            (outer_p, stacked_p), batch, rng)
         new_flat, new_opt = optimizer.apply(
             flatten(outer_p, stacked_p),
             layout.grads_as_slots(flatten(*grads)), opt_state)
-        return (*unflatten(new_flat), new_opt), loss
+        # one array, one copy to the host; a model that counts nothing
+        # returns nothing more than it did
+        return (*unflatten(new_flat), new_opt), loss, \
+            Counters.pack(counters) if counters else {}
 
     # the jitted function's name is the compiled module's ("jit_<name>"),
     # which is how a trace or a compile log tells the step program from
@@ -267,6 +277,27 @@ def build_train_step(model: Layer, optimizer, mesh,
         train_step,
         in_shardings=(shardings, batch_sharding)
         + ((None,) if cfg.dropout > 0.0 else ()),
-        out_shardings=(shardings, None),
+        out_shardings=(shardings, None, None),
         donate_argnums=(0,) if donate else ())
-    return step, layout.place(state, shardings)
+    return recorded(step), layout.place(state, shardings)
+
+
+def recorded(jitted):
+    """`step(state, batch[, rng]) -> (state, loss)` around the jitted
+    step, which also returns what its blocks counted: each call is kept
+    in `profiler.step_records()` with its host dispatch, and lies on a
+    trace's host plane as a `StepTraceAnnotation` of its number. Nothing
+    here waits on the device. `lower` is the jitted step's."""
+    calls = itertools.count()
+
+    def step(state, batch, *rng):
+        n = next(calls)
+        with jax.profiler.StepTraceAnnotation(jitted.__name__, step_num=n):
+            begin = time.perf_counter_ns()
+            state, loss, counters = jitted(state, batch, *rng)
+            end = time.perf_counter_ns()
+        if not isinstance(loss, jax.core.Tracer):   # not inside a trace
+            record_step(n, begin, end, counters)
+        return state, loss
+    step.lower = jitted.lower
+    return step

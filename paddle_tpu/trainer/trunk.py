@@ -3,6 +3,8 @@ as its template block under a scan over the group's stacked leaves, one
 group after the other, over two streams of the batch where a 'model' axis
 has sums to hide, or (one group only) under a pipeline schedule where the
 mesh has a 'pipe' axis; and the sequence-parallel layout of a batch.
+What the blocks count (`profiler.count`) comes out of the scans stacked
+by block, outside a pipeline schedule.
 """
 from __future__ import annotations
 
@@ -19,7 +21,7 @@ from ..distributed.meta_parallel.sequence_parallel import (
 from ..distributed.meta_parallel.stacked_pipeline import pipelined_apply
 from ..framework.random import rng_guard
 from ..nn.layer import Layer, functional_call
-from ..profiler import DECODER, stats
+from ..profiler import DECODER, counting, stats
 from .contract import group_keys
 from .state import of_group
 
@@ -101,7 +103,10 @@ def sequence_parallel(mesh, mode: str, zigzag: bool
 
 
 class Trunk:
-    """`trunk(stacked_p, x, key=None)`: the blocks, by the mesh's axes.
+    """`trunk(stacked_p, x, key=None) -> (h, counters)`: the blocks, by
+    the mesh's axes, and what they counted, `{<group key><name>: [blocks]}`
+    (`contract.group_keys`; summed over the streams; empty where no block
+    counts, and under a pipeline schedule, which hands out none).
 
     `groups` is the model's `[(template, blocks), ...]`: every group is a
     scan of its own over its own leaves, under the one remat policy, the
@@ -146,16 +151,21 @@ class Trunk:
         # the thread-local scope (leak otherwise)
         template._sp_attention = self._sp_attention
         try:
-            with keyed(key):
+            with keyed(key), counting() as counted:
                 out, _ = functional_call(template, bparams, x)
         finally:
             template._sp_attention = None
-        return out
+        return out, counted
+
+    def stage_blocks(self, stage_p, h, key=None, group: int = 0):
+        """`scan_blocks` without the counters: a pipeline stage (shared by
+        the gpipe and 1f1b schedules)."""
+        return self.scan_blocks(stage_p, h, key, group)[0]
 
     @jax.named_scope(DECODER)
-    def stage_blocks(self, stage_p, h, key=None, group: int = 0):
+    def scan_blocks(self, stage_p, h, key=None, group: int = 0):
         """One group's blocks, or one pipeline stage of them = scan over
-        its L/pp blocks (shared by the gpipe and 1f1b schedules). `key`
+        its L/pp blocks, and their counters stacked by block. `key`
         (when dropout > 0) is split into one sub-key per block, and a
         block's into one per stream, so masks decorrelate across layers —
         a closure draw would bake a single mask into the scanned body. `h` is the batch, or a tuple of
@@ -171,13 +181,13 @@ class Trunk:
         def body(carry, xs):
             bp, k = xs
             if not isinstance(carry, tuple):
-                return block(bp, carry, k), None
+                return block(bp, carry, k)
             ks = (None,) * len(carry) if k is None else \
                 jax.random.split(k, len(carry))
-            return tuple(block(bp, c, ki)
-                         for c, ki in zip(carry, ks)), None
-        out, _ = jax.lax.scan(body, h, (stage_p, keys))
-        return out
+            outs, counted = zip(*(block(bp, c, ki)
+                                  for c, ki in zip(carry, ks)))
+            return outs, jax.tree.map(lambda *v: sum(v), *counted)
+        return jax.lax.scan(body, h, (stage_p, keys))
 
     def streams(self, x):
         """x [B, ...] as the streams the blocks are applied to: the two
@@ -221,23 +231,26 @@ class Trunk:
 
     def all_groups(self, stacked_p, h, key=None):
         """Every group's blocks in order, each a scan over its own
-        leaves; with dropout a key a group."""
+        leaves; with dropout a key a group. Returns `(h, counters)`."""
         stats.static("trunk.groups", len(self.groups))
         for g, (_, blocks) in enumerate(self.groups):
             stats.static(f"trunk.groups.g{g}", blocks)
         if len(self.groups) == 1:
-            return self.stage_blocks(stacked_p, h, key)
+            return self.scan_blocks(stacked_p, h, key)
         keys = [None] * len(self.groups) if key is None else \
             jax.random.split(key, len(self.groups))
+        counters = {}
         for g, name in enumerate(group_keys(self.groups)):
-            h = self.stage_blocks(of_group(stacked_p, name), h, keys[g], g)
-        return h
+            h, counted = self.scan_blocks(of_group(stacked_p, name), h,
+                                          keys[g], g)
+            counters.update({name + k: v for k, v in counted.items()})
+        return h, counters
 
     def __call__(self, stacked_p, x, key=None):
         if self.pp == 1:
-            return self.join(self.all_groups(stacked_p, self.streams(x),
-                                             key))
+            h, counters = self.all_groups(stacked_p, self.streams(x), key)
+            return self.join(h), counters
         return pipelined_apply(self.stage_blocks, self.to_staged(stacked_p),
                                x, num_stages=self.pp,
                                num_microbatches=self.microbatches,
-                               remat=False, rng_key=key)
+                               remat=False, rng_key=key), {}

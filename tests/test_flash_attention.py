@@ -546,12 +546,11 @@ def test_selected_backward_sums_dq_across_k_blocks(monkeypatch, s, h, h_kv,
 
 
 def test_selected_backward_counts_itself_and_sizes_its_vmem(monkeypatch):
-    """One backward kernel under a selection, said by a static counter
-    where it is traced; the head's dq accumulator and the kernel's VMEM
+    """One backward kernel under a selection, said by the `pallas_call[`
+    count of its trace; the head's dq accumulator and the kernel's VMEM
     limit follow the shapes (the Keye cell's: 4 MiB of dq, the selection's
     1 MiB block twice), and a sequence whose dq does not fit is refused by
     name, as the latent backward refuses it."""
-    from paddle_tpu.profiler import stats
     acc, limit = fa._fused_bwd_vmem(8192, 1024, [128], 128, selected=True)
     assert acc == 8192 * 128 * 4
     assert limit == fa._fused_bwd_vmem(8192, 1024, [128], 128)[1] \
@@ -567,9 +566,7 @@ def test_selected_backward_counts_itself_and_sizes_its_vmem(monkeypatch):
         return str(jax.make_jaxpr(jax.grad(lambda q: flash_attention(
             q, x[:, :, :1], x[:, :, :1], causal=True,
             selection=sel).sum()))(x))
-    stats.static("attn.selected.bwd_kernels", 0)
     assert trace_backward().count("pallas_call[") == 2
-    assert stats.REGISTRY.counter("attn.selected.bwd_kernels").value == 1
     monkeypatch.setattr(fa, "_DQ_BYTES", 128 * 64 * 4 - 1)
     with pytest.raises(ValueError,
                        match="flash_sel_bwd_dkv keeps a head's whole dq in "
@@ -751,22 +748,18 @@ def _eqns(jaxpr):
     ids=["one-block", "four-blocks"])
 def test_latent_forward_counts_itself_and_carries_no_columns(
         monkeypatch, s, sizes):
-    """The statistics' layout, said by a static counter where the forward
-    is traced and seen in the traced kernel: `m`, `l` and `corr` are
-    [rows, 128], alike in every lane. A reduction's result, a column
-    [rows, 1], meets them at once (one broadcast a row group); no column
-    is broadcast over the scores or the accumulator, none goes through
-    `exp`, and none is carried between grid steps."""
-    from paddle_tpu.profiler import stats
+    """The statistics' layout, seen in the traced kernel: `m`, `l` and
+    `corr` are [rows, 128], alike in every lane. A reduction's result, a
+    column [rows, 1], meets them at once (one broadcast a row group); no
+    column is broadcast over the scores or the accumulator, none goes
+    through `exp`, and none is carried between grid steps."""
     for name, size in sizes.items():
         monkeypatch.setattr(fa, name, size)
     x = [jax.ShapeDtypeStruct((4, s, w), jnp.bfloat16)
          for w in (192, 128, 64, 128)]
-    stats.static("attn.latent.fwd_stat_lanes", 0)
     jaxpr = jax.make_jaxpr(lambda q, kn, kr, v: fa._mla_fwd(
         q, (kn, kr), v, 192 ** -0.5))(*x)
-    assert stats.REGISTRY.counter("attn.latent.fwd_stat_lanes").value \
-        == fa._STAT_LANES == 128
+    assert fa._STAT_LANES == 128
     block, _, sub = fa._values_plan(s, 128, jnp.bfloat16)
     eqns = list(_eqns(jaxpr.jaxpr))
     reduces = [e for e in eqns if e.primitive.name.startswith("reduce_")]
@@ -834,10 +827,9 @@ def test_latent_dq_is_summed_in_float32_and_rounded_once(monkeypatch):
 
 
 def test_latent_backward_counts_itself_and_sizes_its_vmem(monkeypatch):
-    """One backward kernel, said by a static counter where it is traced;
-    the head's dq accumulator and the kernel's VMEM limit follow the
+    """One backward kernel, said by the `pallas_call[` count of its
+    trace; the head's dq accumulator and the kernel's VMEM limit follow the
     shapes, and a head whose dq does not fit is refused by name."""
-    from paddle_tpu.profiler import stats
     acc, limit = fa._fused_bwd_vmem(8192, 1024, [128, 64], 128)
     assert acc == 8192 * 192 * 4            # dq transposed: no lane padding
     assert acc + (16 << 20) < limit < (40 << 20)
@@ -850,9 +842,9 @@ def test_latent_backward_counts_itself_and_sizes_its_vmem(monkeypatch):
     def trace_backward():       # a new function each time: no cached trace
         jax.make_jaxpr(jax.grad(
             lambda *a: fa.flash_attention_latent(*a).sum()))(q, kn, kr, v)
-    stats.static("attn.latent.bwd_kernels", 0)
-    trace_backward()
-    assert stats.REGISTRY.counter("attn.latent.bwd_kernels").value == 1
+    assert str(jax.make_jaxpr(jax.grad(
+        lambda *a: fa.flash_attention_latent(*a).sum()))(q, kn, kr, v)
+    ).count("pallas_call[") == 2
     monkeypatch.setattr(fa, "_DQ_BYTES", 128 * 24 * 4 - 1)
     with pytest.raises(ValueError, match="whole dq in VMEM"):
         trace_backward()
