@@ -144,9 +144,11 @@ class DeepseekV3Attention(Layer):
         def rotary(x):
             return F.rotary_embedding(x, cfg.rope_theta,
                                       interleaved=cfg.rope_interleave)
-        q = jnp.concatenate([q[..., :dn], rotary(q[..., dn:])], -1)
-        o = F.latent_attention(q, kv[..., :dn], rotary(k_rope),
-                               kv[..., dn:])
+        # the attention reads the projections' arrays where they lie: kv
+        # whole, the rotary query part head-major as its fusion writes it
+        o = F.latent_attention(q[..., :dn],
+                               jnp.swapaxes(rotary(q[..., dn:]), 1, 2), kv,
+                               rotary(k_rope))
         return self.o_proj(o.reshape(b, s, -1))
 
 

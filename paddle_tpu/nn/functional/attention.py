@@ -235,17 +235,19 @@ def selected_attention(query, key, value, selection, scale=None):
 
 
 @jax.named_scope(ATTENTION)
-def latent_attention(query, key_nope, key_rope, value, scale=None):
-    """Causal latent attention (DeepSeek's MLA as it trains): query [b, s,
-    h, dn + dr] scores against key_nope [b, s, h, dn] on its first dn
-    lanes and against key_rope [b, s, 1, dr], ONE rotary head that all
-    query heads read, on the rest; value [b, s, h, dv]. The softmax runs
-    in float32 over (q_nope . k_nope + q_rope . k_rope) x scale (default
-    1 / sqrt(dn + dr)). Returns [b, s, h, dv]."""
-    if flag("enable_pallas_kernels") and _pallas_ok(query, query, True):
-        shards = _mesh_shards(query)
+def latent_attention(q_nope, q_rope, kv, k_rope, scale=None):
+    """Causal latent attention (DeepSeek's MLA as it trains), on the arrays
+    the projections make: q_nope [b, s, h, dn] scores against the first
+    dn lanes of each head of kv [b, s, h, dn + dv], and q_rope [b, h, s,
+    dr] (the rotary part head-major, as the rotary fusion writes it)
+    against k_rope [b, s, 1, dr], ONE rotary head that all query heads
+    read; the values are kv's other dv lanes. The softmax runs in float32
+    over (q_nope . k_nope + q_rope . k_rope) x scale (default 1 / sqrt(dn
+    + dr)). Returns [b, s, h, dv]."""
+    if flag("enable_pallas_kernels") and _pallas_ok(q_nope, q_nope, True):
+        shards = _mesh_shards(q_nope)
         if shards is None:
-            return flash_attention_latent(query, key_nope, key_rope, value,
+            return flash_attention_latent(q_nope, q_rope, kv, k_rope,
                                           scale=scale)
         # per shard, as `_flash`: rows over data x sharding, heads over
         # 'model'; the one rotary head whole on every chip, its gradient
@@ -255,21 +257,20 @@ def latent_attention(query, key_nope, key_rope, value, scale=None):
         per_head = P(batch, None, head, None)
         return jax.shard_map(
             functools.partial(flash_attention_latent, scale=scale),
-            mesh=mesh, in_specs=(per_head, per_head,
-                                 P(batch, None, None, None), per_head),
-            out_specs=per_head, check_vma=False)(
-                query, key_nope, key_rope, value)
-    s, dn = query.shape[1], key_nope.shape[-1]
-    scale = scale if scale is not None else 1.0 / math.sqrt(query.shape[-1])
-    scores = (jnp.einsum("bqhd,bkhd->bhqk", query[..., :dn], key_nope,
+            mesh=mesh, in_specs=(per_head, P(batch, head, None, None),
+                                 per_head, P(batch, None, None, None)),
+            out_specs=per_head, check_vma=False)(q_nope, q_rope, kv, k_rope)
+    s, dn = q_nope.shape[1], q_nope.shape[-1]
+    scale = scale if scale is not None else \
+        1.0 / math.sqrt(dn + q_rope.shape[-1])
+    scores = (jnp.einsum("bqhd,bkhd->bhqk", q_nope, kv[..., :dn],
                          preferred_element_type=jnp.float32)
-              + jnp.einsum("bqhd,bkd->bhqk", query[..., dn:],
-                           key_rope[:, :, 0],
+              + jnp.einsum("bhqd,bkd->bhqk", q_rope, k_rope[:, :, 0],
                            preferred_element_type=jnp.float32)) * scale
     scores = jnp.where(jnp.tril(jnp.ones((s, s), dtype=bool)), scores,
                        -jnp.inf)
-    probs = jax.nn.softmax(scores, axis=-1).astype(query.dtype)
-    return jnp.einsum("bhqk,bkhd->bqhd", probs, value)
+    probs = jax.nn.softmax(scores, axis=-1).astype(q_nope.dtype)
+    return jnp.einsum("bhqk,bkhd->bqhd", probs, kv[..., dn:])
 
 
 def _xla_attention(query, key, value, attn_mask, dropout_p, is_causal,

@@ -557,10 +557,14 @@ def _bwd_impl(causal, scale, res, g, mask3=None, heads=1, sel=None,
             raise NotImplementedError(
                 "a selection without causal=True: the fused backward ends "
                 "a query block's dq at its diagonal key block")
-        # the selection arrives transposed, [k, q], like the scores
-        dq, (dk,), dv = _fused_bwd(
-            FLASH_SEL_BWD_DKV, scale, q3,
-            (k3,), v3, o3, lse, g, sel_t=jnp.swapaxes(sel, 1, 2))
+        # the selection arrives transposed, [k, q], like the scores; q, k
+        # and v one head an array row, k and v read by `group` query heads
+        d = q3.shape[-1]
+        parts = Parts(q_lays=(Lay(),), k_lays=(Lay(group), Lay(group)),
+                      q=((0, 0, d),), k=((0, 0, d),), v=(1, 0, d))
+        (dq,), (dk, dv) = _fused_bwd(
+            FLASH_SEL_BWD_DKV, scale, (q3,), (k3, v3), o3, g, lse, parts,
+            sel_t=jnp.swapaxes(sel, 1, 2))
         return dq, dk, dv
     bh, s, d = q3.shape
     plan = _plan(s, d, q3.dtype, causal)
@@ -725,13 +729,24 @@ def flash_attention(query, key, value, causal: bool = False,
 # have (192 = 128 without position + 64 rotary, against 128), and its
 # rotary key is ONE head that every query head reads. The two latent
 # kernels below stand on the same `_walk` as the three above, causal
-# only, with the key given in PARTS along the score width: each part is an
-# operand of its own, `[b h, s, w]` or, shared by a row's heads, `[b, s,
-# w]` read through the index map `b // heads` (the grouped-head route, no
-# copy). The score is the sum of the parts' products with q's lanes of the
-# same place, dq and dk are written part by part, and a shared part's dk
-# comes out one partial sum a query head in float32, added up outside. One
-# part of the full width is the key concatenated in HBM beforehand.
+# only, with q and the key given in PARTS along the score width, paired:
+# the score is the sum of the parts' products, dq and dk are written part
+# by part. One part of the full width is the key concatenated in HBM
+# beforehand.
+#
+# Where a head's rows lie is the caller's to say (`Lay`, `Parts`): the
+# grid runs over query heads (b h), and each operand's index map finds a
+# head's block where the projections left it. At the published widths
+# (`flash_attention_latent`) q without position is read from `[b, s, h
+# 128]`, the key without position and the values from `kv_b_proj`'s own
+# `[b, s, h (128 + 128)]`, one 256-lane block a head, o and dO as `[b, s,
+# h 128]`, which `o_proj` takes as it is; dq comes out in q's two parts,
+# and the key's and the values' gradient as ONE `[b, s, h 256]` in kv's
+# layout, so no operand and no gradient is transposed to or from `[b h,
+# s, w]`. The rotary query part is head-major, `[b h, s, 64]`, as the
+# rotary fusion writes it, and the one rotary key head `[b, s, 64]` is
+# read by the row's heads at b // h (no copy); its gradient comes out one
+# float32 partial a query head, added up outside.
 #
 # The forward (`_mla_fwd_kernel`) holds its running max, sum and correction
 # replicated over a vreg's lanes and hands lse to the backward as rows
@@ -749,20 +764,55 @@ def flash_attention(query, key, value, causal: bool = False,
 # values `group` query heads share, and the selection beside the causal
 # mask (PERF.md, PR 35).
 
-def _part_lanes(ks):
-    """[(first lane, width)] of the key parts along the score width."""
-    out, lo = [], 0
-    for k in ks:
-        out.append((lo, k.shape[-1]))
-        lo += k.shape[-1]
-    return out
+class Lay(NamedTuple):
+    """Where row g of the grid, query head g % h of batch row g // h,
+    finds its block of an operand [rows, s, lanes] of the latent kernels
+    and of the fused backward: at array row g // fold, and at lane block
+    g % fold where a row's `fold` heads lie side by side along lanes
+    (`side`: a projection's own layout, [b, s, h w]), else at lane block 0,
+    a block `fold` query heads share (the one rotary key head of a row, a
+    key/value head of a group), whose gradient leaves as one float32
+    partial a query head, added up outside. fold 1: one head an array
+    row, [b h, s, w]."""
+    fold: int = 1
+    side: bool = False
+
+    def width(self, x) -> int:
+        """Lanes of one head's block of `x`."""
+        return x.shape[-1] // self.fold if self.side else x.shape[-1]
 
 
-def _mla_scores(q_parts, k_refs, row0, rows, scale):
-    """sum over the parts of q_part k_partT: [q rows, rows] fp32."""
+class Parts(NamedTuple):
+    """How the latent kernels and the fused backward find their operands:
+    the `Lay` of each q-side and of each k-side array, and where q's parts,
+    the key's parts (paired with q's) and the values lie in them: (array,
+    first lane, width) within a head's block."""
+    q_lays: tuple
+    k_lays: tuple
+    q: tuple
+    k: tuple
+    v: tuple
+
+    @property
+    def o(self) -> Lay:
+        """o's and dO's: side by side where the values are, else one head
+        an array row."""
+        lay = self.k_lays[self.v[0]]
+        return lay if lay.side else Lay()
+
+
+def _lanes(ref, start, size, lo, w):
+    """Rows [start, start + size) and lanes [lo, lo + w) of a [1, rows,
+    lanes] block."""
+    return ref[0, pl.ds(start, size), pl.ds(lo, w)]
+
+
+def _mla_scores(q_parts, keys, row0, rows, scale):
+    """sum over the parts of q_part k_partT: [q rows, rows] fp32. `keys`:
+    (ref, first lane, width) of each key part."""
     s = None
-    for qp, k_ref in zip(q_parts, k_refs):
-        t = _dot(qp, _rows(k_ref, row0, rows), _NT)
+    for qp, (k_ref, lo, w) in zip(q_parts, keys):
+        t = _dot(qp, _lanes(k_ref, row0, rows, lo, w), _NT)
         s = t if s is None else s + t
     return s * scale
 
@@ -781,7 +831,7 @@ def _over_lanes(x, w):
     return x if x.shape[1] == w else x[:, :w]
 
 
-def _mla_fwd_kernel(q_ref, *refs, lanes, scale, plan, n):
+def _mla_fwd_kernel(*refs, parts, scale, plan, n):
     """The latent forward. Its running statistics `m`, `l` and the
     correction `corr` are NOT columns [rows, 1] but [rows, _STAT_LANES],
     alike in every lane: a column is a vreg for every 8 numbers with one
@@ -792,21 +842,23 @@ def _mla_fwd_kernel(q_ref, *refs, lanes, scale, plan, n):
     with the statistics as rows along lanes, read 13.14). lse leaves as
     the backward reads it, rows along lanes (`_specs` `stat_rows_out`),
     transposed once a q block."""
-    parts = len(lanes)
-    k_refs, (v_ref, o_ref, lse_ref) = refs[:parts], refs[parts:parts + 3]
+    n_q, n_k = len(parts.q_lays), len(parts.k_lays)
+    q_refs, k_refs = refs[:n_q], refs[n_q:n_q + n_k]
+    o_ref, lse_ref = refs[n_q + n_k:n_q + n_k + 2]
+    keys = [(k_refs[a], lo, w) for a, lo, w in parts.k]
+    v_a, v_lo, dv = parts.v
     _, c, sub = plan
-    dv = v_ref.shape[-1]
 
     def prep(r):
-        return tuple(q_ref[0, pl.ds(r, c), pl.ds(lo, w)] for lo, w in lanes)
+        return tuple(_lanes(q_refs[a], r, c, lo, w) for a, lo, w in parts.q)
 
     def piece(ctx, j, g, lo, hi, tri, carry):
         m, l, acc = carry
-        s = _mla_scores([q[g:g + sub] for q in ctx], k_refs, j * c, hi,
+        s = _mla_scores([q[g:g + sub] for q in ctx], keys, j * c, hi,
                         scale)
         if tri:
             s = jnp.where(_keep_tri(sub, hi, g, True), s, NEG_INF)
-        v = _rows(v_ref, j * c, hi)
+        v = _lanes(k_refs[v_a], j * c, hi, v_lo, dv)
         m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
         p = jnp.exp(s - _over_lanes(m_new, hi))
         corr = jnp.exp(m - m_new)
@@ -825,10 +877,10 @@ def _mla_fwd_kernel(q_ref, *refs, lanes, scale, plan, n):
 
     _walk(plan, n, True, True, pl.program_id(1), pl.program_id(2),
           ((NEG_INF, _STAT_LANES), (0.0, _STAT_LANES), (0.0, dv)),
-          refs[parts + 3:], prep, piece, finalize)
+          refs[n_q + n_k + 2:], prep, piece, finalize)
 
 
-def _fused_bwd_kernel(q_ref, *refs, lanes, scale, plan, n, selected):
+def _fused_bwd_kernel(*refs, parts, scale, plan, n, selected):
     """The backward as ONE kernel, of the latent family and of the
     selected: dk of every key part and dv of the resident k block as
     `_dkv_kernel` makes them (the scores transposed, k qT; a per-pair
@@ -844,14 +896,18 @@ def _fused_bwd_kernel(q_ref, *refs, lanes, scale, plan, n, selected):
     16.39, so the one layout serves both). A q block's dq is whole once
     its own (diagonal) k block has passed, which is that k block's first
     step on the grid: there it is transposed back and rounded, once, into
-    `dq_ref`."""
-    parts = len(lanes)
-    k_refs, refs = refs[:parts], list(refs[parts:])
-    v_ref, do_ref, lse_ref, delta_ref = refs[:4]
-    sel_ref = refs[4] if selected else None
-    refs = refs[4 + selected:]
-    dq_ref, dk_refs, dv_ref = refs[0], refs[1:parts + 1], refs[parts + 1]
-    dq_acc = refs[parts + 2:2 * parts + 2]
+    its part's lanes of the q side's gradient. Each k-side array's
+    gradient block takes its parts' lanes (dk without position and dv side
+    by side in one block of kv's gradient)."""
+    n_q, n_k = len(parts.q_lays), len(parts.k_lays)
+    q_refs, k_refs = refs[:n_q], refs[n_q:n_q + n_k]
+    do_ref, lse_ref, delta_ref = refs[n_q + n_k:n_q + n_k + 3]
+    refs = refs[n_q + n_k + 3:]
+    sel_ref = refs[0] if selected else None
+    refs = refs[selected:]
+    dq_refs, dk_refs = refs[:n_q], refs[n_q:n_q + n_k]
+    dq_acc = refs[n_q + n_k:n_q + n_k + len(parts.k)]
+    v_a, v_lo, v_w = parts.v
     _, c, sub = plan
     kb, qb = pl.program_id(1), pl.program_id(2)
 
@@ -861,14 +917,15 @@ def _fused_bwd_kernel(q_ref, *refs, lanes, scale, plan, n, selected):
             acc[qb] = jnp.zeros(acc.shape[1:], jnp.float32)
 
     def prep(r):
-        ks = tuple(_rows(k, r, c) for k in k_refs)
-        return ks, tuple(k.T for k in ks), _rows(v_ref, r, c), r
+        ks = tuple(_lanes(k_refs[a], r, c, lo, w) for a, lo, w in parts.k)
+        return ks, tuple(k.T for k in ks), _lanes(k_refs[v_a], r, c, v_lo,
+                                                  v_w), r
 
     def piece(ctx, i, g, lo, hi, tri, carry):
         ks, kts, v, r = ctx
         *dks, dv = carry
         q0, nq = i * c + lo, hi - lo
-        qs = [q_ref[0, pl.ds(q0, nq), pl.ds(l0, w)] for l0, w in lanes]
+        qs = [_lanes(q_refs[a], q0, nq, l0, w) for a, l0, w in parts.q]
         do = _rows(do_ref, q0, nq)
         st = None
         for k, q in zip(ks, qs):
@@ -890,49 +947,60 @@ def _fused_bwd_kernel(q_ref, *refs, lanes, scale, plan, n, selected):
         return (*(dk + _dot(dst, q, _NN) for dk, q in zip(dks, qs)), dv)
 
     def finalize(ctx, r, carry):
-        for ref, x in zip((*dk_refs, dv_ref), carry):
-            ref[0, pl.ds(r, c), :] = x.astype(ref.dtype)
+        for (a, lo, w), x in zip((*parts.k, parts.v), carry):
+            ref = dk_refs[a]
+            ref[0, pl.ds(r, c), pl.ds(lo, w)] = x.astype(ref.dtype)
 
     _walk(plan, n, True, False, kb, qb,
-          (*((0.0, w) for _, w in lanes), (0.0, v_ref.shape[-1])),
-          refs[2 * parts + 2:], prep, piece, finalize)
+          (*((0.0, w) for _, _, w in parts.k), (0.0, v_w)),
+          refs[n_q + n_k + len(parts.k):], prep, piece, finalize)
 
     @pl.when(qb == kb)
     def _diagonal():
-        for (lo, w), acc in zip(lanes, dq_acc):
-            dq_ref[0, :, pl.ds(lo, w)] = acc[kb].T.astype(dq_ref.dtype)
+        for (a, lo, w), acc in zip(parts.q, dq_acc):
+            ref = dq_refs[a]
+            ref[0, :, pl.ds(lo, w)] = acc[kb].T.astype(ref.dtype)
 
 
-def _parts_specs(plan, q3, ks, out_is_q, heads=1):
+def _parts_specs(plan, out_is_q, heads=1):
     """Block specs of the latent kernels' and the fused backward's
     operands on a grid (b h, out block, reduce block), under the causal
-    mask: q-side operands of the width `w` (`q(w)`), k-side ones (`k(w,
-    fold)`), the key's parts (`ks`), an output (`out(w)`), and the
-    statistics and the selection as `_specs` lays them (`heads` query
-    heads a row share a selection). A k-side operand with fewer heads
-    than q has is read by `fold` query heads each, at b // fold (`folds`,
-    of the key's parts: the one rotary head of a row, or a key/value head
-    of a group)."""
+    mask: an array `x` laid as `lay` (`Lay`) on the q side (`q(x, lay)`),
+    on the k side (`k`) or as an output (`out`), and the statistics and
+    the selection as `_specs` lays them (`heads` query heads a row share a
+    selection)."""
     block = plan.block
-    laid = _specs(plan, q3.shape[-1], True, heads, out_is_q)
+    # of `_specs` only the statistics' and the selection's, which no
+    # operand's width enters
+    laid = _specs(plan, 0, True, heads, out_is_q)
 
     def red(i, j):
         return jnp.minimum(j, i) if out_is_q else jnp.maximum(j, i)
 
-    def spec(w, index):
-        return pl.BlockSpec((1, block, w), index, memory_space=pltpu.VMEM)
+    def spec(x, lay, seq):
+        shift = lay.fold.bit_length() - 1
 
-    def out_side(w, fold=1):
-        return spec(w, lambda b, i, j: (b if fold == 1 else b // fold, i, 0))
+        def index(b, i, j):
+            if lay.side and lay.fold == 1 << shift:
+                # a power of two of heads side by side: row and lane block
+                # by shift and mask, where an integer division costs the
+                # pipeline about 20 ns an operand a grid step (0.07 ms a
+                # forward call of 4096 steps on a v5e: PERF.md section 6)
+                return (jax.lax.shift_right_logical(b, shift), seq(i, j),
+                        b & (lay.fold - 1))
+            return (b if lay.fold == 1 else b // lay.fold, seq(i, j),
+                    b % lay.fold if lay.side else 0)
+        return pl.BlockSpec((1, block, lay.width(x)), index,
+                            memory_space=pltpu.VMEM)
 
-    def red_side(w, fold=1):
-        return spec(w, lambda b, i, j: (b if fold == 1 else b // fold,
-                                        red(i, j), 0))
+    def out_side(x, lay):
+        return spec(x, lay, lambda i, j: i)
+
+    def red_side(x, lay):
+        return spec(x, lay, red)
     q_side, k_side = (out_side, red_side) if out_is_q else \
         (red_side, out_side)
-    folds = [q3.shape[0] // k.shape[0] for k in ks]
-    return dict(q=q_side, k=k_side, out=out_side, folds=folds,
-                ks=[k_side(k.shape[-1], f) for k, f in zip(ks, folds)],
+    return dict(q=q_side, k=k_side, out=out_side,
                 stat_rows=laid["stat_rows"],
                 stat_rows_out=laid["stat_rows_out"], sel=laid["sel"])
 
@@ -951,31 +1019,38 @@ def _values_plan(s: int, dv: int, dtype) -> Plan:
     return _plan(s, dv, dtype, True)
 
 
-def _mla_fwd(q3, ks, v3, scale):
-    """o [b h, s, dv] and lse as the fused backward reads it, [b h,
-    blocks, groups a block, rows a group] (`_specs` `stat_rows`)."""
-    bh, s, d = q3.shape
-    dv = v3.shape[-1]
-    plan = _values_plan(s, dv, q3.dtype)
+def _grid_rows(qs, parts):
+    """(query heads b h, s) of the grid, from q's first array."""
+    return qs[0].shape[0] * parts.q_lays[0].fold, qs[0].shape[1]
+
+
+def _mla_fwd(qs, ks, parts, scale):
+    """o, laid as `parts.o`, and lse as the fused backward reads it, [b h,
+    blocks, groups a block, rows a group] (`_specs` `stat_rows`). `qs`,
+    `ks`: the q-side and the k-side arrays (`Parts`)."""
+    bh, s = _grid_rows(qs, parts)
+    dv = parts.v[2]
+    plan = _values_plan(s, dv, qs[0].dtype)
     n = s // plan.block
-    sp = _parts_specs(plan, q3, ks, out_is_q=True)
+    sp = _parts_specs(plan, out_is_q=True)
+    o = jax.ShapeDtypeStruct((bh // parts.o.fold, s, parts.o.fold * dv),
+                             qs[0].dtype)
     carried = [pltpu.VMEM((plan.block, w), jnp.float32)
                for w in (_STAT_LANES, _STAT_LANES, dv)] if n > 1 else []
     return pl.pallas_call(
-        functools.partial(_mla_fwd_kernel, lanes=_part_lanes(ks),
-                          scale=scale, plan=plan, n=n),
+        functools.partial(_mla_fwd_kernel, parts=parts, scale=scale,
+                          plan=plan, n=n),
         grid=(bh, n, n),
-        in_specs=[sp["q"](d), *sp["ks"], sp["k"](dv)],
-        out_specs=[sp["out"](dv), sp["stat_rows_out"]],
-        out_shape=[jax.ShapeDtypeStruct((bh, s, dv), q3.dtype),
-                   jax.ShapeDtypeStruct(
-                       (bh, n, plan.block // plan.sub, plan.sub),
-                       jnp.float32)],
+        in_specs=[*map(sp["q"], qs, parts.q_lays),
+                  *map(sp["k"], ks, parts.k_lays)],
+        out_specs=[sp["out"](o, parts.o), sp["stat_rows_out"]],
+        out_shape=[o, jax.ShapeDtypeStruct(
+            (bh, n, plan.block // plan.sub, plan.sub), jnp.float32)],
         scratch_shapes=carried,
         interpret=_interpret(),
         compiler_params=_COMPILER_PARAMS,
         name=FLASH_MLA_FWD,
-    )(q3, *ks, v3)
+    )(*qs, *ks)
 
 
 # The most a head's whole dq may take of VMEM in float32 (a v5e core has
@@ -1002,57 +1077,65 @@ def _fused_bwd_vmem(s, block, parts, dv, selected=False):
     return acc, acc + sel + blocks + (16 << 20)
 
 
-def _fused_bwd(name, scale, q3, ks, v3, o3, lse, do3, sel_t=None):
-    """dq, the dk of every key part and dv from ONE `pallas_call` named
-    `name`. q3 [b h, s, d]; `ks` the key's parts along d, `v3` the
-    values, each with q's heads or fewer (`_parts_specs`); `lse` as the
-    family's forward writes it, the rows the kernel reads (`_mla_fwd`)
+def _rowsum(do, o, lay, bh):
+    """rowsum(dO·O) a query head, [b h, s], of o laid as `lay`."""
+    x = do.astype(jnp.float32) * o.astype(jnp.float32)
+    if not lay.side:
+        return jnp.sum(x, axis=-1)
+    x = jnp.sum(x.reshape(*o.shape[:2], lay.fold, -1), axis=-1)  # [b, s, h]
+    return jnp.swapaxes(x, 1, 2).reshape(bh, -1)
+
+
+def _fused_bwd(name, scale, qs, ks, o, do, lse, parts, sel_t=None):
+    """The gradients of the q-side and of the k-side arrays (`qs`, `ks`,
+    laid as `parts` says), from ONE `pallas_call` named `name`; `lse` as
+    the family's forward writes it, the rows the kernel reads (`_mla_fwd`)
     or the columns [b h, s, LANE] of `_fwd`; `sel_t` ([b, s_k, s_q] 0/1
     bytes, or None): a per-pair selection, transposed. Causal."""
-    bh, s, d = q3.shape
-    dv = v3.shape[-1]
-    plan = _values_plan(s, dv, q3.dtype)
+    bh, s = _grid_rows(qs, parts)
+    dv = parts.v[2]
+    plan = _values_plan(s, dv, qs[0].dtype)
     block, _, sub = plan
     n = s // block
-    lanes = _part_lanes(ks)
-    widths = [w for _, w in lanes]
+    widths = [w for _, _, w in parts.k]
     selected = sel_t is not None
     acc_bytes, vmem = _fused_bwd_vmem(s, block, widths, dv, selected)
     if acc_bytes > _DQ_BYTES:
         raise ValueError(
             f"{name} keeps a head's whole dq in VMEM: {s} rows of {widths} "
             f"lanes take {acc_bytes} bytes, over {_DQ_BYTES}")
-    delta = jnp.sum(do3.astype(jnp.float32) * o3.astype(jnp.float32),
-                    axis=-1)
-    delta_rows = delta.reshape(bh, n, block // sub, sub)
+    delta_rows = _rowsum(do, o, parts.o, bh).reshape(bh, n, block // sub,
+                                                     sub)
     if lse.ndim == 3:       # columns of the stat-lane layout: laid out here
         lse = lse[..., 0].reshape(bh, n, block // sub, sub)
 
-    # grid (b h, k block, q block). A k-side operand that several query
-    # heads read (`folds`): its gradient is one partial sum a query head
-    # in float32, added up outside, which costs one pass over [b h, s, w]
+    # grid (b h, k block, q block). A k-side array whose block several
+    # query heads share: its gradient is one partial sum a query head in
+    # float32, added up outside, which costs one pass over [b h, s, w]
     # where a second reduce axis would cost the kernel its static walk.
     # The k blocks of a head follow each other ("arbitrary"): the head's
     # dq crosses them in the scratch.
-    sp = _parts_specs(plan, q3, ks, out_is_q=False,
-                    heads=bh // sel_t.shape[0] if selected else 1)
-    folds = [*sp["folds"], bh // v3.shape[0]]
+    sp = _parts_specs(plan, out_is_q=False,
+                      heads=bh // sel_t.shape[0] if selected else 1)
+    shared = [lay.fold > 1 and not lay.side for lay in parts.k_lays]
+    k_out = [jax.ShapeDtypeStruct((bh, s, x.shape[-1]), jnp.float32) if sh
+             else jax.ShapeDtypeStruct(x.shape, x.dtype)
+             for x, sh in zip(ks, shared)]
     carried = [pltpu.VMEM((block, w), jnp.float32)
                for w in (*widths, dv)] if n > 1 else []
-    dq, *dkv = pl.pallas_call(
-        functools.partial(_fused_bwd_kernel, lanes=lanes, scale=scale,
+    grads = pl.pallas_call(
+        functools.partial(_fused_bwd_kernel, parts=parts, scale=scale,
                           plan=plan, n=n, selected=selected),
         grid=(bh, n, n),
-        in_specs=[sp["q"](d), *sp["ks"], sp["k"](dv, folds[-1]), sp["q"](dv),
+        in_specs=[*map(sp["q"], qs, parts.q_lays),
+                  *map(sp["k"], ks, parts.k_lays), sp["q"](do, parts.o),
                   sp["stat_rows"], sp["stat_rows"],
                   *([sp["sel"]] if selected else [])],
-        out_specs=[sp["out"](d), *(sp["out"](w) for w in widths),
-                   sp["out"](dv)],
-        out_shape=[jax.ShapeDtypeStruct((bh, s, d), q3.dtype),
-                   *(jax.ShapeDtypeStruct(
-                         (bh, s, x.shape[-1]),
-                         jnp.float32 if f > 1 else x.dtype)
-                     for x, f in zip((*ks, v3), folds))],
+        out_specs=[*map(sp["out"], qs, parts.q_lays),
+                   *(sp["out"](x, Lay() if sh else lay)
+                     for x, lay, sh in zip(k_out, parts.k_lays, shared))],
+        out_shape=[*(jax.ShapeDtypeStruct(x.shape, x.dtype) for x in qs),
+                   *k_out],
         scratch_shapes=[*(pltpu.VMEM((n, w, block), jnp.float32)
                           for w in widths), *carried],
         interpret=_interpret(),
@@ -1060,56 +1143,89 @@ def _fused_bwd(name, scale, q3, ks, v3, o3, lse, do3, sel_t=None):
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
             vmem_limit_bytes=vmem),
         name=name,
-    )(q3, *ks, v3, do3, lse, delta_rows, *([sel_t] if selected else []))
-    *dks, dv_ = (
-        g.reshape(bh // f, f, s, -1).sum(1).astype(x.dtype) if f > 1 else g
-        for g, x, f in zip(dkv, (*ks, v3), folds))
-    return dq, tuple(dks), dv_
+    )(*qs, *ks, do, lse, delta_rows, *([sel_t] if selected else []))
+    dks = (g.reshape(bh // lay.fold, lay.fold, s, -1).sum(1).astype(x.dtype)
+           if sh else g
+           for g, x, lay, sh in zip(grads[len(qs):], ks, parts.k_lays,
+                                    shared))
+    return tuple(grads[:len(qs)]), tuple(dks)
 
 
-def _mla_bwd(scale, res, do3):
-    q3, ks, v3, o3, lse = res
-    return _fused_bwd(FLASH_MLA_BWD_DKV, scale, q3, ks, v3, o3, lse, do3)
+def _mla_bwd(parts, scale, res, do):
+    qs, ks, o, lse = res
+    return _fused_bwd(FLASH_MLA_BWD_DKV, scale, qs, ks, o, do, lse, parts)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _mla3(q3, ks, v3, scale):
-    """The differentiable wrapper of the two latent kernels. q3 [b h,
-    s, d]; `ks` the key's parts along d (module comment above); v3 [b h,
-    s, dv]."""
-    return _mla_fwd(q3, ks, v3, scale)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def _mla3(qs, ks, parts, scale):
+    """The differentiable wrapper of the two latent kernels: o laid as
+    `parts.o`. `qs`, `ks`: the q-side and the k-side arrays, laid as
+    `parts` says (module comment above)."""
+    return _mla_fwd(qs, ks, parts, scale)[0]
 
 
-def _mla3_fwd(q3, ks, v3, scale):
-    o, lse = _mla_fwd(q3, ks, v3, scale)
-    return o, (q3, ks, v3, o, lse)
+def _mla3_fwd(qs, ks, parts, scale):
+    o, lse = _mla_fwd(qs, ks, parts, scale)
+    return o, (qs, ks, o, lse)
 
 
 _mla3.defvjp(_mla3_fwd, _mla_bwd)
 
 
-def flash_attention_latent(query, key_nope, key_rope, value, scale=None):
-    """Causal latent attention. query [b, s, h, dn + dr]; key_nope [b, s,
-    h, dn]; key_rope [b, s, 1, dr], ONE head that every query head reads
-    (the rotary part); value [b, s, h, dv]. score = (q[:dn] . k_nope +
-    q[dn:] . k_rope) x scale (default 1 / sqrt(dn + dr)). Returns [b, s,
-    h, dv]. Requires s % 128 == 0. The rotary key is read through the
+def flash_attention_latent(q_nope, q_rope, kv, k_rope, scale=None):
+    """Causal latent attention on the arrays the projections make. q_nope
+    [b, s, h, dn]; q_rope [b, h, s, dr], the rotary part head-major, as
+    the rotary fusion writes it; kv [b, s, h, dn + dv], a head's key
+    without position and its values side by side (`kv_b_proj`'s output);
+    k_rope [b, s, 1, dr], ONE head that every query head reads. score =
+    (q_nope . k_nope + q_rope . k_rope) x scale (default 1 / sqrt(dn +
+    dr)). Returns [b, s, h, dv]. Requires s % 128 == 0.
+
+    Where dn and dv are whole 128-lane tiles (the published 128 and 128)
+    the kernels read q_nope, kv and dO and write o, dq_nope and kv's
+    gradient where they lie, `[b, s, h w]`, through their index maps
+    (`Lay` side): nothing is transposed and nothing is sliced. Else each
+    is laid out `[b h, s, w]` first. The rotary key is read through the
     index map, not concatenated in HBM beforehand (step 0 on the chip:
     tools/flash_mla_step0.py; PERF.md, PR 33)."""
-    b, s, h, d = query.shape
+    b, s, h, dn = q_nope.shape
+    dr = q_rope.shape[-1]
     if s % 128 != 0:
         raise ValueError(f"flash_attention_latent needs seq % 128 == 0, "
                          f"got {s}")
-    if key_rope.shape[2] != 1 or \
-            key_nope.shape[-1] + key_rope.shape[-1] != d:
+    qs, ks, parts = _laid(q_nope, q_rope, kv, k_rope)
+    scale = scale if scale is not None else 1.0 / math.sqrt(dn + dr)
+    o = _mla3(qs, ks, parts, scale)
+    if parts.o.side:
+        return o.reshape(b, s, h, -1)
+    return jnp.swapaxes(o.reshape(b, h, s, -1), 1, 2)
+
+
+def _laid(q_nope, q_rope, kv, k_rope):
+    """The latent kernels' q-side and k-side arrays and their `Parts`,
+    from `flash_attention_latent`'s operands: where a head's parts without
+    position and its values are whole 128-lane tiles, the projections'
+    arrays as they are, [b, s, h w] (free reshapes); else laid out [b h,
+    s, w]. The rotary parts as they come, [b h, s, dr] and [b, s, dr]."""
+    b, s, h, dn = q_nope.shape
+    dr = q_rope.shape[-1]
+    dv = kv.shape[-1] - dn
+    if q_rope.shape != (b, h, s, dr) or kv.shape[:3] != (b, s, h) \
+            or dv <= 0 or k_rope.shape != (b, s, 1, dr):
         raise ValueError(
-            f"key parts {key_nope.shape} + {key_rope.shape} against "
-            f"queries {query.shape}")
-    scale = scale if scale is not None else 1.0 / math.sqrt(d)
+            f"key parts {kv.shape} + {k_rope.shape} against queries "
+            f"{q_nope.shape} + {q_rope.shape}")
+    if dn % 128 == 0 and dv % 128 == 0:
+        lay = Lay(h, side=True)
+        q3, kv3 = q_nope.reshape(b, s, -1), kv.reshape(b, s, -1)
+    else:
+        lay = Lay()
 
-    def to3(x):
-        return jnp.swapaxes(x, 1, 2).reshape(-1, s, x.shape[-1])
-
-    o3 = _mla3(to3(query), (to3(key_nope), to3(key_rope)), to3(value),
-               scale)
-    return jnp.swapaxes(o3.reshape(b, h, s, -1), 1, 2)
+        def to3(x):
+            return jnp.swapaxes(x, 1, 2).reshape(b * h, s, x.shape[-1])
+        q3, kv3 = to3(q_nope), to3(kv)
+    parts = Parts(q_lays=(lay, Lay()), k_lays=(lay, Lay(h)),
+                  q=((0, 0, dn), (1, 0, dr)), k=((0, 0, dn), (1, 0, dr)),
+                  v=(0, dn, dv))
+    return ((q3, q_rope.reshape(b * h, s, dr)),
+            (kv3, k_rope.reshape(b, s, dr)), parts)

@@ -295,38 +295,53 @@ class TestDispatchUnderMesh:
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        rtol=1e-4, atol=5e-5)
 
-    @pytest.mark.parametrize("resident", [None, 128 * 1024],
-                             ids=["one-block", "eight-blocks"])
+    @pytest.mark.parametrize("s,widths,resident", [
+        (1024, (16, 8, 16), None), (1024, (16, 8, 16), 128 * 1024),
+        (1024, (128, 64, 128), None)],
+        ids=["one-block", "eight-blocks", "projections-layout"])
     def test_latent_kernels_per_shard_match_xla(self, mesh, monkeypatch,
-                                                resident):
+                                                s, widths, resident):
         """`F.latent_attention` under dp2 x mp2: the `flash_mla_*` kernels
         per shard, the one rotary head whole on every chip and its
         gradient summed over a chip's heads and then over 'model'; with
         eight blocks a head, dq crosses the k blocks in the fused
-        backward's accumulator."""
+        backward's accumulator; at 128-lane widths each chip's kernels
+        read its local `[b, s, h w]` arrays where they lie."""
         from jax.sharding import NamedSharding, PartitionSpec as P
         from paddle_tpu.nn.functional import attention as A
         if resident:
             monkeypatch.setattr(fa, "_RESIDENT_BYTES", resident)
             assert fa._values_plan(1024, 16, jnp.float32).block * 8 == 1024
-        q, kn, kr, v, do = _latent_operands(1024, 4, 16, 8, 16, b=4)
-        sh = NamedSharding(mesh, P("data", None, "model", None))
-        q, kn, v, do = (jax.device_put(a, sh) for a in (q, kn, v, do))
-        kr = jax.device_put(kr, NamedSharding(mesh, P("data")))
+        q, kn, kr, v, do = _latent_operands(s, 4, *widths, b=4)
+        args = _projected(q, kn, kr, v)
+        specs = [P("data", None, "model", None), P("data", "model"),
+                 P("data", None, "model", None), P("data")]
+        args = [jax.device_put(a, NamedSharding(mesh, p))
+                for a, p in zip(args, specs)]
+        do = jax.device_put(do, NamedSharding(mesh, specs[0]))
 
         def grads():
             return jax.jit(jax.value_and_grad(
                 lambda *a: jnp.sum(A.latent_attention(*a) * do),
                 argnums=(0, 1, 2, 3)))
         sharded = grads()
-        text = str(jax.make_jaxpr(sharded)(q, kn, kr, v))
+        jaxpr = jax.make_jaxpr(sharded)(*args)
+        text = str(jaxpr)
         assert "shard_map" in text and "flash_mla_bwd_dkv" in text
-        got, got_g = sharded(q, kn, kr, v)
+        calls = [e for e in _eqns(jaxpr.jaxpr)
+                 if e.primitive.name == "pallas_call"]
+        assert len(calls) == 2
+        # a chip's two rows of two heads: q without position as it lies,
+        # [2, s, 2 x 128], or laid out [4, s, 16]
+        local_q = (2, s, 2 * widths[0]) if widths[0] % 128 == 0 else \
+            (4, s, widths[0])
+        assert {c.invars[0].aval.shape for c in calls} == {local_q}
+        got, got_g = sharded(*args)
         monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
-        ref, ref_g = grads()(q, kn, kr, v)
+        ref, ref_g = grads()(*args)
         np.testing.assert_allclose(float(got), float(ref), rtol=1e-5)
-        for name, a, b in zip(("dq", "dk_nope", "dk_rope", "dv"), got_g,
-                              ref_g):
+        for name, a, b in zip(("dq_nope", "dq_rope", "dkv", "dk_rope"),
+                              got_g, ref_g):
             assert a.shape == b.shape, name
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        rtol=1e-4, atol=5e-5, err_msg=name)
@@ -604,25 +619,41 @@ def test_selected_backward_refuses_a_walk_that_is_not_causal():
 # ------------------------------------------------------ latent attention
 
 def _latent_operands(s, h, dn, dr, dv, dtype=jnp.float32, b=2):
+    """q [b, s, h, dn + dr], k_nope [b, s, h, dn], k_rope [b, s, 1, dr],
+    v and dO [b, s, h, dv]."""
     ks = jax.random.split(jax.random.key(1), 5)
     shapes = [(b, s, h, dn + dr), (b, s, h, dn), (b, s, 1, dr),
               (b, s, h, dv), (b, s, h, dv)]
     return [jax.random.normal(k, sh, dtype) for k, sh in zip(ks, shapes)]
 
 
+def _projected(q, kn, kr, v):
+    """The operands of `F.latent_attention` as the projections make them:
+    q without position, the rotary query part head-major [b, h, s, dr],
+    k_nope and v side by side a head [b, s, h, dn + dv], the rotary key."""
+    dn = kn.shape[-1]
+    return (q[..., :dn], jnp.swapaxes(q[..., dn:], 1, 2),
+            jnp.concatenate([kn, v], -1), kr)
+
+
 def _latent(q, kn, kr, v, concat=False):
     """`flash_attention_latent`, or the same kernels given the key as ONE
     part of the full width, concatenated beforehand with the rotary head
-    broadcast (step 0's other variant, tools/flash_mla_step0.py)."""
+    broadcast (the other way of handing them the rotary key, ranked by
+    tools/flash_mla_step0.py)."""
     if not concat:
-        return fa.flash_attention_latent(q, kn, kr, v)
+        return fa.flash_attention_latent(*_projected(q, kn, kr, v))
     b, s, h, d = q.shape
+    dv = v.shape[-1]
 
     def to3(x):
         return jnp.swapaxes(x, 1, 2).reshape(-1, s, x.shape[-1])
     k = jnp.concatenate(
         [kn, jnp.broadcast_to(kr, kn.shape[:-1] + kr.shape[-1:])], -1)
-    o3 = fa._mla3(to3(q), (to3(k),), to3(v), d ** -0.5)
+    one = fa.Lay()
+    parts = fa.Parts(q_lays=(one,), k_lays=(one, one), q=((0, 0, d),),
+                     k=((0, 0, d),), v=(1, 0, dv))
+    o3 = fa._mla3((to3(q),), (to3(k), to3(v)), parts, d ** -0.5)
     return jnp.swapaxes(o3.reshape(b, h, s, -1), 1, 2)
 
 
@@ -643,9 +674,15 @@ def _latent(q, kn, kr, v, concat=False):
     # (block, chunk, group) moves the rows of dq a pair adds to
     (1536, 2, 16, 8, 16, {"_RESIDENT_BYTES": 512 * 1024, "_CHUNK": 256,
                           "_CAUSAL_SUB": 128}),
+    # three heads, an odd count, four blocks: the projections' own arrays
+    # (a row's heads side by side, found by division, not by shift) and
+    # `[b h, s, w]` operands
+    (512, 3, 128, 64, 128, {"_RESIDENT_BYTES": 64 * 1024}),
+    (512, 3, 16, 8, 16, {"_RESIDENT_BYTES": 64 * 1024}),
 ], ids=["192-128-s256", "24-16-s1024", "24-16-s512-chunks-groups",
         "192-128-s512-four-blocks", "24-16-s768-six-blocks",
-        "24-16-s1536-blocks-chunks-groups"])
+        "24-16-s1536-blocks-chunks-groups", "192-128-s512-three-heads",
+        "24-16-s512-three-heads"])
 def test_latent_kernels_match_the_xla_path(monkeypatch, s, h, dn, dr, dv,
                                            sizes, concat):
     """Scores over dn + dr lanes with ONE rotary key head shared by every
@@ -664,13 +701,14 @@ def test_latent_kernels_match_the_xla_path(monkeypatch, s, h, dn, dr, dv,
     q, kn, kr, v, do = _latent_operands(s, h, dn, dr, dv)
 
     def xla(*a):
-        return jnp.sum(F.latent_attention(*a) * do)
+        return jnp.sum(F.latent_attention(*_projected(*a)) * do)
 
     def kernels(*a):
         return jnp.sum(_latent(*a, concat=concat) * do)
     np.testing.assert_allclose(
         np.asarray(_latent(q, kn, kr, v, concat=concat)),
-        np.asarray(F.latent_attention(q, kn, kr, v)), atol=5e-6)
+        np.asarray(F.latent_attention(*_projected(q, kn, kr, v))),
+        atol=5e-6)
     want = jax.grad(xla, argnums=(0, 1, 2, 3))(q, kn, kr, v)
     got = jax.grad(kernels, argnums=(0, 1, 2, 3))(q, kn, kr, v)
     for name, a, b in zip(("dq", "dk_nope", "dk_rope", "dv"), got, want):
@@ -705,11 +743,14 @@ def test_latent_forward_o_and_lse_wherever_a_rows_maximum_lies(
     ramp = jnp.linspace(0.0, 100.0, s)
     q = q.at[..., 0].set(8.0)
     kn = kn.at[:, :, 0, 0].set(ramp).at[:, :, 1, 0].set(-ramp)
+    q, kn, kr, v = (x.astype(dtype) for x in (q, kn, kr, v))
     q3, kn3, kr3, v3 = (jnp.swapaxes(x, 1, 2).reshape(-1, s, x.shape[-1])
-                        .astype(dtype) for x in (q, kn, kr, v))
+                        for x in (q, kn, kr, v))
     scale = (dn + dr) ** -0.5
-    o, lse = fa._mla_fwd(q3, (kn3, kr3), v3, scale)
-    assert o.dtype == dtype and o.shape == (h, s, dv)
+    o, lse = fa._mla_fwd(*fa._laid(*_projected(q, kn, kr, v)), scale)
+    # o as `o_proj` takes it, [b, s, h dv]
+    assert o.dtype == dtype and o.shape == (1, s, h * dv)
+    o = jnp.swapaxes(o.reshape(s, h, dv), 0, 1)
     assert lse.dtype == jnp.float32
     assert lse.shape == (h, s // block, block // sub, sub)
 
@@ -731,16 +772,19 @@ def test_latent_forward_o_and_lse_wherever_a_rows_maximum_lies(
                                atol=tol)
 
 
-def _eqns(jaxpr):
+def _eqns(jaxpr, kernels=True):
     """Every equation of a jaxpr and of the jaxprs its equations hold (a
-    `pallas_call`'s kernel, a `cond`'s branches)."""
+    `pallas_call`'s kernel unless `kernels` is False, a `cond`'s
+    branches)."""
     for eqn in jaxpr.eqns:
         yield eqn
+        if not kernels and eqn.primitive.name == "pallas_call":
+            continue
         for v in eqn.params.values():
             for sub in v if isinstance(v, (tuple, list)) else (v,):
                 sub = getattr(sub, "jaxpr", sub)
                 if hasattr(sub, "eqns"):
-                    yield from _eqns(sub)
+                    yield from _eqns(sub, kernels)
 
 
 @pytest.mark.parametrize("s,sizes", [
@@ -755,10 +799,11 @@ def test_latent_forward_counts_itself_and_carries_no_columns(
     through `exp`, and none is carried between grid steps."""
     for name, size in sizes.items():
         monkeypatch.setattr(fa, name, size)
-    x = [jax.ShapeDtypeStruct((4, s, w), jnp.bfloat16)
-         for w in (192, 128, 64, 128)]
-    jaxpr = jax.make_jaxpr(lambda q, kn, kr, v: fa._mla_fwd(
-        q, (kn, kr), v, 192 ** -0.5))(*x)
+    x = [jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+         for shape in ((1, s, 4, 128), (1, 4, s, 64), (1, s, 4, 256),
+                       (1, s, 1, 64))]
+    jaxpr = jax.make_jaxpr(lambda *a: fa._mla_fwd(
+        *fa._laid(*a), 192 ** -0.5))(*x)
     assert fa._STAT_LANES == 128
     block, _, sub = fa._values_plan(s, 128, jnp.bfloat16)
     eqns = list(_eqns(jaxpr.jaxpr))
@@ -792,13 +837,19 @@ def test_latent_dq_is_summed_in_float32_and_rounded_once(monkeypatch):
     s, h, dn, dr, dv = 512, 2, 128, 64, 128
     block = fa._values_plan(s, dv, jnp.bfloat16).block
     assert block * 4 == s
-    q, kn, kr, v, do = (
-        jnp.swapaxes(x, 1, 2).reshape(-1, s, x.shape[-1])
-        for x in _latent_operands(s, h, dn, dr, dv, jnp.bfloat16, b=1))
+    operands = _latent_operands(s, h, dn, dr, dv, jnp.bfloat16, b=1)
+    qs, ks, parts = fa._laid(*_projected(*operands[:4]))
     scale = (dn + dr) ** -0.5
-    o, lse = fa._mla_fwd(q, (kn, kr), v, scale)
-    got = fa._mla_bwd(scale, (q, (kn, kr), v, o, lse), do)[0]
-    assert got.dtype == jnp.bfloat16
+    o, lse = fa._mla_fwd(qs, ks, parts, scale)
+    (dq_nope, dq_rope), _ = fa._mla_bwd(parts, scale, (qs, ks, o, lse),
+                                        operands[4].reshape(1, s, -1))
+    assert dq_nope.dtype == dq_rope.dtype == jnp.bfloat16
+    # dq's two parts as they leave, [b, s, h dn] and [b h, s, dr]
+    got = jnp.concatenate(
+        [jnp.swapaxes(dq_nope.reshape(s, h, dn), 0, 1), dq_rope], -1)
+    q, kn, kr, v, do = (jnp.swapaxes(x, 1, 2).reshape(-1, s, x.shape[-1])
+                        for x in operands)
+    o = jnp.swapaxes(o.reshape(s, h, dv), 0, 1)
 
     f32 = functools.partial(jnp.asarray, dtype=jnp.float32)
     hi = functools.partial(jnp.einsum, precision="highest")
@@ -837,13 +888,13 @@ def test_latent_backward_counts_itself_and_sizes_its_vmem(monkeypatch):
     assert fa._fused_bwd_vmem(32768, 1024, [128, 64], 128)[0] \
         <= fa._DQ_BYTES
 
-    q, kn, kr, v, _ = _latent_operands(128, 2, 16, 8, 16)
+    a = _projected(*_latent_operands(128, 2, 16, 8, 16)[:4])
 
     def trace_backward():       # a new function each time: no cached trace
         jax.make_jaxpr(jax.grad(
-            lambda *a: fa.flash_attention_latent(*a).sum()))(q, kn, kr, v)
+            lambda *a: fa.flash_attention_latent(*a).sum()))(*a)
     assert str(jax.make_jaxpr(jax.grad(
-        lambda *a: fa.flash_attention_latent(*a).sum()))(q, kn, kr, v)
+        lambda *a: fa.flash_attention_latent(*a).sum()))(*a)
     ).count("pallas_call[") == 2
     monkeypatch.setattr(fa, "_DQ_BYTES", 128 * 24 * 4 - 1)
     with pytest.raises(ValueError, match="whole dq in VMEM"):
@@ -852,16 +903,15 @@ def test_latent_backward_counts_itself_and_sizes_its_vmem(monkeypatch):
 
 def test_latent_kernels_in_bf16_and_by_their_own_names():
     from paddle_tpu.nn import functional as F
-    q, kn, kr, v, _ = _latent_operands(256, 2, 128, 64, 128, jnp.bfloat16)
-    got = fa.flash_attention_latent(q, kn, kr, v)
-    want = F.latent_attention(*(x.astype(jnp.float32)
-                                for x in (q, kn, kr, v)))
+    a = _projected(*_latent_operands(256, 2, 128, 64, 128, jnp.bfloat16)[:4])
+    got = fa.flash_attention_latent(*a)
+    want = F.latent_attention(*(x.astype(jnp.float32) for x in a))
     assert got.dtype == jnp.bfloat16 and got.shape == (2, 256, 2, 128)
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want), atol=3e-2)
     text = jax.jit(jax.grad(lambda *a: fa.flash_attention_latent(
         *a).astype(jnp.float32).sum(), argnums=(0, 1, 2, 3))).lower(
-        q, kn, kr, v).as_text(debug_info=True)
+        *a).as_text(debug_info=True)
     for name in ("flash_mla_fwd", "flash_mla_bwd_dkv"):
         assert name in text, name
     assert "flash_mla_bwd_dq" not in text
@@ -878,13 +928,54 @@ def test_latent_attention_off_the_kernels_and_what_it_refuses():
     is the XLA path: the same numbers as the formula written out."""
     from paddle_tpu.nn import functional as F
     q, kn, kr, v, _ = _latent_operands(72, 2, 16, 8, 16)
-    got = F.latent_attention(q, kn, kr, v)
+    got = F.latent_attention(*_projected(q, kn, kr, v))
     k = jnp.concatenate([kn, jnp.broadcast_to(kr, (2, 72, 2, 8))], -1)
     want = _xla_attention(q, k, jnp.pad(v, ((0, 0),) * 3 + ((0, 8),)), None,
                           0.0, True, False, None)[..., :16]
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-6)
     with pytest.raises(ValueError, match="% 128"):
-        fa.flash_attention_latent(q, kn, kr, v)
+        fa.flash_attention_latent(*_projected(q, kn, kr, v))
     q, kn, kr, v, _ = _latent_operands(128, 2, 16, 8, 16)
     with pytest.raises(ValueError, match="key parts"):
-        fa.flash_attention_latent(q, kn, jnp.concatenate([kr, kr], 2), v)
+        fa.flash_attention_latent(
+            *_projected(q, kn, jnp.concatenate([kr, kr], 2), v))
+
+
+@pytest.mark.parametrize("dn,dr,dv", [(128, 64, 128), (16, 8, 16)],
+                         ids=["projections-layout", "head-rows"])
+def test_latent_operands_stay_where_the_projections_leave_them(dn, dr, dv):
+    """The gradient of `flash_attention_latent` as a step takes it: at
+    128 + 64 / 128 lanes no operand and no gradient of the two kernels is
+    transposed, sliced or concatenated between the projections' arrays
+    and the `pallas_call`s (q without position, kv, o and dO go as `[b,
+    s, h w]`, dq in its two parts and kv's gradient as ONE `[b, s, h
+    (dn + dv)]`); at widths that are not whole 128-lane tiles the same
+    kernels take `[b h, s, w]` operands, laid out by transposes, which is
+    what the check can see. Both kernels, two calls, either way."""
+    b, s, h = 2, 256, 3
+    q, kn, kr, v, do = _latent_operands(s, h, dn, dr, dv, b=b)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda *a: jnp.sum(fa.flash_attention_latent(*a) * do),
+        argnums=(0, 1, 2, 3)))(*_projected(q, kn, kr, v))
+    eqns = list(_eqns(jaxpr.jaxpr, kernels=False))
+    calls = [e for e in eqns if e.primitive.name == "pallas_call"]
+    assert [c.params["name"] for c in calls] == ["flash_mla_fwd",
+                                                  "flash_mla_bwd_dkv"]
+    wide = [e for e in eqns if e.primitive.name in
+            ("transpose", "slice", "concatenate")
+            and len(e.invars[0].aval.shape) == 4]
+    fwd, bwd = calls
+    if dn % 128:
+        assert any(e.primitive.name == "transpose" for e in wide)
+        assert fwd.invars[0].aval.shape == (b * h, s, dn)
+        return
+    assert wide == [], [(e.primitive.name, e.invars[0].aval.shape)
+                        for e in wide]
+    ins = [x.aval.shape for x in fwd.invars]
+    assert ins == [(b, s, h * dn), (b * h, s, dr), (b, s, h * (dn + dv)),
+                   (b, s, dr)], ins
+    assert fwd.outvars[0].aval.shape == (b, s, h * dv)
+    outs = [x.aval.shape for x in bwd.outvars]
+    assert outs == [(b, s, h * dn), (b * h, s, dr), (b, s, h * (dn + dv)),
+                    (b * h, s, dr)], outs
+    assert bwd.invars[4].aval.shape == (b, s, h * dv)     # dO

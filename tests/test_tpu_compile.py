@@ -200,19 +200,27 @@ def test_selected_grouped_kernels_compile_at_keye_widths(one_chip):
 def test_latent_kernels_compile_at_kanana_widths(one_chip, concat):
     """The kanana cell's attention at its real size: 32 heads, scores over
     192 (128 + one shared rotary head of 64), values of 128, 8192
-    positions; both ways of handing the kernels the rotary key."""
+    positions; both ways of handing the kernels the rotary key. As built,
+    the kernels read the projections' own arrays: q without position
+    `[b, s, h 128]` and kv `[b, s, h 256]` a 128- and a 256-lane block a
+    head, strided along lanes."""
     b, s, h, dn, dr, dv = 2, 8192, 32, 128, 64, 128
 
     def shape(*dims):
         return jax.ShapeDtypeStruct(dims, jnp.bfloat16, sharding=one_chip)
 
     def one_part(q, kn, kr, v):
-        # step 0's other variant (tools/flash_mla_step0.py): the same
-        # kernels, the key concatenated beforehand to one part of 192
+        # the other way of handing the kernels the rotary key: the key
+        # concatenated beforehand to one part of 192, [b h, s, w] operands
         def to3(x):
             return jnp.swapaxes(x, 1, 2).reshape(-1, s, x.shape[-1])
         k = jnp.concatenate([kn, jnp.broadcast_to(kr, (b, s, h, dr))], -1)
-        return fa._mla3(to3(q), (to3(k),), to3(v), (dn + dr) ** -0.5)
+        one = fa.Lay()
+        parts = fa.Parts(q_lays=(one,), k_lays=(one, one),
+                         q=((0, 0, dn + dr),), k=((0, 0, dn + dr),),
+                         v=(1, 0, dv))
+        return fa._mla3((to3(q),), (to3(k), to3(v)), parts,
+                        (dn + dr) ** -0.5)
 
     def loss_and_grads(*args):
         latent = one_part if concat else fa.flash_attention_latent
@@ -220,9 +228,13 @@ def test_latent_kernels_compile_at_kanana_widths(one_chip, concat):
             lambda *a: latent(*a).astype(jnp.float32).sum(),
             argnums=(0, 1, 2, 3))(*args)
 
-    text = compiled_text(loss_and_grads, shape(b, s, h, dn + dr),
-                         shape(b, s, h, dn), shape(b, s, 1, dr),
-                         shape(b, s, h, dv))
+    if concat:
+        args = (shape(b, s, h, dn + dr), shape(b, s, h, dn),
+                shape(b, s, 1, dr), shape(b, s, h, dv))
+    else:
+        args = (shape(b, s, h, dn), shape(b, h, s, dr),
+                shape(b, s, h, dn + dv), shape(b, s, 1, dr))
+    text = compiled_text(loss_and_grads, *args)
     assert text.count("tpu_custom_call") == 2
     for name in ("flash_mla_fwd", "flash_mla_bwd_dkv"):
         assert name in text, name
@@ -281,8 +293,8 @@ def test_dispatch_shards_latent_kernels_over_four_chips(topo, monkeypatch):
     monkeypatch.setattr(topology, "_GLOBAL_MESH", None)
     mesh = build_mesh(dp=2, mp=2, devices=list(topo.devices))
 
-    def shape(heads, width, spec=P("data", None, "model", None)):
-        return jax.ShapeDtypeStruct((2, 8192, heads, width), jnp.bfloat16,
+    def shape(*dims, spec=P("data", None, "model", None)):
+        return jax.ShapeDtypeStruct(dims, jnp.bfloat16,
                                     sharding=NamedSharding(mesh, spec))
 
     def loss_and_grads(*args):
@@ -290,8 +302,12 @@ def test_dispatch_shards_latent_kernels_over_four_chips(topo, monkeypatch):
             lambda *a: A.latent_attention(*a).astype(jnp.float32).sum(),
             argnums=(0, 1, 2, 3))(*args)
 
-    text = compiled_text(loss_and_grads, shape(32, 192), shape(32, 128),
-                         shape(1, 64, P("data")), shape(32, 128))
+    # the projections' arrays: q without position, the rotary query part
+    # head-major, kv whole, the one rotary key head
+    text = compiled_text(loss_and_grads, shape(2, 8192, 32, 128),
+                         shape(2, 32, 8192, 64, spec=P("data", "model")),
+                         shape(2, 8192, 32, 256),
+                         shape(2, 8192, 1, 64, spec=P("data")))
     assert text.count("tpu_custom_call") == 2
     for name in ("flash_mla_fwd", "flash_mla_bwd_dkv"):
         assert name in text, name

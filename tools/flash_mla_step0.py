@@ -10,39 +10,32 @@ chip:
 
 1. `--kernels latent` (or `1`): the latent flash kernels
    (`profiler.MLA_KERNELS`: since ISSUE 34 the forward and ONE backward,
-   dq out of the dk/dv walk) at the kanana cell's widths (bf16 [2, 8192,
-   32, 192] queries, keys as 32 x 128 + ONE rotary head of 64, values 32
-   x 128); `--kernels selected`: the selected ones (`profiler.SEL_KERNELS`:
-   since ISSUE 35 likewise two) at the Keye cell's (32 query heads over
-   `--kv-heads` 4 of 128, an int8 selection a row that keeps 2048 keys of
-   a query's causal ones, 43.75 % of the causal pairs at 8192). Forward
-   and backward, as the layer scan of a step runs them: a `scan` of
-   `--layers` calls of value-and-gradient. Ms a call by the device's
-   clock: each kernel's own events and the whole program, which holds
-   what XLA does around them (a concatenation and its transpose, the sum
-   over heads, the selection's transpose). Every variant's loss and
-   gradient norms against the first's.
-   VARIANTS of the latent family rank how the kernels are handed the
-   rotary key: read through the index map `b // 32` with two score
-   products summed in the kernel and `dk_rope` added up over the heads
-   outside ("index"), or concatenated in HBM to 192 a head beforehand
-   ("concat"); and the resident block: `_values_plan`'s, sized by the
-   values' 128 lanes (1024 rows at 8192 tokens), or `_plan`'s by the
-   scores' 192, which pad to 256 (512 rows: "_by_score_width"). The
-   selected family has one row, "as_built". (Where the fused backward
-   holds the head's dq was ranked twice and is settled: transposed, `[w,
-   rows]`, with the share kT dst; `[rows, w]` with the share dstT k read
-   5 % slower at the latent widths and the same at the selected ones:
-   PERF.md, PRs 34 and 35. Where the forward keeps its running
-   statistics was ranked in ISSUE 36 and is settled too: replicated over
-   a vreg's 128 lanes, q along sublanes; columns `[rows, 1]` read 13.80
-   ms a call for 11.18, the scores transposed 13.14: PERF.md, PR 36. The
-   forward as it stays is every row's `flash_mla_fwd`; the parent's is
-   `--checkout <parent>`.)
+   dq out of the dk/dv walk) at the kanana cell's widths, on the arrays
+   the projections make (bf16 q [2, 8192, 32 x 192], kv [2, 8192, 32 x
+   (128 + 128)], ONE rotary key head of 64), handed to the kernels as
+   the tree's own `DeepseekV3Attention.forward` hands them: the rotary
+   turn, the slices and the layout of each operand (q without position,
+   kv, o and dO stay `[b, s, h w]` and the rotary query part goes
+   head-major; a tree whose entry takes q whole lays every operand out
+   `[b h, s, w]` inside it), so the program column holds what XLA does
+   around the kernels in that tree; `--kernels selected`: the selected
+   ones (`profiler.SEL_KERNELS`: likewise two) at the Keye
+   cell's (32 query heads over `--kv-heads` 4 of 128, an int8 selection a
+   row that keeps 2048 keys of a query's causal ones, 43.75 % of the
+   causal pairs at 8192). Forward and backward, as the layer scan of a
+   step runs them: a `scan` of `--layers` calls of value-and-gradient.
+   Ms a call by the device's clock: each kernel's own events and the
+   whole program, which holds what XLA does around them (the layouts, the
+   sum over heads, the selection's transpose), and the loss's and the
+   gradients' L1 norms.
+   (How the kernels are handed the rotary key, through the index map or
+   concatenated in HBM, the resident block by the values' width or the
+   scores', where the fused backward holds the head's dq and where the
+   forward keeps its running statistics were ranked before, PERF.md
+   section 6. Each is settled and is what the kernels do.)
    `--checkout DIR` reads the same table from another tree's kernels
-   (the parent's, unpacked by `git archive`): what that tree has no
-   switch for is one row, "as_built", under the kernel names it has (an
-   unfused tree's dq and dk/dv kernels each).
+   (the parent's, unpacked by `git archive`), under the kernel names it
+   has (an unfused tree's dq and dk/dv kernels each).
 2. `--step full,dots`: the cell's whole step as the benchmark builds it,
    once for each remat policy named: compiled (or refused: the
    compiler's message is the row), `--steps` steps by the host's clock
@@ -63,8 +56,8 @@ from __future__ import annotations
 import argparse
 import functools
 import gc
+import inspect
 import json
-import math
 import os
 import re
 import sys
@@ -102,44 +95,35 @@ def device_trace(fn, reps: int) -> dict:
         shutil.rmtree(where, ignore_errors=True)
 
 
-def latent(q, k_nope, k_rope, v, concat: bool):
-    """The production entry, or variant (a): the key laid out in HBM as
-    one [b, s, h, dn + dr] with the rotary head broadcast, one part of
-    the full width through the same kernels (`fa._mla3`)."""
-    if not concat:
-        return fa.flash_attention_latent(q, k_nope, k_rope, v)
-    b, s, h, d = q.shape
-
-    def to3(x):
-        return jnp.swapaxes(x, 1, 2).reshape(-1, s, x.shape[-1])
-    k = jnp.concatenate([k_nope, jnp.broadcast_to(
-        k_rope, k_nope.shape[:-1] + k_rope.shape[-1:])], -1)
-    o3 = fa._mla3(to3(q), (to3(k),), to3(v), 1.0 / math.sqrt(d))
-    return jnp.swapaxes(o3.reshape(b, h, s, -1), 1, 2)
-
-
-def plan_name() -> str:
-    """What the tree under `--checkout` calls the plan by the values'
-    width (`_mla_plan` before PR 35)."""
-    return "_values_plan" if hasattr(fa, "_values_plan") else "_mla_plan"
-
-
 def latent_family(args):
     """(kernel names, differentiable operands' shapes, the output's,
-    further operands of a layer from a key, [(variant, attributes of `fa`
-    to set, the call)])."""
+    further operands of a layer from a key, the call)."""
+    from paddle_tpu.nn import functional as F
     from paddle_tpu.profiler import MLA_KERNELS
     b, s, h = 2, args.seq, args.heads
     dn, dr, dv = args.widths
-    by_score_width = {plan_name(): lambda s_, dv_, dtype: fa._plan(
-        s_, dn + dr, dtype, True)}
-    index, concat = (functools.partial(latent, concat=c)
-                     for c in (False, True))
-    variants = [("index", {}, index), ("concat", {}, concat),
-                ("index_by_score_width", by_score_width, index),
-                ("concat_by_score_width", by_score_width, concat)]
-    return (MLA_KERNELS, [(b, s, h, dn + dr), (b, s, h, dn), (b, s, 1, dr),
-                          (b, s, h, dv)], (b, s, h, dv), None, variants)
+    # an older tree's entry takes q whole and k_nope, v apart
+    whole_q = "query" in inspect.signature(
+        fa.flash_attention_latent).parameters
+
+    def rotary(x):
+        return F.rotary_embedding(x, 1e6, interleaved=True)
+
+    def call(q, kv, k_rope):
+        # what `DeepseekV3Attention.forward` of that tree does between the
+        # projections and `o_proj`
+        q, kv = q.reshape(b, s, h, dn + dr), kv.reshape(b, s, h, dn + dv)
+        if whole_q:
+            o = fa.flash_attention_latent(
+                jnp.concatenate([q[..., :dn], rotary(q[..., dn:])], -1),
+                kv[..., :dn], rotary(k_rope), kv[..., dn:])
+        else:
+            o = fa.flash_attention_latent(
+                q[..., :dn], jnp.swapaxes(rotary(q[..., dn:]), 1, 2), kv,
+                rotary(k_rope))
+        return o.reshape(b, s, h * dv)
+    return (MLA_KERNELS, [(b, s, h * (dn + dr)), (b, s, h * (dn + dv)),
+                          (b, s, 1, dr)], (b, s, h * dv), None, call)
 
 
 def selected_family(args):
@@ -158,14 +142,14 @@ def selected_family(args):
         return fa.flash_attention(q, k, v, causal=True, selection=sel)
     return (SEL_KERNELS, [(b, s, h, d), (b, s, args.kv_heads, d),
                           (b, s, args.kv_heads, d)], (b, s, h, d),
-            selection, [("as_built", {}, call)])
+            selection, call)
 
 
 FAMILIES = {"latent": latent_family, "selected": selected_family}
 
 
 def kernel_table(args, rows: list, family: str) -> None:
-    kernels, shapes, out_shape, further, variants = FAMILIES[family](args)
+    kernels, shapes, out_shape, further, call = FAMILIES[family](args)
     shapes = shapes + [out_shape]
 
     def operands(key):
@@ -175,61 +159,41 @@ def kernel_table(args, rows: list, family: str) -> None:
                 further(keys[-1]) if further else ())
     (*stacks, dos), more = jax.jit(lambda keys: jax.lax.map(operands, keys))(
         jax.random.split(jax.random.key(args.seed), args.layers))
-    first = None
-    for name, patches, call in variants:
-        kept = {k: getattr(fa, k) for k in patches}
-        for k, v in patches.items():
-            setattr(fa, k, v)
-        plan = list(getattr(fa, plan_name())(args.seq, out_shape[-1],
-                                             jnp.bfloat16))
+    width = args.widths[2] if family == "latent" else args.widths[0]
 
-        def layers(*diff):
-            # the gradient is taken outside the scan, as a step takes it:
-            # a backward loop of its own, and the kernels under the names
-            # a step gives them
-            def one(xs):
-                *a, do, extra = xs
-                return jnp.sum(call(*a, *((extra,) if further else ()))
-                               .astype(jnp.float32) * do.astype(jnp.float32))
-            return jnp.sum(jax.lax.map(one, (*diff, dos, more)))
-        fn = jax.jit(lambda xs: jax.value_and_grad(
-            layers, argnums=tuple(range(len(xs))))(*xs))
-        t = time.perf_counter()
-        try:
-            out = jax.block_until_ready(fn(stacks))
-            dev = device_trace(lambda: fn(stacks), args.reps)
-        except Exception as e:  # a variant the compiler refuses is a row
-            log({"what": "kernels", "family": family, "variant": name,
-                 "error": str(e)[:300]}, rows)
-            continue
-        finally:
-            for k, v in kept.items():
-                setattr(fa, k, v)
-        calls = args.reps * args.layers
-        row = {"what": "kernels", "family": family, "variant": name,
-               "plan": plan,
-               "program_ms_a_call":
-                   1e3 * sum(e - s0 for s0, e, _ in dev["modules"]) / calls}
-        if further:
-            row["selection_keeps_of_causal"] = float(
-                jnp.sum(more[0], dtype=jnp.float32)
-                / (more[0].shape[0] * args.seq * (args.seq + 1) / 2))
-        for kernel in kernels:
-            hits = [e - s0 for s0, e, n in dev["ops"]
-                    if re.match(rf"%{kernel}(\.[.\w]*)? = ", n)]
-            row[kernel + "_ms_a_call"] = 1e3 * sum(hits) / calls
-        # the loss and the L1 norm of each gradient: what a variant is
-        # held to the first by (the arrays themselves do not fit twice)
-        sums = [float(jnp.sum(jnp.abs(x.astype(jnp.float32))))
-                for x in jax.tree.leaves(out)]
-        if first is None:
-            first = sums
-        else:
-            row["max_gap_to_first"] = max(
-                abs(a - b) / max(abs(b), 1e-30) for a, b in zip(sums, first))
-        row["compile_and_runs_s"] = time.perf_counter() - t
-        log(row, rows)
-        del out
+    def layers(*diff):
+        # the gradient is taken outside the scan, as a step takes it: a
+        # backward loop of its own, and the kernels under the names a step
+        # gives them
+        def one(xs):
+            *a, do, extra = xs
+            return jnp.sum(call(*a, *((extra,) if further else ()))
+                           .astype(jnp.float32) * do.astype(jnp.float32))
+        return jnp.sum(jax.lax.map(one, (*diff, dos, more)))
+    fn = jax.jit(lambda xs: jax.value_and_grad(
+        layers, argnums=tuple(range(len(xs))))(*xs))
+    t = time.perf_counter()
+    out = jax.block_until_ready(fn(stacks))
+    dev = device_trace(lambda: fn(stacks), args.reps)
+    calls = args.reps * args.layers
+    row = {"what": "kernels", "family": family,
+           "plan": list(fa._values_plan(args.seq, width, jnp.bfloat16)),
+           "program_ms_a_call":
+               1e3 * sum(e - s0 for s0, e, _ in dev["modules"]) / calls}
+    if further:
+        row["selection_keeps_of_causal"] = float(
+            jnp.sum(more[0], dtype=jnp.float32)
+            / (more[0].shape[0] * args.seq * (args.seq + 1) / 2))
+    for kernel in kernels:
+        hits = [e - s0 for s0, e, n in dev["ops"]
+                if re.match(rf"%{kernel}(\.[.\w]*)? = ", n)]
+        row[kernel + "_ms_a_call"] = 1e3 * sum(hits) / calls
+    # the loss and the L1 norm of each gradient: what two trees' rows are
+    # held to each other by (the arrays themselves do not fit twice)
+    row["l1_norms"] = [float(jnp.sum(jnp.abs(x.astype(jnp.float32))))
+                       for x in jax.tree.leaves(out)]
+    row["compile_and_runs_s"] = time.perf_counter() - t
+    log(row, rows)
 
 
 def cell_spec(args):
