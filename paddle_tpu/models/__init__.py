@@ -37,6 +37,12 @@ from .deepseek_v3 import (  # noqa: F401
     DeepseekV3Model,
     deepseek_v3_tiny,
 )
+from .afmoe import (  # noqa: F401
+    AfmoeConfig,
+    AfmoeForCausalLM,
+    AfmoeModel,
+    afmoe_tiny,
+)
 from .bert import (  # noqa: F401
     BertConfig,
     BertForPretraining,

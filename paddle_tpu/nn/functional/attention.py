@@ -24,22 +24,29 @@ from ...profiler import ATTENTION
 @jax.named_scope(ATTENTION)   # mask handling, layout changes, the kernel
 def scaled_dot_product_attention(query, key, value, attn_mask=None,
                                  dropout_p=0.0, is_causal=False,
-                                 training=True, scale=None):
+                                 training=True, scale=None, window=None):
     """query/key/value: [batch, seq, heads, head_dim] (paddle 2.x layout).
 
     attn_mask: broadcastable to [batch, heads, q_len, k_len]; boolean (True =
-    keep) or additive float.
+    keep) or additive float. window (with is_causal): query position p
+    reads the keys p - window + 1 .. p alone, a sliding window; no
+    attn_mask beside it.
     """
+    if window is not None and (not is_causal or attn_mask is not None):
+        raise NotImplementedError(
+            f"window={window}: a sliding window under is_causal=True, "
+            f"without attn_mask")
     if flag("enable_pallas_kernels") and dropout_p == 0.0 \
             and _pallas_ok(query, key, is_causal):
         kv_mask = _as_kv_mask(attn_mask, query.shape[0], key.shape[1]) \
             if attn_mask is not None else None
         if attn_mask is None or kv_mask is not None:
-            return _flash(query, key, value, is_causal, scale, kv_mask)
+            return _flash(query, key, value, is_causal, scale, kv_mask,
+                          window)
         _log_fallback("attn_mask is not a [b,1,1,k] bool/int k-side "
                       "padding mask")
     return _xla_attention(query, key, value, attn_mask, dropout_p, is_causal,
-                          training, scale)
+                          training, scale, window)
 
 
 # mesh axes the per-shard kernel was compiled and run for (PR 23: AOT for
@@ -76,7 +83,7 @@ def _mesh_shards(query):
     return mesh, batch, head
 
 
-def _flash(query, key, value, causal, scale, kv_mask):
+def _flash(query, key, value, causal, scale, kv_mask, window=None):
     """The Pallas kernel, per shard under a multi-device mesh: GSPMD
     cannot partition a Mosaic kernel ("Mosaic kernels cannot be
     automatically partitioned"), so each device runs it on its own
@@ -86,7 +93,7 @@ def _flash(query, key, value, causal, scale, kv_mask):
     shards = _mesh_shards(query)
     if shards is None:
         return flash_attention(query, key, value, causal=causal,
-                               scale=scale, kv_mask=kv_mask)
+                               scale=scale, kv_mask=kv_mask, window=window)
     from jax.sharding import PartitionSpec as P
     mesh, batch, head = shards
     qkv = P(batch, None, head, None)
@@ -97,7 +104,7 @@ def _flash(query, key, value, causal, scale, kv_mask):
 
     def local(q, k, v, m=None):
         return flash_attention(q, k, v, causal=causal, scale=scale,
-                               kv_mask=m)
+                               kv_mask=m, window=window)
 
     # manual over EVERY mesh axis: Mosaic refuses a partly-manual context
     return jax.shard_map(local, mesh=mesh, in_specs=tuple(specs),
@@ -274,19 +281,21 @@ def latent_attention(q_nope, q_rope, kv, k_rope, scale=None):
 
 
 def _xla_attention(query, key, value, attn_mask, dropout_p, is_causal,
-                   training, scale):
+                   training, scale, window=None):
     q_len, k_len = query.shape[1], key.shape[1]
     head_dim = query.shape[-1]
     scale = scale if scale is not None else 1.0 / math.sqrt(head_dim)
-    # [b, s, h, d] -> [b, h, s, d]
+    # [b, s, h, d] -> [b, h, s, d]; fewer key/value heads are repeated
     q = jnp.swapaxes(query, 1, 2)
-    k = jnp.swapaxes(key, 1, 2)
-    v = jnp.swapaxes(value, 1, 2)
+    k = jnp.swapaxes(_repeat_kv(key, query.shape[2]), 1, 2)
+    v = jnp.swapaxes(_repeat_kv(value, query.shape[2]), 1, 2)
     # score accumulation in fp32 for bf16 inputs (MXU native mixed precision)
     scores = jnp.einsum("bhqd,bhkd->bhqk", q, k,
                         preferred_element_type=jnp.float32) * scale
     if is_causal:
         causal = jnp.tril(jnp.ones((q_len, k_len), dtype=bool))
+        if window is not None:
+            causal = causal & ~jnp.tril(causal, -window)
         scores = jnp.where(causal, scores, -jnp.inf)
     if attn_mask is not None:
         if attn_mask.dtype == jnp.bool_:
@@ -299,3 +308,10 @@ def _xla_attention(query, key, value, attn_mask, dropout_p, is_causal,
         probs = _dropout(probs, p=dropout_p, training=True)
     out = jnp.einsum("bhqk,bhkd->bhqd", probs, v)
     return jnp.swapaxes(out, 1, 2)
+
+
+def _repeat_kv(x, heads: int):
+    """[b, s, h_kv, d] -> [b, s, heads, d]: query head i reads key/value
+    head i // (heads / h_kv), as the flash kernels' index maps do."""
+    h_kv = x.shape[2]
+    return x if h_kv == heads else jnp.repeat(x, heads // h_kv, axis=2)

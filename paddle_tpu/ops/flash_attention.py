@@ -40,6 +40,18 @@ statistics are fp32. Row statistics (logsumexp/delta) ride an 8-lane
 broadcast between forward and dq because TPU block layouts need a
 lane-divisible trailing dim.
 
+A sliding window (`window=`: query p reads keys p - window + 1 .. p) is a
+second diagonal of the causal family, `window` positions below the first.
+The kernels (`flash_win_fwd`, `flash_win_bwd_dq`, `flash_win_bwd_dkv`)
+keep the causal walk and add that edge at every level: the grid's reduce
+axis holds only the blocks the band meets (`_band_blocks` + 1, a static
+offset between the out block and the reduce block at each step, `_specs`
+`band`), so a block below the band costs no DMA and no grid step; inside a
+block `_chunk_spans` leaves out the chunks below it, `_groups` starts each
+group's reduce rows at its lower edge, and `_keep_tri` masks the groups
+that edge crosses. At 8192 tokens, 1024-row blocks and a window of 2048 a
+query block meets 3 of the 8 key blocks (`score_work`).
+
 Latent attention (further down) and attention under a per-pair selection
 have two kernels, not three: their backward is the dk/dv walk alone
 (`_fused_bwd`), and dq comes out of it, because a dq kernel of its own
@@ -85,7 +97,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ..profiler import (FLASH_BWD_DKV, FLASH_BWD_DQ, FLASH_FWD,
                         FLASH_MLA_BWD_DKV, FLASH_MLA_FWD, FLASH_SEL_BWD_DKV,
-                        FLASH_SEL_FWD)
+                        FLASH_SEL_FWD, FLASH_WIN_BWD_DKV, FLASH_WIN_BWD_DQ,
+                        FLASH_WIN_FWD)
 
 # Row statistics (lse/delta) ride an 8-lane broadcast: TPU block layouts
 # need the last two dims (sublane, lane) to divide (8, 128) or equal the
@@ -155,58 +168,112 @@ _GROUP_SCORES = 256 * 1024
 _CAUSAL_SUB = 256
 
 
-def _chunk_spans(o0, c, n, causal, before):
+def _chunk_spans(o0, c, n, causal, before, window=None, shift=0):
     """The reduce-side chunks (of `c` rows, `n` in all) that the out-side
-    rows [o0, o0 + c) visit: (full_lo, full_hi, diag_lo, diag_hi), two
-    half-open ranges of chunk indices — chunks clear of the diagonal, and
-    the one it crosses. `before`: the reduce side is k and the out side q
-    (forward, dq), so the full chunks lie before the diagonal; else the
-    reduce side is q (dk/dv) and they lie after it. The kernels' walk and
-    `score_work` both come from here."""
+    rows [o0, o0 + c) meet, in rising order: (j, delta), `delta` the q
+    chunk's first position less the k chunk's, None without a causal mask
+    (every chunk, nothing masked). `before`: the reduce side is k and the
+    out side q (forward, dq); else the reduce side is q (dk/dv). `shift`:
+    the q block's first position less the k block's, so that `delta` is
+    in positions of the sequence. Under the causal mask a chunk wholly above the
+    diagonal is left out, and under a `window` one wholly below the band's
+    lower edge, `window` positions back (query p reads keys p - window + 1
+    .. p). The kernels' walk and `score_work` both come from here."""
     if not causal:
-        return 0, n, 0, 0
-    d = o0 // c
-    return (0, d, d, d + 1) if before else (d + 1, n, d, d + 1)
+        return [(j, None) for j in range(n)]
+    out = []
+    for j in range(n):
+        delta = shift + o0 - j * c if before else shift + j * c - o0
+        if delta <= -c or (window is not None and delta - c + 1 >= window):
+            continue
+        out.append((j, delta))
+    return out
 
 
-def _groups(c, sub, before, diag):
-    """A chunk (c x c) cut into groups of `sub` out-side rows: (out_lo,
-    red_lo, red_hi) — out rows [out_lo, out_lo + sub) against the reduce
-    rows [red_lo, red_hi). In the chunk the diagonal crosses (its corner
-    lies on it) a group needs nothing beyond its own diagonal. Static, so
-    a chunk's body is straight-line code."""
-    if not diag:
-        return [(g, 0, c) for g in range(0, c, sub)]
-    return [(g, 0, g + sub) if before else (g, g, c)
-            for g in range(0, c, sub)]
+def _diffs(rows, cols, off, before):
+    """(least, most) of q - k over a group's [rows, cols] scores whose
+    mask offset is `off` (`_keep_tri`)."""
+    if before:
+        return off - cols + 1, off + rows - 1
+    return off - rows + 1, off + cols - 1
+
+
+def _groups(c, sub, before, delta, window=None):
+    """A chunk pair (c x c, `_chunk_spans`' delta) cut into groups of
+    `sub` out-side rows: (out_lo, red_lo, red_hi, off) — out rows [out_lo,
+    out_lo + sub) against the reduce rows [red_lo, red_hi), and the offset
+    `_keep_tri` masks them by, None where the group keeps every pair. A
+    group needs nothing beyond its own diagonal and, under a window,
+    nothing before its band's lower edge; its reduce rows are whole groups
+    of `sub`, and a group that needs none is left out. Static, so a
+    chunk's body is straight-line code."""
+    if delta is None:
+        return [(g, 0, c, None) for g in range(0, c, sub)]
+    out = []
+    for g in range(0, c, sub):
+        if before:      # q rows g.., k rows with q - window < k <= q
+            lo = 0 if window is None else delta + g - window + 1
+            hi = delta + g + sub
+        else:           # k rows g.., q rows with k <= q < k + window
+            lo = g - delta
+            hi = c if window is None else g + sub - 1 - delta + window
+        lo = min(max(lo // sub * sub, 0), c)
+        hi = min(max(-(-hi // sub) * sub, 0), c)
+        if lo >= hi:
+            continue
+        off = delta + g - lo if before else delta + lo - g
+        least, most = _diffs(sub, hi - lo, off, before)
+        masked = least < 0 or (window is not None and most >= window)
+        out.append((g, lo, hi, off if masked else None))
+    return out
 
 
 class ScoreWork(NamedTuple):
     chunks: int         # chunk bodies one head runs
-    masked_chunks: int  # of them, the body that applies the causal mask
+    masked_chunks: int  # of them, bodies that apply a mask
     ratio: float        # computed / needed score elements
+    needed: int         # score elements one head needs
 
 
 def score_work(s: int, causal: bool, d: int = 64, dtype=jnp.bfloat16,
-               transposed: bool = False) -> ScoreWork:
+               transposed: bool = False, window=None) -> ScoreWork:
     """What one head costs under `_plan`'s schedule, counted from the same
     spans and groups the kernels are built from. `transposed`: the dk/dv
-    walk (k chunks over q chunks) instead of the forward's and dq's."""
+    walk (k chunks over q chunks) instead of the forward's and dq's.
+    `window`: the causal band of that many keys a query (`flash_attention`
+    `window=`). A windowed kernel's grid visits the k blocks the band
+    meets (`_band_blocks`) and, inside them, these chunks."""
     _, c, sub = _plan(s, d, dtype, causal)
     before = not transposed
-    full = diag = 0
+    chunks = masked = computed = 0
     for o0 in range(0, s, c):
-        f_lo, f_hi, d_lo, d_hi = _chunk_spans(o0, c, s // c, causal, before)
-        full += f_hi - f_lo
-        diag += d_hi - d_lo
+        for _, delta in _chunk_spans(o0, c, s // c, causal, before, window):
+            groups = _groups(c, sub, before, delta, window)
+            chunks += 1
+            masked += any(off is not None for *_, off in groups)
+            computed += sum(sub * (hi - lo) for _, lo, hi, _ in groups)
+    if not causal:
+        needed = s * s
+    elif window is None or window >= s:
+        needed = s * (s + 1) // 2
+    else:
+        needed = window * (window + 1) // 2 + (s - window) * window
+    return ScoreWork(chunks, masked, computed / needed, needed)
 
-    def scores(on_diag):
-        return sum(sub * (hi - lo)
-                   for _, lo, hi in _groups(c, sub, before, on_diag))
 
-    computed = full * scores(False) + diag * scores(True)
-    needed = s * (s + 1) // 2 if causal else s * s
-    return ScoreWork(full + diag, diag, computed / needed)
+def _band_blocks(window: int, block: int, n: int) -> int:
+    """How many k blocks before its own a q block's band reaches: the
+    first query of a block reads back to `window` - 1 positions."""
+    return min(n - 1, -(-(window - 1) // block))
+
+
+def window_kblocks(s: int, d: int, dtype, window: int) -> int:
+    """The key blocks one query block's grid steps visit under a window
+    (the windowed kernels' reduce axis): its own and the `_band_blocks`
+    before it, of `_plan`'s resident blocks. 3 of 8 at 8192 tokens of
+    head dim 128 in bf16 and a window of 2048."""
+    block = _plan(s, d, dtype, True).block
+    return _band_blocks(window, block, s // block) + 1
 
 
 def _interpret() -> bool:
@@ -236,28 +303,42 @@ def _rows(ref, start, size):
     return ref[0, pl.ds(start, size), :]
 
 
-def _keep_tri(rows, cols, off, before):
-    """The causal mask of a diagonal group: keep where q_pos >= k_pos.
-    `before`: q along rows from `off`, k along columns from 0; else k
-    along rows and q along columns from the same position."""
+def _keep_tri(rows, cols, off, before, window=None):
+    """The mask of a group (`_groups`): keep where 0 <= q - k, and q - k <
+    `window` under a window. `before`: q along rows, k along columns, q -
+    k = row + off - column; else k along rows and q along columns, q - k =
+    column + off - row. Only an edge that crosses the group is built."""
     a = jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 0)
     b = jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 1)
-    return a + off >= b if before else b >= a
+    q, k = (a, b) if before else (b, a)
+    least, most = _diffs(rows, cols, off, before)
+    keep = None
+    if least < 0:
+        keep = q + off >= k if before or off else q >= k
+    if window is not None and most >= window:
+        band = k > q + (off - window)
+        keep = band if keep is None else keep & band
+    return keep
 
 
 def _walk(plan, n, causal, before, out_id, red_id, init, scratch, prep,
-          piece, finalize):
+          piece, finalize, window=None):
     """The skeleton the three kernels share. The resident out block is cut
     into chunks; each takes its carry (from `scratch` where the reduce
     side has more than one block, else `init`: (fill, width) per value),
     meets the resident reduce chunks `_chunk_spans` names in rising
     order, group by group (`_groups`), through `piece(ctx, j, g, lo, hi,
-    tri, rows)` -> the group's new rows of the carry (`tri`: apply the
-    causal mask), and hands the carry to `finalize(ctx, r, carry)` after
-    its last reduce block. `ctx = prep(r)` is what a chunk of out rows
-    needs once. Of several reduce blocks under a causal mask only the one
-    on the diagonal is walked causally and the ones clear of it in full,
-    so the spans inside a block are static either way."""
+    off, rows)` -> the group's new rows of the carry (`off`: the mask's
+    offset, None for none), and hands the carry to `finalize(ctx, r,
+    carry)` after its last reduce block. `ctx = prep(r)` is what a chunk
+    of out rows needs once. Of several reduce blocks under a causal mask
+    only the one on the diagonal is walked causally and the ones clear of
+    it in full, so the spans inside a block are static either way. Under
+    a `window` the grid's reduce axis counts the blocks the band meets
+    (`_band_blocks` + 1, `_specs`), and each step's offset between the
+    out block and the reduce block is static too: the forward and dq meet
+    k blocks out_id - m .. out_id, dk/dv q blocks out_id .. out_id + m,
+    and a step whose block lies outside the sequence computes nothing."""
     block, c, sub = plan
     if n > 1:
         @pl.when(red_id == 0)
@@ -265,25 +346,27 @@ def _walk(plan, n, causal, before, out_id, red_id, init, scratch, prep,
             for ref, (fill, _) in zip(scratch, init):
                 ref[:] = jnp.full_like(ref, fill)
 
-    def meet(ctx, diag, j, carry):
-        parts = [piece(ctx, j, g, lo, hi, diag,
-                       tuple(x[g:g + sub] for x in carry))
-                 for g, lo, hi in _groups(c, sub, before, diag)]
+    def meet(ctx, j, delta, carry):
+        kept = {g: rest for g, *rest in _groups(c, sub, before, delta,
+                                                window)}
+        parts = []
+        for g in range(0, c, sub):
+            rows = tuple(x[g:g + sub] for x in carry)
+            parts.append(piece(ctx, j, g, *kept[g], rows) if g in kept
+                         else rows)
         return tuple(jnp.concatenate(xs, axis=0) for xs in zip(*parts))
 
-    def out_chunk(r, on_diag, last):
+    def out_chunk(r, shift, last):
         ctx = prep(r)
-        f_lo, f_hi, d_lo, d_hi = _chunk_spans(r, c, block // c, on_diag,
-                                              before)
+        spans = _chunk_spans(r, c, block // c, shift is not None, before,
+                             window, shift or 0)
         if n > 1:
             carry = tuple(ref[pl.ds(r, c), :] for ref in scratch)
         else:
             carry = tuple(jnp.full((c, w), fill, jnp.float32)
                           for fill, w in init)
-        spans = [(f_lo, f_hi, False), (d_lo, d_hi, True)]
-        for lo, hi, diag in spans if before else spans[::-1]:
-            for j in range(lo, hi):
-                carry = meet(ctx, diag, j, carry)
+        for j, delta in spans:
+            carry = meet(ctx, j, delta, carry)
         if n > 1:
             for ref, x in zip(scratch, carry):
                 ref[pl.ds(r, c), :] = x
@@ -293,21 +376,37 @@ def _walk(plan, n, causal, before, out_id, red_id, init, scratch, prep,
         else:
             pl.when(last)(lambda: finalize(ctx, r, carry))
 
-    def walk(on_diag, last):
+    def walk(shift, last):
+        """`shift`: the q block's first position less the k block's, None
+        without a causal mask."""
         for r in range(0, block, c):
-            out_chunk(r, on_diag, last)
+            out_chunk(r, shift, last)
 
-    if n == 1:
-        walk(causal, True)
-    elif not causal:
-        walk(False, red_id == n - 1)
-    else:
+    if not causal:
+        walk(None, True if n == 1 else red_id == n - 1)
+    elif n == 1:
+        walk(0, True)
+    elif window is None:
         # forward and dq end a q block at its diagonal k block; dk/dv
-        # begins a k block there and ends at the last q block
+        # begins a k block there and ends at the last q block. A block
+        # clear of the diagonal is walked as one a block away.
         pl.when(red_id == out_id)(
-            lambda: walk(True, True if before else red_id == n - 1))
+            lambda: walk(0, True if before else red_id == n - 1))
         pl.when(red_id < out_id if before else red_id > out_id)(
-            lambda: walk(False, False if before else red_id == n - 1))
+            lambda: walk(block, False if before else red_id == n - 1))
+    else:
+        m = _band_blocks(window, block, n)
+        for j in range(m + 1):
+            if before:      # k block out_id - m + j; the last is out_id
+                real = red_id == j
+                if j < m:
+                    real = real & (out_id >= m - j)
+                shift, last = (m - j) * block, j == m
+            else:           # q block out_id + j, up to the last block
+                real = (red_id == j) & (out_id + j <= n - 1)
+                shift = j * block
+                last = True if j == m else out_id + j == n - 1
+            pl.when(real)(functools.partial(walk, shift, last))
 
 
 # ---------------------------------------------------------------- forward
@@ -321,7 +420,7 @@ def _selected(sel_ref, row0, rows, col0, cols):
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, *refs, causal, scale, plan, n, masked,
-                selected=False):
+                selected=False, window=None):
     refs = list(refs)
     mask_ref = refs.pop(0) if masked else None
     sel_ref = refs.pop(0) if selected else None
@@ -332,14 +431,16 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, causal, scale, plan, n, masked,
     def prep(r):
         return _rows(q_ref, r, c), r
 
-    def piece(ctx, j, g, lo, hi, tri, carry):
+    def piece(ctx, j, g, lo, hi, off, carry):
         """One online-softmax step of a group's q rows over k/v rows."""
         q, r = ctx
         m, l, acc = carry
-        k, v = _rows(k_ref, j * c, hi), _rows(v_ref, j * c, hi)
-        s = _dot(q[g:g + sub], k, _NT) * scale            # [sub, hi] fp32
-        if tri:
-            s = jnp.where(_keep_tri(sub, hi, g, True), s, NEG_INF)
+        k0 = j * c + lo
+        k, v = _rows(k_ref, k0, hi - lo), _rows(v_ref, k0, hi - lo)
+        s = _dot(q[g:g + sub], k, _NT) * scale       # [sub, hi - lo] fp32
+        if off is not None:
+            s = jnp.where(_keep_tri(sub, hi - lo, off, True, window), s,
+                          NEG_INF)
         if masked:
             # k-side padding mask (1=keep), a row [1, k]: k runs along
             # lanes like the scores' columns
@@ -352,8 +453,12 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, causal, scale, plan, n, masked,
         if masked or selected:
             # fully-masked row guard: m_new == NEG_INF would make the
             # masked exp(s - m_new) = 1; clamp so p stays 0 and the row
-            # sums to 0. Without a k-side mask a row's first chunk always
-            # holds its own position, so m_new is finite from there on.
+            # sums to 0. Without a k-side mask a row's first chunk holds
+            # its own position, so m_new is finite from there on; under a
+            # window a row may see no key of its first chunk (the band's
+            # lower edge), and what that chunk adds to l and acc is wiped
+            # by corr = exp(NEG_INF - m) = 0 at the row's next chunk,
+            # which holds keys it reads.
             m_new = jnp.where(m_new > 0.5 * NEG_INF, m_new, 0.0)
         p = jnp.exp(s - m_new)
         corr = jnp.exp(m - m_new)
@@ -370,10 +475,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, causal, scale, plan, n, masked,
 
     _walk(plan, n, causal, True, pl.program_id(1), pl.program_id(2),
           ((NEG_INF, 1), (0.0, 1), (0.0, d)), refs[2:], prep, piece,
-          finalize)
+          finalize, window)
 
 
-def _specs(plan, d, causal, heads, out_is_q, group=1):
+def _specs(plan, d, causal, heads, out_is_q, group=1, band=None):
     """Block specs of a grid (bh, out_block, reduce_block): operand rows of
     the out side and of the reduce side, the q side's statistics as
     columns (`stat_out`: the forward writes them, the causal and masked
@@ -389,12 +494,19 @@ def _specs(plan, d, causal, heads, out_is_q, group=1):
     heads share one key/value head: the grid runs over query heads and
     the index map folds them (b // group), so no copy is made. Under a
     causal mask the reduce side stops (dk/dv: starts) at the out block,
-    so a grid step that computes nothing fetches nothing either."""
+    so a grid step that computes nothing fetches nothing either. `band`
+    (m, n): under a window the reduce axis counts the m + 1 blocks the
+    band meets, k blocks i - m .. i (dk/dv: q blocks i .. i + m), held
+    inside the sequence, so that a step beyond it fetches nothing new."""
     block, c, sub = plan
 
     def red(b, i, j):
         if not causal:
             return j
+        if band is not None:
+            m, n = band
+            return jnp.maximum(i - m + j, 0) if out_is_q else \
+                jnp.minimum(i + j, n - 1)
         return jnp.minimum(j, i) if out_is_q else jnp.maximum(j, i)
 
     def kv(b):
@@ -421,11 +533,27 @@ def _specs(plan, d, causal, heads, out_is_q, group=1):
                  lambda *g: (g[0] // heads, g[1], red(*g))))
 
 
-def _fwd(q3, k3, v3, causal, scale, mask3=None, heads=1, sel=None, group=1):
+def _band(plan, n, window):
+    """(`_band_blocks`, n) of a windowed call, for `_specs`; None without
+    a window."""
+    return None if window is None else (
+        _band_blocks(window, plan.block, n), n)
+
+
+def _grid(bh, n, band):
+    """(b h, out blocks, reduce blocks): every block, or under a window
+    the blocks its band meets."""
+    return (bh, n, n if band is None else band[0] + 1)
+
+
+def _fwd(q3, k3, v3, causal, scale, mask3=None, heads=1, sel=None, group=1,
+         window=None):
     bh, s, d = q3.shape
     plan = _plan(s, d, q3.dtype, causal)
     n = s // plan.block
-    sp = _specs(plan, d, causal, heads, out_is_q=True, group=group)
+    band = _band(plan, n, window)
+    sp = _specs(plan, d, causal, heads, out_is_q=True, group=group,
+                band=band)
     in_specs = [sp["out"], sp["kv_red"], sp["kv_red"]]
     args = [q3, k3, v3]
     if mask3 is not None:
@@ -443,8 +571,8 @@ def _fwd(q3, k3, v3, causal, scale, mask3=None, heads=1, sel=None, group=1):
     o, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, causal=causal, scale=scale,
                           plan=plan, n=n, masked=mask3 is not None,
-                          selected=sel is not None),
-        grid=(bh, n, n),
+                          selected=sel is not None, window=window),
+        grid=_grid(bh, n, band),
         in_specs=in_specs,
         out_specs=[sp["out"], sp["stat_out"]],
         out_shape=[jax.ShapeDtypeStruct((bh, s, d), q3.dtype),
@@ -455,8 +583,9 @@ def _fwd(q3, k3, v3, causal, scale, mask3=None, heads=1, sel=None, group=1):
         # the name is the innermost component of the kernel's `op_name`,
         # and with it the compiled instruction's name ("%flash_fwd.N"):
         # a trace tells the three kernels apart without knowing shapes,
-        # and the selected path from the plain one
-        name=FLASH_FWD if sel is None else FLASH_SEL_FWD,
+        # and the selected and the windowed path from the plain one
+        name=(FLASH_SEL_FWD if sel is not None else
+              FLASH_WIN_FWD if window is not None else FLASH_FWD),
     )(*args)
     return o, lse
 
@@ -464,7 +593,7 @@ def _fwd(q3, k3, v3, causal, scale, mask3=None, heads=1, sel=None, group=1):
 # --------------------------------------------------------------- backward
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
-               causal, scale, plan, n, masked):
+               causal, scale, plan, n, masked, window=None):
     refs = list(refs)
     mask_ref = refs.pop(0) if masked else None
     dq_ref = refs[0]
@@ -476,12 +605,14 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
                 _rows(lse_ref, r, c)[:, 0:1],
                 _rows(delta_ref, r, c)[:, 0:1])
 
-    def piece(ctx, j, g, lo, hi, tri, carry):
+    def piece(ctx, j, g, lo, hi, off, carry):
         q, do, lse, delta = (x[g:g + sub] for x in ctx)
-        k, v = _rows(k_ref, j * c, hi), _rows(v_ref, j * c, hi)
+        k0 = j * c + lo
+        k, v = _rows(k_ref, k0, hi - lo), _rows(v_ref, k0, hi - lo)
         s = _dot(q, k, _NT) * scale
-        if tri:
-            s = jnp.where(_keep_tri(sub, hi, g, True), s, NEG_INF)
+        if off is not None:
+            s = jnp.where(_keep_tri(sub, hi - lo, off, True, window), s,
+                          NEG_INF)
         if masked:
             s = jnp.where(mask_ref[0, 0, pl.ds(j, 1), :hi] > 0, s, NEG_INF)
         ds = jnp.exp(s - lse) * (_dot(do, v, _NT) - delta) * scale
@@ -491,11 +622,11 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
         dq_ref[0, pl.ds(r, c), :] = carry[0].astype(dq_ref.dtype)
 
     _walk(plan, n, causal, True, pl.program_id(1), pl.program_id(2),
-          ((0.0, d),), refs[1:], prep, piece, finalize)
+          ((0.0, d),), refs[1:], prep, piece, finalize, window)
 
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
-                causal, scale, plan, n, masked):
+                causal, scale, plan, n, masked, window=None):
     """dk and dv of the resident k block, a chunk of k rows at a time over
     the chunks of the resident q block. The scores are computed
     transposed, sᵀ = k qᵀ: k runs along sublanes and q along lanes, so
@@ -511,14 +642,15 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
         keep = _rows(mask_ref, r, c)[:, 0:1] > 0 if masked else None
         return _rows(k_ref, r, c), _rows(v_ref, r, c), keep
 
-    def piece(ctx, i, g, lo, hi, tri, carry):
+    def piece(ctx, i, g, lo, hi, off, carry):
         k, v, keep = (x[g:g + sub] if x is not None else None for x in ctx)
         dk, dv = carry
         q0, nq = i * c + lo, hi - lo
         q, do = _rows(q_ref, q0, nq), _rows(do_ref, q0, nq)
         st = _dot(k, q, _NT) * scale                      # [sub, nq] fp32
-        if tri:
-            st = jnp.where(_keep_tri(sub, nq, 0, False), st, NEG_INF)
+        if off is not None:
+            st = jnp.where(_keep_tri(sub, nq, off, False, window), st,
+                           NEG_INF)
         if masked:
             st = jnp.where(keep, st, NEG_INF)
         pt = jnp.exp(st - _stat_row(lse_ref, q0, nq, sub))
@@ -533,7 +665,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
         dv_ref[0, pl.ds(r, c), :] = dv.astype(dv_ref.dtype)
 
     _walk(plan, n, causal, False, pl.program_id(1), pl.program_id(2),
-          ((0.0, d), (0.0, d)), refs[2:], prep, piece, finalize)
+          ((0.0, d), (0.0, d)), refs[2:], prep, piece, finalize, window)
 
 
 def _stat_row(ref, start, size, sub):
@@ -546,7 +678,7 @@ def _stat_row(ref, start, size, sub):
 
 
 def _bwd_impl(causal, scale, res, g, mask3=None, heads=1, sel=None,
-              group=1):
+              group=1, window=None):
     """dq, dk, dv. The causal and the k-side-masked family: a dq kernel
     and a dk/dv kernel, each of which computes the scores, their `exp`
     and dO vᵀ of every pair it visits. Under a selection: the ONE fused
@@ -570,6 +702,7 @@ def _bwd_impl(causal, scale, res, g, mask3=None, heads=1, sel=None,
     plan = _plan(s, d, q3.dtype, causal)
     block, c, sub = plan
     n = s // block
+    band = _band(plan, n, window)
     do3 = g
     # softmax delta rowsum(dO·O), precomputed once (not per k chunk). dq
     # reads it, like lse, as a column over the stat-lane layout; dk/dv
@@ -583,7 +716,8 @@ def _bwd_impl(causal, scale, res, g, mask3=None, heads=1, sel=None,
     acc = [pltpu.VMEM((block, d), jnp.float32)] if n > 1 else []
 
     # dq grid: (bh, q_block, k_block) — the k-side mask follows axis 2
-    sp = _specs(plan, d, causal, heads, out_is_q=True, group=group)
+    sp = _specs(plan, d, causal, heads, out_is_q=True, group=group,
+                band=band)
     dq_in = [sp["out"], sp["kv_red"], sp["kv_red"], sp["out"],
              sp["stat_out"], sp["stat_out"]] + (
         [sp["mask_rows"]] if masked else [])
@@ -591,15 +725,15 @@ def _bwd_impl(causal, scale, res, g, mask3=None, heads=1, sel=None,
         [mask3.reshape(-1, n, block // c, c)] if masked else [])
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, causal=causal, scale=scale,
-                          plan=plan, n=n, masked=masked),
-        grid=(bh, n, n),
+                          plan=plan, n=n, masked=masked, window=window),
+        grid=_grid(bh, n, band),
         in_specs=dq_in,
         out_specs=[sp["out"]],
         out_shape=[jax.ShapeDtypeStruct((bh, s, d), q3.dtype)],
         scratch_shapes=acc,
         interpret=_interpret(),
         compiler_params=_COMPILER_PARAMS,
-        name=FLASH_BWD_DQ,
+        name=FLASH_BWD_DQ if window is None else FLASH_WIN_BWD_DQ,
     )(*dq_args)[0]
 
     # grid dims: (bh, k_block, q_block) — q is the reduce (innermost) dim;
@@ -608,7 +742,8 @@ def _bwd_impl(causal, scale, res, g, mask3=None, heads=1, sel=None,
     # over query heads: each writes its own dk, dv in fp32 and XLA adds a
     # group's up, which costs one pass over [bh, s, d] where a second
     # reduce axis would cost the kernels their static walk.
-    sp = _specs(plan, d, causal, heads, out_is_q=False, group=group)
+    sp = _specs(plan, d, causal, heads, out_is_q=False, group=group,
+                band=band)
     dkv_in = [sp["red"], sp["kv_out"], sp["kv_out"], sp["red"],
               sp["stat_rows"], sp["stat_rows"]] + (
         [sp["mask_col"]] if masked else [])
@@ -618,8 +753,8 @@ def _bwd_impl(causal, scale, res, g, mask3=None, heads=1, sel=None,
     part = jnp.float32 if group > 1 else None
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, causal=causal, scale=scale,
-                          plan=plan, n=n, masked=masked),
-        grid=(bh, n, n),
+                          plan=plan, n=n, masked=masked, window=window),
+        grid=_grid(bh, n, band),
         in_specs=dkv_in,
         out_specs=[sp["out"], sp["out"]],
         out_shape=[jax.ShapeDtypeStruct((bh, s, d), part or k3.dtype),
@@ -627,7 +762,7 @@ def _bwd_impl(causal, scale, res, g, mask3=None, heads=1, sel=None,
         scratch_shapes=acc * 2,
         interpret=_interpret(),
         compiler_params=_COMPILER_PARAMS,
-        name=FLASH_BWD_DKV,
+        name=FLASH_BWD_DKV if window is None else FLASH_WIN_BWD_DKV,
     )(*dkv_args)
     if group > 1:
         dk, dv = (x.reshape(bh // group, group, s, d).sum(1).astype(k3.dtype)
@@ -637,30 +772,33 @@ def _bwd_impl(causal, scale, res, g, mask3=None, heads=1, sel=None,
 
 # ------------------------------------------------------------- public op
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
-def _flash3(q3, k3, v3, mask3, sel, causal, scale, heads, group):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
+def _flash3(q3, k3, v3, mask3, sel, causal, scale, heads, group,
+            window=None):
     """The one differentiable wrapper of the forward kernel and its
     backward: a dq and a dk/dv kernel, or under a selection the one fused
     kernel (`_bwd_impl`). `mask3` ([batch, 1, s] float, or None): the
     k-side padding mask; `sel` ([batch, s_q, s_k] 0/1 bytes, or None): a
     per-pair selection; a row's `heads` query heads share both. `group`
-    query heads share one key/value head."""
+    query heads share one key/value head. `window` (or None): the causal
+    band's keys a query."""
     o, _ = _fwd(q3, k3, v3, causal, scale, mask3=mask3, heads=heads, sel=sel,
-                group=group)
+                group=group, window=window)
     return o
 
 
-def _flash3_fwd(q3, k3, v3, mask3, sel, causal, scale, heads, group):
+def _flash3_fwd(q3, k3, v3, mask3, sel, causal, scale, heads, group,
+                window=None):
     o, lse = _fwd(q3, k3, v3, causal, scale, mask3=mask3, heads=heads,
-                  sel=sel, group=group)
+                  sel=sel, group=group, window=window)
     return o, (q3, k3, v3, o, lse, mask3, sel)
 
 
-def _flash3_bwd(causal, scale, heads, group, res, g):
+def _flash3_bwd(causal, scale, heads, group, window, res, g):
     import numpy as np
     *res, mask3, sel = res
     dq, dk, dv = _bwd_impl(causal, scale, res, g, mask3=mask3, heads=heads,
-                           sel=sel, group=group)
+                           sel=sel, group=group, window=window)
     # neither is a value: the mask's cotangent is zero, an integer
     # operand's is float0
     dmask = None if mask3 is None else jnp.zeros_like(mask3)
@@ -672,7 +810,7 @@ _flash3.defvjp(_flash3_fwd, _flash3_bwd)
 
 
 def flash_attention(query, key, value, causal: bool = False,
-                    scale=None, kv_mask=None, selection=None):
+                    scale=None, kv_mask=None, selection=None, window=None):
     """[b, s, h, d] fused attention. Requires s % 128 == 0.
 
     kv_mask ([b, s], bool/0-1, optional): k-side padding mask — 1 keeps
@@ -692,6 +830,13 @@ def flash_attention(query, key, value, causal: bool = False,
     causal=True, which says which blocks the walk may skip and where the
     backward has a query block's dq whole. Rows that select nothing
     return 0.
+
+    window (int, optional, with causal=True): query position p reads the
+    keys p - window + 1 .. p alone (a sliding window). The kernels' grid
+    visits only the key blocks that band meets, and their walk only the
+    chunks and groups inside it (`score_work`); they are named apart from
+    the causal ones (`flash_win_fwd`, `flash_win_bwd_dq`,
+    `flash_win_bwd_dkv`).
     """
     b, s, h, d = query.shape
     if s % 128 != 0:
@@ -713,13 +858,18 @@ def flash_attention(query, key, value, causal: bool = False,
         raise NotImplementedError(
             "a selection without causal=True: the selected backward ends a "
             "query block's dq at its diagonal key block")
+    if window is not None and (not causal or kv_mask is not None
+                               or selection is not None or window < 1):
+        raise NotImplementedError(
+            f"window={window}: a band of at least one key under "
+            f"causal=True, without kv_mask or a selection")
     # [batch, 1, s] / [batch, s_q, s_k]: heads share the batch row via the
     # kernels' b // heads index map (no h-fold HBM duplication)
     m3 = None if kv_mask is None else jnp.asarray(
         kv_mask, jnp.float32).reshape(b, 1, s)
     sel = None if selection is None else jnp.asarray(selection, jnp.int8)
     o3 = _flash3(to3(query), to3(key), to3(value), m3, sel, causal, scale,
-                 h, h // h_kv)
+                 h, h // h_kv, None if window is None else int(window))
     return jnp.swapaxes(o3.reshape(b, h, s, d), 1, 2)
 
 
@@ -852,12 +1002,12 @@ def _mla_fwd_kernel(*refs, parts, scale, plan, n):
     def prep(r):
         return tuple(_lanes(q_refs[a], r, c, lo, w) for a, lo, w in parts.q)
 
-    def piece(ctx, j, g, lo, hi, tri, carry):
+    def piece(ctx, j, g, lo, hi, off, carry):
         m, l, acc = carry
         s = _mla_scores([q[g:g + sub] for q in ctx], keys, j * c, hi,
                         scale)
-        if tri:
-            s = jnp.where(_keep_tri(sub, hi, g, True), s, NEG_INF)
+        if off is not None:
+            s = jnp.where(_keep_tri(sub, hi, off, True), s, NEG_INF)
         v = _lanes(k_refs[v_a], j * c, hi, v_lo, dv)
         m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
         p = jnp.exp(s - _over_lanes(m_new, hi))
@@ -921,7 +1071,7 @@ def _fused_bwd_kernel(*refs, parts, scale, plan, n, selected):
         return ks, tuple(k.T for k in ks), _lanes(k_refs[v_a], r, c, v_lo,
                                                   v_w), r
 
-    def piece(ctx, i, g, lo, hi, tri, carry):
+    def piece(ctx, i, g, lo, hi, off, carry):
         ks, kts, v, r = ctx
         *dks, dv = carry
         q0, nq = i * c + lo, hi - lo
@@ -932,8 +1082,8 @@ def _fused_bwd_kernel(*refs, parts, scale, plan, n, selected):
             t = _dot(k[g:g + sub], q, _NT)
             st = t if st is None else st + t
         st = st * scale                                   # [sub, nq] fp32
-        if tri:
-            st = jnp.where(_keep_tri(sub, nq, 0, False), st, NEG_INF)
+        if off is not None:
+            st = jnp.where(_keep_tri(sub, nq, off, False), st, NEG_INF)
         if selected:
             st = jnp.where(_selected(sel_ref, r + g, sub, q0, nq), st,
                            NEG_INF)

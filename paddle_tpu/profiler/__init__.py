@@ -66,9 +66,15 @@ INDEX_TOPK = "index_topk"
 # rotary key head shared by every query head) than the values read
 FLASH_MLA_FWD = "flash_mla_fwd"
 FLASH_MLA_BWD_DKV = "flash_mla_bwd_dkv"
+# the causal three under a sliding window: their grid visits only the key
+# blocks the band meets
+FLASH_WIN_FWD = "flash_win_fwd"
+FLASH_WIN_BWD_DQ = "flash_win_bwd_dq"
+FLASH_WIN_BWD_DKV = "flash_win_bwd_dkv"
 KERNELS = (FLASH_FWD, FLASH_BWD_DQ, FLASH_BWD_DKV)
 MLA_KERNELS = (FLASH_MLA_FWD, FLASH_MLA_BWD_DKV)
 SEL_KERNELS = (FLASH_SEL_FWD, FLASH_SEL_BWD_DKV)
+WIN_KERNELS = (FLASH_WIN_FWD, FLASH_WIN_BWD_DQ, FLASH_WIN_BWD_DKV)
 # not ours to choose: the instruction the TPU compiler makes of
 # `jax.lax.ragged_dot` (the expert layer's grouped product) is a custom
 # call of this name, "%ragged-dot-none.3", forward and backward alike
@@ -94,6 +100,10 @@ MOE_EXPERTS = "moe.experts"
 # shared expert inside `mlp`
 ATTN_LATENT = "attn.latent"
 MOE_SHARED = "moe.shared"
+# a sliding-window layer's attention dispatch, and an attention output's
+# sigmoid gate, inside `attn`
+ATTN_WINDOW = "attn.window"
+ATTN_GATE = "attn.gate"
 
 
 class Span(NamedTuple):

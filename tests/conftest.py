@@ -107,11 +107,15 @@ def pytest_runtest_setup(item):
 
 # xdist hands out whole files in collection order (--dist loadfile), and
 # a four-minute file that starts last sets the wall time of the run. The
-# files that take longest, longest first (junit times of a whole run,
-# PR 23: 280, 165, 121, 117, 96, 92, 85, 76 s):
-_LONGEST_FIRST = ("test_rcnn.py", "test_examples.py", "test_yolo.py",
-                  "test_parallel.py", "test_sequence_parallel.py",
-                  "test_contrib_ops.py", "test_native_selftest.py",
+# files that take longest, longest first (junit times of a whole run
+# under six workers: 713, 631, 380, 372, 358, 286, 285, 264, 253, 253,
+# 234, 220, 187, 179, 167 s):
+_LONGEST_FIRST = ("test_flash_attention.py", "test_rcnn.py", "test_afmoe.py",
+                  "test_perf_benchmark.py", "test_examples.py",
+                  "test_deepseek_v3.py", "test_keye.py", "test_parallel.py",
+                  "test_yolo.py", "test_trinity_cell.py",
+                  "test_sequence_parallel.py", "test_keye_cell.py",
+                  "test_contrib_ops.py", "test_kanana_cell.py",
                   "test_onnx_export.py")
 
 

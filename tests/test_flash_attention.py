@@ -109,6 +109,38 @@ def test_bf16_operands_match_xla(s, causal, masked):
                  dict(rtol=2e-2, atol=2e-2), dict(rtol=5e-2, atol=5e-2))
 
 
+def _covered(s, causal, d, dtype, transposed, window=None):
+    """The spans and groups the kernels are built from, laid over the (q,
+    k) plane: every needed score is covered exactly once, a group that
+    applies no mask holds none outside the causal band, and the area and
+    the bodies are what `score_work` counts."""
+    _, c, sub = fa._plan(s, d, dtype, causal)
+    seen = np.zeros((s, s), np.int32)                     # [q, k]
+    bodies = masked_bodies = 0
+    q_pos, k_pos = np.arange(s)[:, None], np.arange(s)[None, :]
+    keep = np.ones((s, s), bool) if not causal else q_pos >= k_pos
+    if window is not None:
+        keep &= q_pos - k_pos < window
+    for o0 in range(0, s, c):
+        for j, delta in fa._chunk_spans(o0, c, s // c, causal,
+                                        not transposed, window):
+            groups = fa._groups(c, sub, not transposed, delta, window)
+            bodies += 1
+            masked_bodies += any(off is not None for *_, off in groups)
+            for g, r_lo, r_hi, off in groups:
+                out = slice(o0 + g, o0 + g + sub)
+                red = slice(j * c + r_lo, j * c + r_hi)
+                q, k = (red, out) if transposed else (out, red)
+                if off is None:
+                    assert keep[q, k].all()             # needs no mask
+                seen[q, k] += 1
+    assert seen.max() == 1 and (seen >= keep).all()
+    work = fa.score_work(s, causal, d, dtype, transposed, window)
+    assert (work.chunks, work.masked_chunks) == (bodies, masked_bodies)
+    assert work.needed == keep.sum()
+    assert work.ratio == pytest.approx(seen.sum() / keep.sum())
+
+
 @pytest.mark.parametrize("transposed", [False, True], ids=["fwd-dq", "dkv"])
 @pytest.mark.parametrize("s,causal,d,dtype", [
     (1024, True, 64, jnp.bfloat16), (1024, False, 64, jnp.bfloat16),
@@ -120,30 +152,41 @@ def test_score_work_is_what_the_walk_covers(s, causal, d, dtype, transposed):
     spans and groups the kernels are built from cover every needed score
     exactly once, a group that applies no causal mask holds none above
     the diagonal, and the area is what the counter says."""
-    _, c, sub = fa._plan(s, d, dtype, causal)
-    seen = np.zeros((s, s), np.int32)                     # [q, k]
-    bodies = masked_bodies = 0
-    for o0 in range(0, s, c):
-        f_lo, f_hi, d_lo, d_hi = fa._chunk_spans(o0, c, s // c, causal,
-                                                 not transposed)
-        for lo, hi, diag in ((f_lo, f_hi, False), (d_lo, d_hi, True)):
-            for j in range(lo, hi):
-                bodies += 1
-                masked_bodies += diag
-                for g, r_lo, r_hi in fa._groups(c, sub, not transposed,
-                                                diag):
-                    out = slice(o0 + g, o0 + g + sub)
-                    red = slice(j * c + r_lo, j * c + r_hi)
-                    q, k = (red, out) if transposed else (out, red)
-                    if causal and not diag:
-                        assert k.stop - 1 <= q.start    # needs no mask
-                    seen[q, k] += 1
-    needed = np.tril(np.ones((s, s), np.int32)) if causal \
-        else np.ones((s, s), np.int32)
-    assert seen.max() == 1 and (seen >= needed).all()
-    work = fa.score_work(s, causal, d, dtype, transposed)
-    assert (work.chunks, work.masked_chunks) == (bodies, masked_bodies)
-    assert work.ratio == pytest.approx(seen.sum() / needed.sum())
+    _covered(s, causal, d, dtype, transposed)
+
+
+@pytest.mark.parametrize("transposed", [False, True], ids=["fwd-dq", "dkv"])
+@pytest.mark.parametrize("s,d,dtype,window", [
+    (2048, 64, jnp.float32, 200), (2048, 64, jnp.float32, 512),
+    (2048, 64, jnp.float32, 700), (2048, 64, jnp.float32, 2048),
+    (2048, 64, jnp.bfloat16, 1), (2048, 64, jnp.bfloat16, 300),
+    (1152, 80, jnp.bfloat16, 500), (4096, 128, jnp.bfloat16, 1024)])
+def test_window_score_work_is_what_the_walk_covers(s, d, dtype, window,
+                                                   transposed):
+    """The same geometry under a sliding window: the band's lower edge is
+    a second diagonal, and the chunks below it are not walked."""
+    _covered(s, True, d, dtype, transposed, window)
+
+
+def test_window_score_work_at_the_trinity_cell():
+    """8192 tokens of head dim 128 in bf16 under a window of 2048: 1024-row
+    blocks of one chunk, so a query block meets 3 of the 8 key blocks
+    (its own, masked causally, the one before in full, and the one the
+    band's lower edge crosses, masked by the band) where the causal
+    kernels meet 1 to 8: 21 chunk bodies against 36. The band needs
+    0.4375 of the causal triangle's scores."""
+    causal = fa.score_work(8192, True, 128)
+    band = fa.score_work(8192, True, 128, window=2048)
+    assert fa._plan(8192, 128, jnp.bfloat16, True) == (1024, 1024, 256)
+    assert fa.window_kblocks(8192, 128, jnp.bfloat16, 2048) == 3
+    assert (causal.chunks, causal.masked_chunks) == (36, 8)
+    assert (band.chunks, band.masked_chunks) == (21, 8 + 6)
+    assert band.needed / causal.needed == pytest.approx(0.4375, rel=1e-3)
+    assert band.ratio == pytest.approx(1.125, rel=1e-3)
+    assert fa.score_work(8192, True, 128, transposed=True, window=2048) \
+        == band
+    # a window as long as the row is the causal mask
+    assert fa.score_work(8192, True, 128, window=8192) == causal
 
 
 def test_score_work_bounds():

@@ -31,6 +31,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P, \
     SingleDeviceSharding
 
 from paddle_tpu.ops import flash_attention as fa
+from paddle_tpu import profiler as prof
 
 # Each kernel compiles in about a second here; the whole file in ~30 s.
 COMPILE_LIMIT_S = 120
@@ -193,6 +194,31 @@ def test_selected_grouped_kernels_compile_at_keye_widths(one_chip):
     assert "flash_sel_bwd_dq" not in text
     acc, limit = fa._fused_bwd_vmem(s, 1024, [d], d, selected=True)
     assert acc == s * d * 4 and acc + (18 << 20) < limit < (32 << 20)
+
+
+@pytest.mark.parametrize("window", [2048, None], ids=["sliding", "full"])
+def test_grouped_kernels_compile_at_trinity_widths(one_chip, window):
+    """The Trinity cell's attention at its real size: 32 query heads over 4
+    key/value heads of 128, 8192 positions, causal, a sliding layer's
+    window of 2048 (the grid's reduce axis the 3 key blocks its band
+    meets) and a full layer's causal kernels."""
+    b, s, h, h_kv, d = 2, 8192, 32, 4, 128
+    q = jax.ShapeDtypeStruct((b, s, h, d), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((b, s, h_kv, d), jnp.bfloat16,
+                              sharding=one_chip)
+
+    def loss_and_grads(q, k, v):
+        return jax.value_and_grad(
+            lambda q, k, v: fa.flash_attention(
+                q, k, v, causal=True, window=window
+            ).astype(jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
+
+    text = compiled_text(loss_and_grads, q, kv, kv)
+    assert text.count("tpu_custom_call") == 3
+    names = prof.WIN_KERNELS if window else prof.KERNELS
+    for name in names:
+        assert name in text, name
+    assert ("flash_win" in text) == bool(window)
 
 
 @pytest.mark.parametrize("concat", [False, True],

@@ -191,7 +191,8 @@ def metric_files() -> dict:
 
 # what an `op_ms` pattern may name: the program's kernels, and the one
 # instruction the compiler names for it (`jax.lax.ragged_dot`)
-NAMED = prof.KERNELS + prof.SEL_KERNELS + prof.MLA_KERNELS + (
+NAMED = prof.KERNELS + prof.SEL_KERNELS + prof.MLA_KERNELS + \
+    prof.WIN_KERNELS + (
     prof.INDEX_SCORES, prof.INDEX_TOPK, prof.RAGGED_DOT)
 # names the accepted patterns still carry and no kernel bears: the latent
 # dq kernel went into the dk/dv walk (ISSUE 34) and the selected one after
